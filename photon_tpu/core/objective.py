@@ -233,13 +233,6 @@ class GlmObjective:
             n * k, dim, n,
             has_fm=has_fm, has_aligned=has_al, has_benes=has_benes,
             has_xchg=has_xchg,
-            # Whether values were pre-permuted at attach changes the
-            # per-step data movement the probe must time (baked: dz
-            # expansion only; unbaked — streamed chunks: the full product
-            # stream rides the exchange).
-            xchg_baked=(
-                has_xchg and getattr(batch.xchg, "vals_dest", None) is not None
-            ),
         )
         return None if choice == "autodiff" else choice
 
@@ -312,35 +305,6 @@ class GlmObjective:
                 val = val + 0.5 * self.l2_weight * jnp.dot(w, w)
                 g = g + self.l2_weight * w
             return val, g
-        if (
-            not isinstance(batch, DenseBatch)
-            and batch.ids.ndim == 2
-            and self.normalization is None
-        ):
-            from photon_tpu.ops.pallas_sparse import (
-                fused_value_and_grad,
-                kernel_supported,
-                pallas_enabled,
-            )
-
-            # Fused Pallas pass: gather + loss + dz + scatter in one kernel
-            # (photon_tpu.ops.pallas_sparse); L2 added analytically, as in
-            # the XLA path.  kernel_supported() is an EAGER one-time Mosaic
-            # capability probe — a try/except here could not catch lowering
-            # failures, which surface when the enclosing jit (the
-            # optimizer's while_loop) compiles.  On v5e Mosaic lacks vector
-            # scatter-add, so this routes back to XLA there.
-            if pallas_enabled() and kernel_supported(
-                self.loss, int(batch.ids.shape[1]), int(w.shape[0])
-            ):
-                v, g = fused_value_and_grad(
-                    self.loss, w, batch.ids, batch.vals,
-                    batch.label, batch.offset, batch.weight,
-                )
-                if not _static_zero(self.l2_weight):
-                    v = v + 0.5 * self.l2_weight * jnp.dot(w, w)
-                    g = g + self.l2_weight * w
-                return v, g
         return jax.value_and_grad(self.value)(w, batch)
 
     def grad(self, w: Array, batch: Batch) -> Array:

@@ -227,7 +227,6 @@ def attach_feature_major(
     aligned_dim: int | None = None,
     aligned_forward: bool | None = None,
     geometry_gather=None,
-    global_entries: int | None = None,
 ) -> SparseBatch:
     """Attach the static feature-major layout (:class:`FeatureMajorAux`).
 
@@ -293,11 +292,7 @@ def attach_feature_major(
 
         ids_np = np.asarray(batch.ids)
         vals_np = np.asarray(batch.vals, np.float32)
-        # Size floors judge the GLOBAL problem (the kernels run at global
-        # scale): a multi-process assembly passes the allgathered total
-        # so four processes sharing a big batch don't each fall below a
-        # local floor and silently lose the route everywhere.
-        want_xchg = xchg_route_wanted(global_entries or (n * k))
+        want_xchg = xchg_route_wanted()
         if aligned_forward is None:
             # xchg implies the pallas forward: its whole point is deleting
             # the E-element gathers, and XLA margins would reintroduce one.

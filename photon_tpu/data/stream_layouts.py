@@ -30,9 +30,9 @@ under a second per pass while deleting the per-pass E-element gather
 the xchg kernel exists to delete.
 
 Select with ``PHOTON_STREAM_KERNEL=autodiff|fm|pallas|xchg`` (default
-``autodiff`` — the measured-best round-4 TPU kernel, and the right
-default while streamed passes are host-parse-bound).  ``xchg`` honors
-``PHOTON_XCHG_REDUCE`` like the resident path.
+``autodiff`` — the right default while streamed passes are
+host-parse-bound).  ``xchg`` honors ``PHOTON_XCHG_REDUCE`` like the
+resident path (and, like it, does not lower on the v5e — ops/vperm.py).
 """
 
 from __future__ import annotations
@@ -78,10 +78,9 @@ def stream_kernel_why(kernel: str) -> str:
     """One-line provenance for bench/driver reporting."""
     if kernel == "autodiff":
         return (
-            "default: streamed passes are host-parse-bound and autodiff "
-            "is the measured-best TPU kernel (KERNEL_NOTES r4 table); "
-            "set PHOTON_STREAM_KERNEL to attach cached fast-kernel "
-            "layouts per chunk"
+            "default: streamed passes are host-parse-bound; set "
+            "PHOTON_STREAM_KERNEL to attach cached fast-kernel layouts "
+            "per chunk"
         )
     return (
         f"PHOTON_STREAM_KERNEL={kernel}: per-file layouts/routes built "
@@ -201,12 +200,23 @@ def _save_aux(path: str, layout, aux) -> None:
     if aux is not None:
         for key, val in _aux_to_npz(aux).items():
             out["aux_" + key] = val
+    import tempfile
+
     try:
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        tmp = path + f".tmp{os.getpid()}.{id(layout) & 0xffff:x}"
-        with open(tmp, "wb") as f:
-            np.savez(f, **out)
-        os.replace(tmp, path)
+        directory = os.path.dirname(path) or "."
+        os.makedirs(directory, exist_ok=True)
+        # mkstemp: a name no other thread or process can pick (two io-pool
+        # workers caching the same file used to collide on a pid+id name).
+        fd, tmp = tempfile.mkstemp(
+            prefix=os.path.basename(path) + ".", suffix=".tmp", dir=directory
+        )
+        try:
+            with os.fdopen(fd, "wb") as f:
+                np.savez(f, **out)
+            os.replace(tmp, path)
+        except BaseException:
+            os.unlink(tmp)
+            raise
     except Exception as exc:  # noqa: BLE001 — best-effort cache
         _LOG.warning("stream layout cache write failed (%s)", exc)
 

@@ -296,10 +296,7 @@ def _chunk_value_and_grad(objective, kernel, w: Array, chunk: SparseBatch):
     reg weights, so this is the data term): chunks whose carried aux
     wins the measured selection run that fast kernel; everything else —
     bare chunks, and aux-carrying chunks whose selection says autodiff —
-    takes the literal pre-round-5 autodiff path.  Deliberately NOT the
-    objective's generic value_and_grad: its pallas_sparse fused branch
-    would silently change streamed numerics for PHOTON_TPU_PALLAS=1
-    users and contradict the bench's kernel attribution."""
+    takes the literal pre-round-5 autodiff path."""
     if kernel is None:
         return jax.value_and_grad(objective.data_value)(w, chunk)
     return objective._fast_data_value_and_grad(w, chunk, kernel)
@@ -871,7 +868,6 @@ def make_global_batch(local_batch: SparseBatch, mesh, axis: str = "data",
             shards=local_shards,
             aligned_dim=aligned_dim if wants_aligned else None,
             geometry_gather=gather_geometry,
-            global_entries=global_entries,
         )
         rebuilt = True
     if local_batch.fm is not None:

@@ -20,31 +20,46 @@ import numpy as np
 from photon_tpu.telemetry import NULL_SESSION, TelemetrySession, telemetry_enabled
 
 
-def select_backend(backend: str) -> None:
-    """Pin the JAX platform before any device use.
+def select_backend(backend: str = "tpu") -> dict:
+    """The device policy — every driver and ``bench.py`` call this before
+    any array is made, and nothing else decides where the program runs.
 
-    ``cpu`` forces the host platform (needed in sandboxes where the TPU
-    plugin's device init requires real hardware); ``tpu`` (default) lets the
-    environment's TPU platform load.
+    ``tpu`` (the default) REQUIRES a TPU: if ``jax.devices()[0].platform``
+    is anything else the run raises, naming what it found — a driver never
+    carries on quietly on whatever JAX fell back to.  CPU runs only when
+    asked for explicitly, by ``--backend cpu`` or ``JAX_PLATFORMS=cpu`` in
+    the environment (tier-1 tests, rehearsals); that is a choice, not a
+    fallback, and the summary and run report say ``platform: cpu``.
+
+    Also the one place the persistent compile cache is switched on
+    (``utils/compilation_cache.enable`` — ``JAX_COMPILATION_CACHE_DIR`` if
+    set, else ``<repo>/.jax_cache``).  Matmul precision stays JAX's
+    default: measured on the v5e, the train and serve legs hold their CPU
+    tolerances as is (README "Numerics") — a site that needs an f32-exact
+    MXU product says so with ``precision=`` and a measurement.
+    Returns ``{"platform", "device_kind", "device_count"}`` for the
+    driver's summary.
     """
     import jax
 
+    from photon_tpu.utils.compilation_cache import enable
+    from photon_tpu.utils.device import device_facts
+
+    asked = backend
     if backend == "cpu":
         jax.config.update("jax_platforms", "cpu")
-    # "tpu": leave the environment's platform selection alone.
-    _enable_compilation_cache()
-
-
-def _enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache for every driver run
-    (``PHOTON_COMPILATION_CACHE`` overrides the location, ``off`` disables;
-    an already-configured cache dir — tests, bench, the operator — wins)."""
-    from photon_tpu.utils.compilation_cache import enable
-
-    enable(
-        "PHOTON_COMPILATION_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "photon_tpu_xla"),
-    )
+    elif os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        asked = "cpu"
+    enable()
+    facts = device_facts()
+    if facts["platform"] != asked:
+        raise RuntimeError(
+            f"backend {asked!r} was asked for but JAX found platform "
+            f"{facts['platform']!r} ({facts['device_kind']}, "
+            f"{facts['device_count']} device(s)); pass --backend cpu or set "
+            "JAX_PLATFORMS=cpu to run on the host deliberately"
+        )
+    return facts
 
 
 def add_telemetry_arg(parser: argparse.ArgumentParser) -> None:
@@ -138,17 +153,19 @@ def maybe_init_distributed(args: argparse.Namespace) -> bool:
         )
     import jax
 
-    # Backend choice must be pinned before initialize() touches devices.
-    select_backend(getattr(args, "backend", "tpu"))
+    # The platform is pinned BEFORE initialize() (the runtime wires the
+    # coordination service into backend creation) and verified by
+    # select_backend() after it — the policy check itself creates the
+    # backend, so it cannot run first.
+    if getattr(args, "backend", "tpu") == "cpu":
+        jax.config.update("jax_platforms", "cpu")
     # Pin the sparse-gradient kernel across processes: auto-selection is a
     # per-process wall-clock measurement, so near the kernel crossover two
     # processes could pick different kernels — different per-shard reduction
     # orders — giving non-identical float results across ranks (VERDICT r3
     # weak 2).  An explicit PHOTON_SPARSE_GRAD (any value but "auto") is the
     # operator's pin and is respected; otherwise every rank defaults to
-    # autodiff — the measured-fastest kernel on real TPU hardware at the
-    # headline shape (1.881 vs fm's 1.124 steps/s; ops/KERNEL_NOTES.md
-    # round-4 hardware table).
+    # autodiff, the kernel that needs no static layout.
     if os.environ.get("PHOTON_SPARSE_GRAD", "auto") == "auto":
         os.environ["PHOTON_SPARSE_GRAD"] = "autodiff"
     jax.distributed.initialize(

@@ -81,7 +81,8 @@ def build_parser() -> argparse.ArgumentParser:
                    default="thread",
                    help="replica runtime: threads in this process, or one "
                    "child process per replica (own Python/jax runtime, "
-                   "frame protocol over loopback, devices dealt per child)")
+                   "frame protocol over loopback; --backend cpu only — a "
+                   "chip belongs to one process)")
     p.add_argument("--supervise", action="store_true",
                    help="attach the self-healing supervisor: health probes "
                    "(ping + known-answer score vs the host oracle), "
@@ -165,15 +166,15 @@ def _publish_text(output_dir: str, name: str, write_fn, session,
 
 
 def run(args: argparse.Namespace) -> dict:
-    common.select_backend(args.backend)
+    device = common.select_backend(args.backend)
     from photon_tpu.utils import PhotonLogger
 
     logger = PhotonLogger("photon_tpu.serve_game", args.log_file)
     with common.telemetry_run(args, "serve_game", logger) as session:
-        return _run(args, logger, session)
+        return _run(args, logger, session, device)
 
 
-def _run(args: argparse.Namespace, logger, session) -> dict:
+def _run(args: argparse.Namespace, logger, session, device: dict) -> dict:
     from photon_tpu.fault.retry import retry_call
     from photon_tpu.game.model_io import load_game_model
     from photon_tpu.serving import (
@@ -248,6 +249,13 @@ def _run(args: argparse.Namespace, logger, session) -> dict:
         logger.info("fleet warm: %d %s replicas, %d programs compiled%s",
                     args.replicas, args.replica_backend, fleet.compilations,
                     ", supervised" if args.supervise else "")
+        warm_compilations = fleet.compilations
+        # Read off the table arrays themselves (thread replicas; a
+        # subprocess replica's tables live in its child).
+        replica_devices = {
+            r.replica_id: [d.id for d in r.scorer.table_devices()]
+            for r in fleet.replicas if hasattr(r.scorer, "table_devices")
+        }
 
     spec = TrafficSpec(
         requests=args.requests,
@@ -324,6 +332,7 @@ def _run(args: argparse.Namespace, logger, session) -> dict:
 
     cold = _counter("serving.cold_entities")
     summary = {
+        "device": device,
         "requests": len(outcomes),
         "served": len(ok),
         "shed": len(shed),
@@ -337,7 +346,9 @@ def _run(args: argparse.Namespace, logger, session) -> dict:
         "latency_p99_ms": round(p99, 3),
         "cold_entities": int(cold),
         "compiled_programs": fleet.compilations,
+        "compiled_during_traffic": fleet.compilations - warm_compilations,
         "replicas": args.replicas,
+        "replica_devices": replica_devices,
         "replica_backend": args.replica_backend,
         "supervised": bool(args.supervise),
         "replica_deaths": int(_counter("serving.replica_deaths")),

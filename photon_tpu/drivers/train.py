@@ -108,7 +108,8 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-def _run_streaming(args: argparse.Namespace, logger, session) -> dict:
+def _run_streaming(args: argparse.Namespace, logger, session,
+                   device: dict) -> dict:
     """Host-streamed lambda sweep (data beyond device memory; lbfgs)."""
     import glob as globmod
 
@@ -343,15 +344,15 @@ def _run_streaming(args: argparse.Namespace, logger, session) -> dict:
         return {"streaming": True, "rank": jax.process_index()}
     return common.select_and_save_sweep(
         sweep, evaluators, val_batch is not None, index_map, args, logger,
-        extra_summary={"optimizer": "lbfgs", "streaming": True},
+        extra_summary={"optimizer": "lbfgs", "streaming": True,
+                       "device": device},
         telemetry=session,
     )
 
 
 def run(args: argparse.Namespace) -> dict:
     distributed = common.maybe_init_distributed(args)
-    if not distributed:
-        common.select_backend(args.backend)
+    device = common.select_backend(args.backend)
     from photon_tpu.utils import PhotonLogger
 
     logger = PhotonLogger("photon_tpu.train", args.log_file)
@@ -372,7 +373,7 @@ def run(args: argparse.Namespace) -> dict:
                     f"{args.checkpoint_dir!r}"
                 )
         if getattr(args, "stream", False):
-            return _run_streaming(args, logger, session)
+            return _run_streaming(args, logger, session, device)
         if distributed:
             # The resident-data path has no work to split across processes —
             # every rank would redundantly load the full dataset and race on
@@ -384,10 +385,11 @@ def run(args: argparse.Namespace) -> dict:
                 "resident-data path is single-process; use --stream for "
                 "multi-process)"
             )
-        return _run_resident(args, logger, session)
+        return _run_resident(args, logger, session, device)
 
 
-def _run_resident(args: argparse.Namespace, logger, session) -> dict:
+def _run_resident(args: argparse.Namespace, logger, session,
+                  device: dict) -> dict:
     """Device-resident lambda sweep (the default path)."""
     # Imports after backend pinning (device init happens on first jax use).
     import jax
@@ -670,7 +672,8 @@ def _run_resident(args: argparse.Namespace, logger, session) -> dict:
 
     return common.select_and_save_sweep(
         sweep, evaluators, val_batch is not None, index_map, args, logger,
-        extra_summary={"optimizer": optimizer}, telemetry=session,
+        extra_summary={"optimizer": optimizer, "device": device},
+        telemetry=session,
     )
 
 

@@ -426,17 +426,18 @@ def _has_published_checkpoint(checkpoint_dir) -> bool:
 
 
 def run(args: argparse.Namespace) -> dict:
-    common.maybe_init_distributed(args) or common.select_backend(args.backend)
+    common.maybe_init_distributed(args)
+    device = common.select_backend(args.backend)
     from photon_tpu.utils import PhotonLogger
 
     logger = PhotonLogger("photon_tpu.train_game", args.log_file)
     with common.telemetry_run(
         args, "train_game", logger, preemptible=True
     ) as session:
-        return _run(args, logger, session)
+        return _run(args, logger, session, device)
 
 
-def _run(args: argparse.Namespace, logger, session) -> dict:
+def _run(args: argparse.Namespace, logger, session, device: dict) -> dict:
     from photon_tpu.evaluation.evaluators import (
         MultiEvaluator,
         default_evaluators_for_task,
@@ -822,6 +823,7 @@ def _run(args: argparse.Namespace, logger, session) -> dict:
         )
     summary = {
         "task": args.task,
+        "device": device,
         "best_configuration": best.configuration.name,
         "best_metrics": best.metrics,
         "sweep": [

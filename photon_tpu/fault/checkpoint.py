@@ -555,6 +555,19 @@ class CheckpointPublisherBase:
         any other guarded write; an exhausted retry raises — a run that
         cannot checkpoint is a failed run, not a silently unprotected one."""
         if not self._should_write():
+            # A globally-sharded array is fetched by a COLLECTIVE
+            # (parallel/mesh.to_host -> process_allgather): every rank must
+            # take part, in the writer's order, or the writer hangs in it.
+            import jax
+
+            from photon_tpu.parallel.mesh import to_host
+
+            for value in arrays.values():
+                if (isinstance(value, jax.Array)
+                        and not value.is_fully_addressable):
+                    # host-sync: the non-writer's half of the writer's
+                    # checkpoint-staging gather (same off-hot-path fetch).
+                    to_host(value)
             return None
         t0 = time.monotonic()
         # The d2h-staging fault window: a kill here (or anywhere before the
@@ -745,8 +758,6 @@ class DescentCheckpointer(CheckpointPublisherBase):
         """Stage + publish ``state``; returns the checkpoint path (None on
         non-writing ranks).  See :meth:`CheckpointPublisherBase.save_arrays`
         for the sync/async semantics."""
-        if not self._should_write():
-            return None
         arrays, models_meta = _models_to_arrays("m", state.models)
         # When the best model IS the current iterate (the common improving-
         # run case), its coordinate models are the same objects as
@@ -894,8 +905,6 @@ class StreamCheckpointer(CheckpointPublisherBase):
     KIND = "stream-lbfgs"
 
     def save(self, state: StreamState) -> Optional[str]:
-        if not self._should_write():
-            return None
         payload = {
             "version": STATE_VERSION,
             "kind": self.KIND,

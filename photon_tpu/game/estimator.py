@@ -288,7 +288,36 @@ class GameEstimator:
             # The coordinates' own telemetry (bin-occupancy gauges,
             # warm-start transfer counters) lands in the run's session.
             coord.telemetry = self.telemetry
+            self._record_placement(name, coord.device_data)
         return coords
+
+    def _record_placement(self, name: str, device_data) -> None:
+        """``placement.devices`` / ``placement.slices`` per coordinate, read
+        off the training arrays themselves: how many devices hold the
+        fixed effect's rows (or the random effect's entity blocks) and how
+        many DISTINCT slices they hold — equal to the mesh size when the
+        data is really sharded, 1 slice when it sits replicated or on one
+        device whatever the mesh says."""
+        from photon_tpu.game.coordinate import FixedEffectDeviceData
+
+        if isinstance(device_data, FixedEffectDeviceData):
+            arrays = [device_data.batch.label]
+        else:
+            arrays = [b["label"] for b in device_data.device_buckets]
+        if not arrays:
+            return
+        devices = set()
+        slices = []
+        for arr in arrays:
+            shards = arr.addressable_shards
+            devices |= {s.device for s in shards}
+            slices.append(len({str(s.index) for s in shards}))
+        self.telemetry.gauge("placement.devices", coordinate=name).set(
+            len(devices)
+        )
+        self.telemetry.gauge("placement.slices", coordinate=name).set(
+            min(slices)
+        )
 
     # -- streamed (out-of-core) mode -----------------------------------------
     def _stream_plan(self):

@@ -1,15 +1,20 @@
 """Lazy on-demand build of the native shared library.
 
-Compiles ``src/*.cpp`` into ``_photon_native.so`` with g++ the first time a
-native entry point is used (and whenever a source is newer than the built
-library).  Failures are cached for the process so a missing toolchain costs
-one attempt, not one per call.
+Compiles ``src/*.cpp`` with g++ the first time a native entry point is
+used.  The library's file name carries a hash of the sources
+(``_photon_native.<digest>.so``), so a binary built from other sources —
+a tree copy that kept an old ``.so``, an archive extraction that reset
+mtimes — is never loaded: the name does not match and the library is
+rebuilt.  Failures are cached for the process so a missing toolchain
+costs one attempt, not one per call, and :func:`status` says why.
 """
 
 from __future__ import annotations
 
 import ctypes
 import glob
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -17,28 +22,38 @@ from typing import Optional
 
 _HERE = os.path.dirname(__file__)
 _SRC_DIR = os.path.join(_HERE, "src")
-_LIB_PATH = os.path.join(_HERE, "_photon_native.so")
+_LOG = logging.getLogger("photon_tpu.native")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _failed = False
+_status = "not loaded"
 
 
 def native_disabled() -> bool:
     return os.environ.get("PHOTON_TPU_NO_NATIVE", "") not in ("", "0")
 
 
-def _needs_build(sources: list[str]) -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(os.path.getmtime(s) > lib_mtime for s in sources)
+def status() -> str:
+    """``built`` | ``loaded`` (an up-to-date binary was already there) |
+    ``unavailable (<why>)`` | ``not loaded`` (nothing asked for it yet)."""
+    return _status
 
 
-def _compile(sources: list[str]) -> bool:
+def _lib_path(sources: list[str]) -> str:
+    h = hashlib.sha256()
+    for path in sources:
+        h.update(os.path.basename(path).encode())
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_HERE, f"_photon_native.{h.hexdigest()[:12]}.so")
+
+
+def _compile(sources: list[str], lib_path: str) -> Optional[str]:
+    """Build ``lib_path``; returns None on success, else why it failed."""
     # Compile to a process-unique temp path and os.replace() atomically:
     # concurrent first-use builds must never CDLL a half-written .so.
-    tmp_path = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp_path = f"{lib_path}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
         "-o", tmp_path, *sources,
@@ -48,17 +63,29 @@ def _compile(sources: list[str]) -> bool:
             cmd, capture_output=True, text=True, timeout=300
         )
         if proc.returncode != 0 or not os.path.exists(tmp_path):
-            return False
-        os.replace(tmp_path, _LIB_PATH)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+            _LOG.warning(
+                "native build failed (g++ rc=%d):\n%s",
+                proc.returncode, proc.stderr[-4000:],
+            )
+            return f"g++ exited {proc.returncode}"
+        os.replace(tmp_path, lib_path)
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        _LOG.warning("native build failed: %s: %s", type(exc).__name__, exc)
+        return f"{type(exc).__name__}: {exc}"
     finally:
         if os.path.exists(tmp_path):
             try:
                 os.unlink(tmp_path)
             except OSError:
                 pass
-    return True
+    # Binaries of other source revisions are dead weight now.
+    for stale in glob.glob(os.path.join(_HERE, "_photon_native*.so")):
+        if stale != lib_path:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
+    return None
 
 
 def _declare(lib: ctypes.CDLL) -> None:
@@ -103,8 +130,9 @@ def _declare(lib: ctypes.CDLL) -> None:
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """The native library, building it if needed; None when unavailable."""
-    global _lib, _failed
+    """The native library, building it if needed; None when unavailable
+    (:func:`status` says why — the Python readers then run instead)."""
+    global _lib, _failed, _status
     if native_disabled():
         return None
     if _lib is not None:
@@ -116,41 +144,20 @@ def get_lib() -> Optional[ctypes.CDLL]:
             return _lib
         sources = sorted(glob.glob(os.path.join(_SRC_DIR, "*.cpp")))
         if not sources:
-            _failed = True
+            _failed, _status = True, "unavailable (no sources)"
             return None
-        if _needs_build(sources) and not _compile(sources):
-            _failed = True
-            return None
-        lib = None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            _declare(lib)
-        except (OSError, AttributeError):
-            # AttributeError: a stale prebuilt .so predating newly declared
-            # symbols (mtime >= sources, so _needs_build skipped the
-            # rebuild — e.g. archive extraction resets mtimes).  One forced
-            # rebuild from the present sources before giving up; without it
-            # a single stale artifact permanently demotes EVERY native
-            # entry point (readers included) to the Python fallbacks.
-            # dlclose the stale handle first: the loader caches by
-            # pathname, so re-dlopening the same path would hand back the
-            # old link map even after os.replace swapped the file.
-            if lib is not None:
-                try:
-                    import _ctypes
-
-                    _ctypes.dlclose(lib._handle)
-                except Exception:  # noqa: BLE001 - best-effort unload
-                    pass
-                lib = None
-            if not _compile(sources):
-                _failed = True
-                return None
+        lib_path = _lib_path(sources)
+        built = not os.path.exists(lib_path)
+        why = _compile(sources, lib_path) if built else None
+        if why is None:
             try:
-                lib = ctypes.CDLL(_LIB_PATH)
+                lib = ctypes.CDLL(lib_path)
                 _declare(lib)
-            except (OSError, AttributeError):
-                _failed = True
-                return None
-        _lib = lib
+            except (OSError, AttributeError) as exc:
+                why = f"{type(exc).__name__}: {exc}"
+                _LOG.warning("native library %s unusable: %s", lib_path, why)
+        if why is not None:
+            _failed, _status = True, f"unavailable ({why})"
+            return None
+        _lib, _status = lib, "built" if built else "loaded"
     return _lib

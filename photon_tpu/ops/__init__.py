@@ -2,12 +2,6 @@
 
 The reference's innermost loops run per-partition on Breeze/BLAS via JNI
 (SURVEY.md §2.4); here the device compute path is XLA, with Pallas kernels
-where fusion beyond XLA's reach pays — currently the fused sparse GLM
-value-and-gradient pass (:mod:`photon_tpu.ops.pallas_sparse`)."""
-
-from photon_tpu.ops.pallas_sparse import (
-    fused_value_and_grad,
-    pallas_enabled,
-)
-
-__all__ = ["fused_value_and_grad", "pallas_enabled"]
+where data movement beyond XLA's reach pays — the slab-aligned sparse
+gradient (:mod:`photon_tpu.ops.pallas_gather`), selected at run time by
+:mod:`photon_tpu.ops.sparse_grad_select`."""

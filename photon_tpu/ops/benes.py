@@ -1,10 +1,9 @@
 """The `benes` sparse kernel: value/gradient/Hv with NO random E-access.
 
-Fourth production kernel behind ops/sparse_grad_select (after fm /
-autodiff / pallas).  The round-4 hardware windows pinned every existing
-kernel to ~0.1% of HBM roofline because each pays at least one random
-E-element gather or scatter (ops/KERNEL_NOTES.md, round-4 verdicts); this
-kernel eliminates them:
+Explicit opt-in kernel behind ops/sparse_grad_select (after fm /
+autodiff / pallas).  Each of those pays at least one random E-element
+gather or scatter per direction (ops/KERNEL_NOTES.md); this kernel
+eliminates them:
 
 - FORWARD (margins / ``X u``): per-entry products come from the
   slab-aligned Pallas gather (``w[dup_map]`` is a small dictionary
@@ -46,6 +45,7 @@ from photon_tpu.ops.clos import (
     invert_route,
     route_permutation,
 )
+from photon_tpu.utils.device import pallas_interpret
 
 Array = jax.Array
 
@@ -126,7 +126,7 @@ def benes_xu_product(u: Array, al, aux: BenesAux, n: int, k: int,
     from photon_tpu.ops.pallas_gather import LANES, aligned_gather_products
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     u2d = jnp.take(u, al.dup_map, axis=0).reshape(-1, LANES)
     pw = aligned_gather_products(
         u2d, al.slab_of_tile, al.lo, al.vals, interpret=bool(interpret)
@@ -143,7 +143,7 @@ def benes_segment_grad(per_row: Array, vals_rowmajor: Array, al,
     from photon_tpu.ops.pallas_gather import aligned_reduce
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     pv_row = (per_row[:, None] * vals_rowmajor).astype(jnp.float32)
     flat = _pad_to_grid(pv_row.reshape(-1), aux)
     slots = apply_clos_grid(flat, aux.to_slots)[: aux.n_slots]

@@ -50,6 +50,8 @@ import numpy as np
 from jax import tree_util
 from jax.experimental import pallas as pl
 
+from photon_tpu.utils.device import pallas_interpret
+
 Array = jax.Array
 
 LANES = 128
@@ -659,7 +661,7 @@ def aligned_segment_grad(
        into the ``dim`` coefficients (duplicated features merge here).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     pv = (
         jnp.take(per_row, al.rows.reshape(-1), axis=0).reshape(al.rows.shape)
         * al.vals
@@ -679,7 +681,7 @@ def aligned_reduce(
     products by static permutation instead of the E-gather and enters
     here."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     partial = _position_partial_sums(
         al.slab_of_tile, pv, al.lo, n_slabs=al.n_slabs, interpret=bool(interpret)
     )
@@ -687,39 +689,6 @@ def aligned_reduce(
     return jax.ops.segment_sum(
         flat, al.sorted_feats, num_segments=dim, indices_are_sorted=True
     )
-
-
-_REDUCE_SUPPORTED: dict = {}
-
-
-def reduce_kernel_supported() -> bool:
-    """Eager Mosaic capability probe for the position-reduce kernel (cached
-    per backend).  Same rationale as ops/pallas_sparse.kernel_supported: a
-    lowering failure surfaces when the ENCLOSING jit compiles, so probe
-    compiled (non-interpret) lowering once, eagerly, on a one-tile input."""
-    backend = jax.default_backend()
-    if backend not in _REDUCE_SUPPORTED:
-        try:
-            # Probe inputs under ensure_compile_time_eval: the first call
-            # often happens while an enclosing jit is being traced
-            # (kernel selection at trace time), where bare jnp.zeros
-            # would become tracers, the probe would raise, and the except
-            # would cache a spurious "unsupported" for the whole process.
-            # The lower/compile itself stays OUTSIDE the escape hatch
-            # (eval-trace has no rules for pallas primitives).
-            with jax.ensure_compile_time_eval():
-                probe_args = (
-                    jnp.zeros(1, jnp.int32),
-                    jnp.zeros((TILE_SUBLANES, LANES), jnp.float32),
-                    jnp.zeros((TILE_SUBLANES, LANES), jnp.int32),
-                )
-            _position_partial_sums.lower(
-                *probe_args, n_slabs=1, interpret=False
-            ).compile()
-            _REDUCE_SUPPORTED[backend] = True
-        except Exception:  # noqa: BLE001 — any lowering failure means "no"
-            _REDUCE_SUPPORTED[backend] = False
-    return _REDUCE_SUPPORTED[backend]
 
 
 def aligned_grad_reference(

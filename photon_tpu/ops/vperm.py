@@ -1,13 +1,22 @@
-"""vperm: fast static E-element permutations from measured-fast primitives.
+"""vperm: static E-element permutations from lane-local primitives.
 
-The round-4 chained hardware probes (ops/KERNEL_NOTES.md, third window)
-showed this chip runs data-DEPENDENT XLA ops at 23–275 Melem/s (gather
-68, row-wise gather 70, sort 275, 3-stage XLA Clos 23) while pallas
-lane-local gathers run at 3.4 Gelem/s and XLA strided transposes at
-14 GB/s.  The sparse-GLM hot loop needs exactly one data-dependent
-movement per direction — the static row-order ↔ feature-order exchange
-of the entry stream — so routing that exchange through the fast
-primitives is the whole performance ballgame.
+The sparse-GLM hot loop needs exactly one data-DEPENDENT movement per
+direction — the static row-order ↔ feature-order exchange of the entry
+stream — and XLA lowers it as a random gather or scatter.  This module
+routes that exchange through lane-local Pallas gathers and strided XLA
+transposes instead.
+
+STATUS ON THE v5e (jax 0.9.0 / libtpu 0.0.34, PR 21 chip run): the chunk
+kernel does NOT lower.  Its middle stage is a ``take_along_axis`` along a
+CH-wide lane axis of the transposed ``[128, CH]`` chunk, and Mosaic
+refuses any CH over one vreg: "Not implemented: Multiple source vregs
+along gather dimension" (CH = 256, 2048, 4096 all refused; CH = 8 and the
+lane-only middle pass compile and match).  Any exchange over 2^14
+entries has CH > 128 (production geometries use 2048 or 4096), so the
+``xchg`` kernel runs in interpret mode only; it is an
+explicit opt-in (``PHOTON_SPARSE_GRAD=xchg``), never an auto candidate,
+and on a TPU that opt-in fails at compile.  Whether it is rebuilt around
+one-vreg gathers or deleted is ROADMAP D3.
 
 Decomposition (two-level Clos, all stages static, routed on host):
 
@@ -57,6 +66,7 @@ import jax.numpy as jnp
 from jax import tree_util
 
 from photon_tpu.ops.clos import route_permutation
+from photon_tpu.utils.device import pallas_interpret
 
 Array = jax.Array
 
@@ -460,8 +470,7 @@ def build_xchg_route(layout, n: int, k: int) -> VpermRoute:
     ``src``).  The returned route feeds ops/pallas_gather.aligned_reduce:
     ``apply_vperm(products_rowmajor, route)`` is the slot stream, with
     pad slots carrying zeros.  This replaces the per-step E-element XLA
-    ``per_row[rows]`` gather (measured 493 ms at E=2^25, third window)
-    with the 3-pass vperm pipeline.
+    ``per_row[rows]`` gather with the 3-pass vperm pipeline.
     """
     n_rm = n * k
     slots_src = layout.src.reshape(-1)
@@ -775,65 +784,6 @@ def _chunk_expand_kernel(dz_ref, i1_ref, i2_ref, i3_ref, o_ref):
     o_ref[...] = _micro_clos_body(y, i1_ref, i2_ref, i3_ref)
 
 
-_EXPAND_SUPPORTED: dict = {}
-
-
-def expand_kernel_supported(k: int = 32,
-                            dtype=jnp.float32) -> bool:
-    """Eager Mosaic capability probe for the fused dz-expansion kernel
-    (jnp.repeat along lanes), cached per (backend, k, dtype) — the
-    exact configuration that will run, since narrow-lane tiles and
-    bf16 gathers can lower differently.  A lowering failure would
-    otherwise surface only when the optimizer's enclosing jit
-    compiles."""
-    backend = jax.default_backend()
-    key = (backend, int(k), jnp.dtype(dtype).name)
-    if key not in _EXPAND_SUPPORTED:
-        if backend != "tpu":
-            _EXPAND_SUPPORTED[key] = True  # interpret mode
-        else:
-            from jax.experimental import pallas as pl
-
-            try:
-                f = pl.pallas_call(
-                    _chunk_expand_kernel,
-                    out_shape=jax.ShapeDtypeStruct((8, LANES), dtype),
-                    grid=(1,),
-                    in_specs=[
-                        pl.BlockSpec((8, LANES // k), lambda i: (i, 0)),
-                        pl.BlockSpec((8, LANES), lambda i: (i, 0)),
-                        pl.BlockSpec((LANES, 8), lambda i: (i, 0)),
-                        pl.BlockSpec((8, LANES), lambda i: (i, 0)),
-                    ],
-                    out_specs=pl.BlockSpec((8, LANES), lambda i: (i, 0)),
-                )
-                # ensure_compile_time_eval + jit: first call may happen
-                # inside an enclosing jit trace (kernel routing at trace
-                # time); staged probe inputs would raise and cache a
-                # spurious "unsupported" (same rationale as
-                # pallas_gather.reduce_kernel_supported).  The jit wrap
-                # matters: a BARE pallas_call under the escape hatch hits
-                # eval-trace rules (program_id has none).
-                with jax.ensure_compile_time_eval():
-                    jax.block_until_ready(jax.jit(f)(
-                        jnp.ones((8, LANES // k), dtype),
-                        jnp.zeros((8, LANES), jnp.int8),
-                        jnp.zeros((LANES, 8), jnp.int16),
-                        jnp.zeros((8, LANES), jnp.int8),
-                    ))
-                _EXPAND_SUPPORTED[key] = True
-            except Exception as exc:  # noqa: BLE001 — fall back
-                import logging
-
-                logging.getLogger("photon_tpu.vperm").warning(
-                    "fused dz-expansion kernel unavailable on %s "
-                    "(k=%d, %s): %s — using the streamed exchange path",
-                    backend, k, jnp.dtype(dtype).name, exc,
-                )
-                _EXPAND_SUPPORTED[key] = False
-    return _EXPAND_SUPPORTED[key]
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def apply_balanced_dz(dz: Array, route: BalancedRoute,
                       interpret: bool = False) -> Array:
@@ -913,15 +863,6 @@ def apply_balanced(x: Array, route: BalancedRoute,
 # Versioned PER MODE so bumping one builder doesn't invalidate the other
 # mode's (expensive) cached routes.
 _ROUTE_CACHE_VERSION = {"aligned": 2, "cumsum": 3}
-
-
-def _default_route_cache_root() -> str:
-    """Back-compat alias — the shared resolution lives in
-    photon_tpu.utils.caches (one contract for route/layout/stream
-    caches)."""
-    from photon_tpu.utils.caches import default_route_cache_root
-
-    return default_route_cache_root()
 
 
 def _route_cache_path(ids: np.ndarray, dim: int, mode: str, layout,
@@ -1163,7 +1104,7 @@ def bake_vals_dest(aux: XchgAux, vals: np.ndarray) -> XchgAux:
 
     if not (aux.bounds is not None or isinstance(aux.route, BalancedRoute)):
         return aux
-    interp = jax.default_backend() != "tpu"
+    interp = pallas_interpret()
     flat_np = np.asarray(vals, np.float32).reshape(-1)
     flat = jnp.asarray(flat_np)
     if isinstance(aux.route, BalancedRoute):
@@ -1215,7 +1156,7 @@ def xchg_segment_grad(per_row: Array, vals_rowmajor: Array, al,
     from photon_tpu.ops.pallas_gather import aligned_reduce
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     import os
 
     if isinstance(aux, VpermRoute):  # back-compat: bare aligned route
@@ -1257,11 +1198,7 @@ def xchg_segment_grad(per_row: Array, vals_rowmajor: Array, al,
             )
     bf16 = os.environ.get("PHOTON_XCHG_DTYPE", "float32") == "bfloat16"
     balanced = isinstance(aux.route, BalancedRoute)
-    if (balanced and aux.route.k_expand and aux.vals_dest is not None
-            and expand_kernel_supported(
-                aux.route.k_expand,
-                jnp.bfloat16 if bf16 else jnp.float32,
-            )):
+    if balanced and aux.route.k_expand and aux.vals_dest is not None:
         # Fully fused fast path: the [n] dz vector expands INSIDE stage
         # A (no E-stream materialization at all) and the static values
         # multiply at the destination.

@@ -25,18 +25,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6: top-level export, `check_vma` kwarg
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental home, kwarg named `check_rep`
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_experimental(f, **kw)
 
 from photon_tpu.core.objective import GlmObjective, _static_zero
 from photon_tpu.data.batch import Batch

@@ -72,6 +72,7 @@ from photon_tpu.serving.scorer import (
     ShardSpec,
     _pad_rows,
     bucket_ladder,
+    devices_of,
     padded_cost,
     request_spec_for_model,
     slice_request,
@@ -810,11 +811,14 @@ class MultiModelScorer:
     def _donate_argnums(self) -> tuple:
         """Donate request buffers (args 1-4: feats/gidx/mslot/offset) on
         accelerators only — same CPU aliasing hazard as GameScorer."""
-        leaves = jax.tree_util.tree_leaves(self.arena.tables)
-        devices = leaves[0].devices() if leaves else set()
-        if any(d.platform == "cpu" for d in devices):
+        if any(d.platform == "cpu" for d in self.table_devices()):
             return ()
         return (1, 2, 3, 4)
+
+    def table_devices(self) -> list:
+        """The devices that hold the arena tables, by id (GameScorer's
+        contract)."""
+        return devices_of(self.arena.tables)
 
     def _compile(self, bucket: int, layout: str, tables, programs):
         program = programs.get((bucket, layout))

@@ -114,8 +114,8 @@ class ServingFleet:
     scorers in this process, per-replica sub-meshes via ``devices``) or
     ``"subprocess"`` (ISSUE 13 — each replica is a CHILD PROCESS with its
     own Python/jax runtime speaking the frame protocol over loopback,
-    devices dealt per child via ``JAX_PLATFORMS``/visible-device env; the
-    shared model artifact lives under ``workdir``).  ``supervise()``
+    on the host platform only — refused under a TPU parent, which holds
+    the chip; the shared model artifact lives under ``workdir``).  ``supervise()``
     attaches the self-healing supervisor — health probes, canary-gated
     resurrection, flap quarantine — over either backend.
     """
@@ -197,9 +197,22 @@ class ServingFleet:
             from photon_tpu.serving.replica_proc import (
                 ModelStore,
                 SubprocessReplica,
-                child_device_env,
             )
+            from photon_tpu.utils.device import device_facts
 
+            # A chip belongs to one process: this parent has touched JAX
+            # (it loaded the model), so on a TPU it HOLDS the chip and a
+            # child that needs it would fail or hang in backend init.
+            # Refuse here instead; replicas on chips use the thread
+            # backend, one device each (ROADMAP D7 is the redesign).
+            platform = device_facts()["platform"]
+            if platform != "cpu":
+                raise RuntimeError(
+                    f"ServingFleet(backend='subprocess') cannot run under "
+                    f"a parent on platform {platform!r}: the parent process "
+                    "holds the chip, so child replicas could never open "
+                    "it.  Use backend='thread' (one device per replica)"
+                )
             if workdir is None:
                 workdir = tempfile.mkdtemp(prefix="photon-fleet-")
                 self._workdir_owned = True
@@ -214,8 +227,7 @@ class ServingFleet:
             spec = request_spec or request_spec_for_model(model)
             try:
                 for i in range(int(replicas)):
-                    env = dict(child_device_env(i, int(replicas)))
-                    env.update(child_env or {})
+                    env = {"JAX_PLATFORMS": "cpu", **(child_env or {})}
                     self.replicas.append(
                         SubprocessReplica(
                             f"r{i}", model, self._store,

@@ -15,10 +15,11 @@ supervised from above — is the shape, PAPERS.md 1803.06333):
   supervision vocabulary on a control connection: ``ping``/``pong``
   (liveness), ``swap`` (hot-swap to a newer model artifact, zero child
   recompiles — the scorer's capacity-headroom swap), ``shutdown``.
-  Device ownership comes from the environment the parent deals each child
-  (``JAX_PLATFORMS`` + visible-device vars): on a multi-core/multi-device
-  host each child owns its runtime and its devices; on the 1-core CPU
-  fixture children share the core (the PR 12 honest-scaling bar applies).
+  Children run on the HOST platform (``JAX_PLATFORMS=cpu``): a chip
+  belongs to one process and the parent, which loaded the model with JAX,
+  already holds it — ``ServingFleet(backend="subprocess")`` refuses to
+  start under a TPU parent; replicas on chips are thread replicas, one
+  device each.
 - **The parent side** (:class:`SubprocessReplica`) is a drop-in
   :class:`~photon_tpu.serving.router.ScorerReplica`: the router's
   batcher coalesces requests exactly as for a thread replica, and the
@@ -600,32 +601,6 @@ def _child_main(argv=None) -> None:
 
 
 # -- the parent side -----------------------------------------------------------
-
-
-def child_device_env(index: int, n_replicas: int) -> Dict[str, str]:
-    """The per-child device deal: each child pins the parent's platform via
-    ``JAX_PLATFORMS`` and, on device-backed platforms, owns a round-robin
-    slice of the visible devices — process-level replica isolation with
-    real per-replica device ownership.  The slice is cut from the
-    PARENT'S OWN visibility mask when one is set (``CUDA_VISIBLE_DEVICES=
-    2,3`` must deal ``2``/``3`` to the children, never absolute ids the
-    job was fenced away from).  On CPU there is nothing to deal (children
-    share the host's cores; the honest 1-core bar applies)."""
-    import jax
-
-    platform = jax.default_backend()
-    env = {"JAX_PLATFORMS": platform}
-    if platform in ("gpu", "cuda", "rocm", "tpu"):
-        var = ("TPU_VISIBLE_DEVICES" if platform == "tpu"
-               else "CUDA_VISIBLE_DEVICES")
-        mask = os.environ.get(var, "").strip()
-        if mask:
-            ids = [t.strip() for t in mask.split(",") if t.strip()]
-        else:
-            ids = [str(i) for i in range(jax.local_device_count())]
-        mine = ids[index % len(ids):: n_replicas] or [ids[index % len(ids)]]
-        env[var] = ",".join(mine)
-    return env
 
 
 class _RemoteScorer:

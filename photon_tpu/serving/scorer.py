@@ -85,6 +85,14 @@ def bucket_ladder(
     return tuple(sorted(set(int(b) for b in buckets)))
 
 
+def devices_of(tables) -> list:
+    """The devices holding any leaf of ``tables``, sorted by id."""
+    devices = set()
+    for leaf in jax.tree_util.tree_leaves(tables):
+        devices |= leaf.devices()
+    return sorted(devices, key=lambda d: d.id)
+
+
 def padded_cost(n: int, buckets: Tuple[int, ...]) -> int:
     """Device rows an ``n``-row request actually COSTS through the bucket
     ladder: the smallest holding bucket, with oversize requests chunked
@@ -599,11 +607,15 @@ class GameScorer:
         accelerators only.  See the comment at the jit site: on CPU the
         placed buffers can alias the staged host memory and each other
         across replicas, and donating an aliased buffer corrupts scores."""
-        leaves = jax.tree_util.tree_leaves(self._tables)
-        devices = leaves[0].devices() if leaves else set()
-        if any(d.platform == "cpu" for d in devices):
+        if any(d.platform == "cpu" for d in self.table_devices()):
             return ()
         return (2, 3, 4)
+
+    def table_devices(self) -> list:
+        """The devices that hold this scorer's serving tables, by id — what
+        a summary reports so "each replica on its own chip" is read off the
+        arrays, not off the mesh the replica was handed."""
+        return devices_of(self._tables)
 
     # -- program build -------------------------------------------------------
     def _program(self, bucket: int, layout: str = "request"):

@@ -161,7 +161,13 @@ class TelemetrySession:
                      error: Optional[str] = None,
                      extra: Optional[dict] = None) -> dict:
         from photon_tpu.telemetry.report import capture_environment
+        from photon_tpu.utils.device import kernel_metrics
 
+        metrics = self.registry.snapshot()
+        # Kernel refusals/selections are process-wide facts (recorded at
+        # trace time, far from any session): every report of the process
+        # carries them, so a refused kernel can never go unseen.
+        metrics["counters"] = metrics["counters"] + kernel_metrics()
         report = {
             "driver": self.driver,
             "run_id": self.run_id,
@@ -171,7 +177,7 @@ class TelemetrySession:
             "duration_s": time.monotonic() - self._t0,
             "environment": capture_environment(),
             "phase_totals": self.tracer.phase_totals() if self.tracer else {},
-            "metrics": self.registry.snapshot(),
+            "metrics": metrics,
             "spans": self.tracer.export() if self.tracer else [],
         }
         if extra:

@@ -11,26 +11,16 @@ so the contract cannot drift between hand-rolled copies.
 
 from __future__ import annotations
 
-import functools
 import os
 from typing import Optional
 
-
-@functools.lru_cache(maxsize=1)
-def default_route_cache_root() -> str:
-    """Resolve the default cache root ONCE per process: back-compat
-    honors an existing CWD cache (pre-round-5 default, and how this
-    host's pre-built production routes are stored); otherwise cache
-    files stay out of the working directory (ADVICE r4) under the
-    conventional user cache root.  Memoized so a mid-process chdir
-    cannot flip the location and split a cache across two roots
-    (the env overrides are still read per call by callers)."""
-    legacy = os.path.abspath(".photon_route_cache")
-    if os.path.isdir(legacy):
-        return legacy
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "photon_tpu", "routes"
-    )
+# Anchored to the checkout (this file's location), never to the working
+# directory or $HOME: two processes started from different directories
+# must share ONE cache, and a sealed machine has no $HOME worth keeping.
+CHECKOUT_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+DEFAULT_ROUTE_CACHE_ROOT = os.path.join(CHECKOUT_ROOT, ".photon_route_cache")
 
 
 def resolve_cache_dir(env_name: str, subdir: str) -> Optional[str]:
@@ -52,5 +42,5 @@ def resolve_cache_dir(env_name: str, subdir: str) -> Optional[str]:
     if base == "0":
         return None
     if base is None:
-        base = default_route_cache_root()
+        base = DEFAULT_ROUTE_CACHE_ROOT
     return os.path.join(base, subdir) if subdir else base

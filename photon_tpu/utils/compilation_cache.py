@@ -1,44 +1,39 @@
-"""Shared persistent-XLA-compilation-cache setup.
+"""The one persistent-compile-cache contract.
 
-One implementation behind both the drivers (every CLI run) and bench.py —
-driver programs are identical run-to-run, so caching them cuts a repeat
-GAME fit from ~14 s to ~3 s on a 1-core host (the analog of the reference
-benefitting from a warmed JVM).
+If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses that directory
+and this module sets no other.  If it is not, the cache lives at ONE fixed
+path inside the checkout (``<repo>/.jax_cache``, git-ignored), resolved
+from this file — never from ``$HOME``, ``$TMPDIR``, a pid, a time or a
+digest: the directory is part of JAX's cache key, so a cache that moves
+never hits.  Drivers, ``bench.py`` and ``chip_smoke.py`` all come through
+:func:`enable`; child processes inherit the directory through the
+environment.
 """
 
 from __future__ import annotations
 
 import os
 
+from photon_tpu.utils.caches import CHECKOUT_ROOT
 
-def enable(
-    env_var: str,
-    default_dir: str,
-    min_compile_secs: float = 0.2,
-    respect_existing: bool = True,
-) -> None:
-    """Point JAX's persistent compilation cache at ``$env_var`` (or
-    ``default_dir``).  ``$env_var`` set to ``0``/``off``/``none``/
-    ``disabled`` disables; with ``respect_existing`` a cache dir already
-    configured (tests, an enclosing tool, the operator) wins.  Best-effort:
-    never raises.
-    """
+_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
+
+
+def enable() -> str:
+    """Make sure a persistent compilation cache is on; return its
+    directory.  Call before the first compile."""
     import jax
 
-    spec = os.environ.get(env_var, "")
-    if spec.lower() in ("0", "off", "none", "disabled"):
-        return
-    try:
-        if respect_existing and jax.config.jax_compilation_cache_dir:
-            return
-        jax.config.update("jax_compilation_cache_dir", spec or default_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_secs
-        )
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as ex:  # noqa: BLE001 — caching is best-effort, never fatal
-        import logging
-
-        logging.getLogger("photon_tpu.compilation_cache").warning(
-            "persistent compilation cache disabled: %s", ex
-        )
+    cache_dir = os.environ.get(_ENV_VAR)
+    if not cache_dir:
+        cache_dir = DEFAULT_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # Exported so subprocess replicas and bench workers share it.
+        os.environ[_ENV_VAR] = cache_dir
+    # Driver programs are many and small (one per size bin / bucket); the
+    # JAX defaults (>= 1 s compiles only) would leave most of a warm run
+    # recompiling.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return cache_dir

@@ -146,7 +146,6 @@ def _counter_total(session, name, **labels):
 
 def _compile_listener():
     import jax.monitoring
-    from jax._src import monitoring as monitoring_src
 
     events = []
 
@@ -158,7 +157,7 @@ def _compile_listener():
         jax.monitoring.register_event_listener(listener)
 
     def detach():
-        monitoring_src._unregister_event_listener_by_callback(listener)
+        jax.monitoring.unregister_event_listener(listener)
 
     return events, attach, detach
 

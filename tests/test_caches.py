@@ -37,7 +37,7 @@ def test_route_cache_resolves_own_root(monkeypatch):
     assert resolve_cache_dir("PHOTON_ROUTE_CACHE", "") == "/tmp/routes"
     monkeypatch.delenv("PHOTON_ROUTE_CACHE", raising=False)
     root = resolve_cache_dir("PHOTON_ROUTE_CACHE", "")
-    assert root is not None  # default root (memoized per process)
+    assert root is not None  # default root
 
 
 def test_override_wins_even_when_route_cache_disabled(monkeypatch):
@@ -50,23 +50,81 @@ def test_override_wins_even_when_route_cache_disabled(monkeypatch):
     assert resolve_cache_dir("PHOTON_LAYOUT_CACHE", "layouts") == "/tmp/explicit"
 
 
-def test_default_root_location(monkeypatch, tmp_path):
-    """The default root must honor an existing CWD legacy cache, else
-    fall under ~/.cache (the ADVICE-r4 no-CWD-pollution contract) —
-    'is not None' alone would let a wrong location regress silently."""
+def test_default_root_is_anchored_to_the_checkout(monkeypatch, tmp_path):
+    """The default root is resolved from the package location — not the
+    working directory, not $HOME — so processes started from different
+    directories share one cache."""
     from photon_tpu.utils import caches
 
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.delenv("PHOTON_ROUTE_CACHE", raising=False)
-    caches.default_route_cache_root.cache_clear()
-    monkeypatch.chdir(tmp_path)  # no legacy dir here
-    try:
-        assert caches.default_route_cache_root() == os.path.join(
-            os.path.expanduser("~"), ".cache", "photon_tpu", "routes"
-        )
-        caches.default_route_cache_root.cache_clear()
-        os.makedirs(tmp_path / ".photon_route_cache")
-        assert caches.default_route_cache_root() == str(
-            tmp_path / ".photon_route_cache"
-        )
-    finally:
-        caches.default_route_cache_root.cache_clear()
+    monkeypatch.setenv("HOME", str(tmp_path / "home"))
+    os.makedirs(tmp_path / ".photon_route_cache")
+    monkeypatch.chdir(tmp_path)
+    assert caches.resolve_cache_dir("PHOTON_ROUTE_CACHE", "") == os.path.join(
+        repo, ".photon_route_cache"
+    )
+
+
+# -- the one compile-cache contract (utils/compilation_cache) ----------------
+
+
+def _run_py(code, cwd, env_extra, drop=()):
+    import subprocess
+    import sys
+
+    env = {k: v for k, v in os.environ.items() if k not in drop}
+    env.update(env_extra)
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=cwd, env=env,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    return out.stdout.strip().splitlines()[-1]
+
+
+def test_compile_cache_env_var_wins(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, enable() names that directory
+    and sets no other in code."""
+    import jax
+
+    from photon_tpu.utils import compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "mine"))
+    assert compilation_cache.enable() == str(tmp_path / "mine")
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout(tmp_path):
+    """Unset, the cache is <repo>/.jax_cache — the same from two processes
+    started in different working directories (never $HOME, $TMPDIR, a pid,
+    a time or a digest), exported so children inherit it.  And the test
+    harness itself honours an externally set directory (conftest)."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        f"import sys, os; sys.path.insert(0, {repo!r}); import jax; "
+        "from photon_tpu.utils.compilation_cache import enable; "
+        "d = enable(); "
+        "print(d, jax.config.jax_compilation_cache_dir, "
+        "os.environ['JAX_COMPILATION_CACHE_DIR'])"
+    )
+    other = tmp_path / "elsewhere"
+    other.mkdir()
+    drop = ("JAX_COMPILATION_CACHE_DIR",)
+    expected = " ".join([os.path.join(repo, ".jax_cache")] * 3)
+    assert _run_py(code, repo, {"HOME": str(tmp_path)}, drop) == expected
+    assert _run_py(
+        code, str(other), {"TMPDIR": str(tmp_path)}, drop
+    ) == expected
+
+    conftest_code = (
+        f"import sys, os; sys.path.insert(0, {os.path.join(repo, 'tests')!r}); "
+        "import conftest, jax; "
+        "print(os.environ['JAX_COMPILATION_CACHE_DIR'], "
+        "jax.config.jax_compilation_cache_dir)"
+    )
+    mine = str(tmp_path / "external")
+    assert _run_py(
+        conftest_code, str(other), {"JAX_COMPILATION_CACHE_DIR": mine}
+    ) == f"{mine} {mine}"

@@ -25,6 +25,21 @@ def libsvm_files(tmp_path_factory):
     return train_p, val_p
 
 
+def test_device_policy_refuses_a_platform_nobody_asked_for(monkeypatch):
+    """--backend tpu (the default) with no JAX_PLATFORMS=cpu in the
+    environment REQUIRES a tpu: finding anything else raises, naming it."""
+    from photon_tpu.drivers import common
+
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(RuntimeError, match=r"'tpu' was asked for.*'cpu'"):
+        common.select_backend("tpu")
+    # Asked for by the environment or the flag, the host is a choice.
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert common.select_backend("tpu")["platform"] == "cpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert common.select_backend("cpu")["platform"] == "cpu"
+
+
 def test_train_driver_end_to_end(libsvm_files, tmp_path):
     train_p, val_p = libsvm_files
     out = str(tmp_path / "out")
@@ -41,6 +56,10 @@ def test_train_driver_end_to_end(libsvm_files, tmp_path):
     with open(os.path.join(out, "training_summary.json")) as f:
         persisted = json.load(f)
     assert persisted["best_lambda"] == summary["best_lambda"]
+    # Explicit CPU is a choice the summary records, not a fallback.
+    assert persisted["device"] == {
+        "platform": "cpu", "device_kind": "cpu", "device_count": 8,
+    }
     # Model should beat chance comfortably on separable-ish synthetic data.
     aucs = [e["metrics"]["AUC"] for e in summary["sweep"]]
     assert max(aucs) > 0.7

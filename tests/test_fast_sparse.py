@@ -380,29 +380,62 @@ def test_select_kernel_availability_fallbacks(monkeypatch):
 
 
 def test_measure_correctness_gate_excludes_bad_pallas(monkeypatch):
-    """A Mosaic kernel that miscompiles on the live backend must be
-    DISQUALIFIED by the probe's on-device correctness gate, never timed
-    into production eligibility; a correct kernel passes the gate."""
-    import numpy as np
-
+    """A Mosaic kernel that miscompiles — or that the compiler refuses —
+    on the live backend must be DISQUALIFIED by the probe's on-device
+    check, never timed into production eligibility, and never quietly: the
+    refusal is counted (``kernels.refused{kernel=pallas}``) and carried by
+    every run report of the process.  A correct kernel passes."""
     import photon_tpu.ops.pallas_gather as pg
     import photon_tpu.ops.sparse_grad_select as sel
+    from photon_tpu.telemetry import TelemetrySession
+    from photon_tpu.utils import device
 
     real = pg.aligned_segment_grad
 
     def garbage(per_row, al, dim, interpret=None):
         return real(per_row, al, dim, interpret=True) + 1.0  # wrong output
 
+    def refused(per_row, al, dim, interpret=None):
+        raise RuntimeError(
+            "Mosaic failed to compile TPU kernel: Not implemented\nmore"
+        )
+
     def correct(per_row, al, dim, interpret=None):
         return real(per_row, al, dim, interpret=True)  # CPU-safe, right math
 
+    monkeypatch.setattr(device, "_refused", {})
     monkeypatch.setattr(pg, "aligned_segment_grad", garbage)
     choice = sel._measure(1 << 12, 256, 256, with_pallas=True)
     assert choice in ("fm", "autodiff"), "garbage pallas must be excluded"
+    assert device.kernel_refusals()["pallas"]["error"].startswith(
+        "parity failed"
+    )
 
+    monkeypatch.setattr(device, "_refused", {})
+    monkeypatch.setattr(pg, "aligned_segment_grad", refused)
+    choice = sel._measure(1 << 12, 256, 256, with_pallas=True)
+    assert choice in ("fm", "autodiff"), "a refused pallas must be excluded"
+    assert device.kernel_refusals()["pallas"] == {
+        "count": 1,
+        "error": "Mosaic failed to compile TPU kernel: Not implemented",
+    }
+    report = TelemetrySession("t").build_report()
+    assert {
+        "name": "kernels.refused", "labels": {"kernel": "pallas"},
+        "value": 1.0,
+    } in report["metrics"]["counters"]
+    status = sel.kernel_report(1 << 12, 256, 256, kernels=("fm", "pallas"))
+    assert status == {
+        "fm": "compiled+parity ok",
+        "pallas": "refused: Mosaic failed to compile TPU kernel: "
+                  "Not implemented",
+    }
+
+    monkeypatch.setattr(device, "_refused", {})
     monkeypatch.setattr(pg, "aligned_segment_grad", correct)
     choice2 = sel._measure(1 << 12, 256, 256, with_pallas=True)
     assert choice2 in ("fm", "autodiff", "pallas")  # gate passed; timing decides
+    assert not device.kernel_refusals()
 
 
 def test_probe_cap_env_override(monkeypatch):
@@ -438,18 +471,13 @@ def test_probe_floor_skips_measurement_for_small_problems(monkeypatch):
     monkeypatch.setattr(sel, "_measure", boom)
     sel._CACHE.clear()
     assert sel.select_kernel(1 << 10, 64, 256, has_fm=True) == "autodiff"
-    # The cache stays empty on the floor path — if the floor were removed,
-    # boom would fire into select_kernel's failure fallback, which ALSO
-    # returns autodiff but caches it; the cache is the discriminator.
     assert not sel._CACHE, "below the floor the probe path must not engage"
-    # At/above the floor the measurement DOES run (here: boom fires, and
-    # select_kernel's failure fallback also resolves to autodiff — assert
-    # via the cache to distinguish the probed path from the floor path).
+    # At/above the floor the measurement DOES run — and a probe that fails
+    # outright propagates: there is no quiet pin to a default kernel.
     monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "512")
-    sel._CACHE.clear()
-    assert sel.select_kernel(1 << 10, 64, 256, has_fm=True) == "autodiff"
-    assert sel._CACHE, "above the floor the probe path must engage"
-    sel._CACHE.clear()
+    with pytest.raises(AssertionError, match="probe must not run"):
+        sel.select_kernel(1 << 10, 64, 256, has_fm=True)
+    assert not sel._CACHE, "a failed probe must not cache a verdict"
 
 
 def test_aligned_layout_survives_astype_and_pad_strip(monkeypatch):
@@ -510,16 +538,20 @@ def test_fast_path_matches_autodiff_across_random_configs():
 def test_selection_probe_measures_under_enclosing_trace(monkeypatch):
     """The auto-selection probe usually first fires while an ENCLOSING
     jit (optimizer while_loop, streamed chunk program) is being traced;
-    under omnistaging its host synchronizations would raise and the
-    blanket except would silently pin "autodiff" forever.  The
-    ensure_compile_time_eval escape hatch must let the real measurement
-    complete there (round-5 fix — the failure was latent in every
-    jitted auto-mode path)."""
+    under omnistaging its host synchronizations would raise.  The
+    eval-context escape hatch must let the real measurement complete
+    there — INCLUDING the Pallas candidate, whose kernel body (it reads
+    ``program_id``) cannot be traced under ensure_compile_time_eval on
+    jax 0.9: that refusal was silent until the first chip run."""
     import jax
     import jax.numpy as jnp
 
     import photon_tpu.ops.sparse_grad_select as sg
+    from photon_tpu.utils import device
 
+    monkeypatch.setattr(device, "_refused", {})
+    # The interpreter stands in for Mosaic: same kernel-body trace.
+    monkeypatch.setattr(sg, "_pallas_eligible", lambda: True)
     saved = dict(sg._CACHE)
     sg._CACHE.clear()
     calls = []
@@ -535,15 +567,15 @@ def test_selection_probe_measures_under_enclosing_trace(monkeypatch):
     monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "1")
     try:
         def f(x):
-            choice = sg.select_kernel(4096, 512, 256, has_fm=True)
-            assert choice in ("fm", "autodiff")
+            choice = sg.select_kernel(
+                4096, 512, 256, has_fm=True, has_aligned=True
+            )
+            assert choice in ("fm", "autodiff", "pallas")
             return x * 2.0
 
         jax.jit(f)(jnp.ones(2))
-        assert calls, (
-            "the probe must have completed a real measurement under the "
-            "trace, not fallen into the except-Exception autodiff pin"
-        )
+        assert calls, "the probe must have measured under the trace"
+        assert not device.kernel_refusals(), device.kernel_refusals()
     finally:
         sg._CACHE.clear()
         sg._CACHE.update(saved)

@@ -150,6 +150,20 @@ def _fleet(model, data, session, replicas=2, max_batch=16, **kwargs):
 
 # -- wire format -------------------------------------------------------------
 
+def test_subprocess_fleet_refuses_under_a_tpu_parent(monkeypatch):
+    """One process per chip: a parent on a TPU holds the chip, so a child
+    replica could only fail or hang in backend init — the fleet refuses at
+    construction, before anything is spawned."""
+    from photon_tpu.utils import device
+
+    model, _ = _fixture()
+    monkeypatch.setattr(device, "device_facts", lambda: {
+        "platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1,
+    })
+    with pytest.raises(RuntimeError, match="holds the chip"):
+        ServingFleet(model, replicas=2, backend="subprocess")
+
+
 def test_transport_request_roundtrip():
     req = ScoringRequest(
         features={
@@ -274,7 +288,6 @@ def test_overload_sheds_deterministically_without_recompiles():
     every future resolves, admitted p99 bounded) and the whole episode
     triggers ZERO jax compilations after warmup."""
     import jax.monitoring
-    from jax._src import monitoring as monitoring_src
 
     model, data = _fixture(seed=11)
     session = TelemetrySession("test-overload")
@@ -323,7 +336,7 @@ def test_overload_sheds_deterministically_without_recompiles():
             with pytest.raises(RequestShedError) as shed_info:
                 fleet.submit(requests[0], deadline_s=0.0)
         finally:
-            monitoring_src._unregister_event_listener_by_callback(listener)
+            jax.monitoring.unregister_event_listener(listener)
         assert shed_info.value.reason == "deadline"
         for got, rows in results:
             np.testing.assert_allclose(
@@ -362,7 +375,6 @@ def test_cold_start_storm_rides_zero_row_fallback():
     only scores through the (movable) zero row, counted as cold — and
     never recompiles."""
     import jax.monitoring
-    from jax._src import monitoring as monitoring_src
 
     model, data = _fixture(seed=17)
     session = TelemetrySession("test-storm")
@@ -387,7 +399,7 @@ def test_cold_start_storm_rides_zero_row_fallback():
                 traffic.items, clients=3,
             )
         finally:
-            monitoring_src._unregister_event_listener_by_callback(listener)
+            jax.monitoring.unregister_event_listener(listener)
     assert all(o.status == "ok" for o in outcomes)
     for out in outcomes:
         np.testing.assert_allclose(
@@ -460,7 +472,6 @@ def test_rollout_canary_probe_then_promote_under_load():
     scores, the stream's tail serves the new model, and nothing
     recompiles (same-layout swap, capacity-headroom tables)."""
     import jax.monitoring
-    from jax._src import monitoring as monitoring_src
 
     model, data = _fixture(seed=29)
     retrained = _retrained(model, seed=31)
@@ -487,7 +498,7 @@ def test_rollout_canary_probe_then_promote_under_load():
                 futures.append(fleet.submit(req))
             results = [f.result(timeout=60) for f in futures]
         finally:
-            monitoring_src._unregister_event_listener_by_callback(listener)
+            jax.monitoring.unregister_event_listener(listener)
         assert fleet.compilations == compiled
     for rows, got in zip(windows, results):
         ok_old = np.allclose(got, want_old[rows], rtol=1e-4, atol=1e-4)
@@ -829,6 +840,14 @@ def test_serve_game_fleet_driver_end_to_end(tmp_path):
     assert summary["resurrections"] == 0
     assert summary["transport"] == "tcp"
     assert summary["traffic"] == "powerlaw"
+    # The summary names the device the policy resolved and where each
+    # replica's tables really sit (8 virtual devices dealt over 2 replicas).
+    assert summary["device"]["platform"] == "cpu"
+    assert summary["device"]["device_count"] == 8
+    assert summary["replica_devices"] == {
+        "r0": [0, 2, 4, 6], "r1": [1, 3, 5, 7],
+    }
+    assert summary["compiled_during_traffic"] == 0
     assert summary["served"] + summary["shed"] == 30
     assert summary["served"] > 0
     assert summary["cold_entities"] > 0  # the storm rode the fallback

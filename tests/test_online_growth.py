@@ -451,7 +451,6 @@ def test_fixed_batch_row_capacity_zero_recompiles_across_refresh():
     kernels recompile cheaply per refresh); the expensive artifact this
     satellite protects is the fixed-effect BATCH and its solve."""
     import jax.monitoring
-    from jax._src import monitoring as monitoring_src
 
     from photon_tpu.game.coordinate import (
         FixedEffectCoordinate,
@@ -490,7 +489,7 @@ def test_fixed_batch_row_capacity_zero_recompiles_across_refresh():
         # the rebuilt batch replays entirely against compiled programs.
         dd2, padded = train_at(g2, cap)
     finally:
-        monitoring_src._unregister_event_listener_by_callback(listener)
+        jax.monitoring.unregister_event_listener(listener)
     assert events == []
     assert dd2.batch.num_examples == cap
     assert dd2.unpadded_n == g2.num_examples

@@ -215,7 +215,6 @@ def test_no_recompiles_after_warmup_across_buckets(served):
     """50 post-warmup batches of varied sizes spanning both padded buckets:
     ZERO jax compilations and exactly one host sync per batch."""
     import jax.monitoring
-    from jax._src import monitoring as monitoring_src
 
     model, data, scorer, session = served
     compile_events = []
@@ -239,7 +238,7 @@ def test_no_recompiles_after_warmup_across_buckets(served):
         for req in requests:
             scorer.score_batch(req)
     finally:
-        monitoring_src._unregister_event_listener_by_callback(listener)
+        jax.monitoring.unregister_event_listener(listener)
 
     assert compile_events == []
     assert scorer.compilations == compilations_before

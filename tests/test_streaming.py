@@ -117,11 +117,10 @@ def test_stream_chunks_pooled_delivery_order(monkeypatch):
 
 
 def _run_stream_scale_bench(tmp_path, flag, rows):
-    """Run ``bench.py <flag>`` in a subprocess at toy size and return the
-    parsed final JSON line.  Shared scaffold of the two stream-scale bench
-    tests; isolating TMPDIR keeps the test's 5s-probe cpu-fallback verdict
-    out of the shared backend-probe cache, where a real bench run within
-    the TTL would silently skip the TPU probe."""
+    """Run ``bench.py <flag>`` in a subprocess at toy size and return
+    ``(returncode, parsed final JSON line)``.  The subprocess runs under
+    the device policy's EXPLICIT cpu (``JAX_PLATFORMS=cpu``) and inherits
+    the suite's compile cache through ``JAX_COMPILATION_CACHE_DIR``."""
     import json
     import subprocess
     import sys as _sys
@@ -129,28 +128,29 @@ def _run_stream_scale_bench(tmp_path, flag, rows):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         PHOTON_STREAM_SCALE_ROWS=str(rows),
         PHOTON_STREAM_SCALE_DIR=str(tmp_path / "data"),
-        PHOTON_BENCH_PROBE_TIMEOUT="5",
-        TMPDIR=str(tmp_path),
-        PHOTON_BENCH_COMPILATION_CACHE=os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache")
-        ),
     )
     out = subprocess.run(
         [_sys.executable, os.path.join(repo, "bench.py"), flag],
         capture_output=True, text=True, timeout=500, env=env, cwd=repo,
     )
-    assert out.returncode == 0, out.stderr[-2000:]
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    lines = out.stdout.strip().splitlines()
+    assert lines, out.stderr[-2000:]
+    return out.returncode, json.loads(lines[-1]), out.stderr
 
 
 def test_stream_scale_mp_bench_mode(tmp_path):
     """bench.py --stream-scale-mp at toy size: the 2-process distributed
     pass runs, the JSON line parses, and the (value, |grad|) cross-check
-    against the single-process pass holds (both CPU-pinned workers)."""
-    line = _run_stream_scale_bench(tmp_path, "--stream-scale-mp", 2000)
+    against the single-process pass holds (both CPU-pinned workers).  A
+    mode that raises prints its bench_error line AND exits non-zero."""
+    rc, line, stderr = _run_stream_scale_bench(
+        tmp_path, "--stream-scale-mp", 2000
+    )
     if line["metric"] == "bench_error":
+        assert rc != 0, "a failed bench mode must exit non-zero"
         # Some jaxlibs cannot run cross-process collectives on the CPU
         # backend at all; that is a platform limitation, not a bench bug
         # (same signatures test_multiprocess skips on).
@@ -159,11 +159,15 @@ def test_stream_scale_mp_bench_mode(tmp_path):
         err = str(line["detail"].get("error", ""))
         if any(marker in err for marker in MP_UNSUPPORTED_MARKERS):
             pytest.skip(f"platform cannot run multi-process JAX: {err[:200]}")
+    assert rc == 0, stderr[-2000:]
     assert line["metric"] == "config5_stream_mp_rows_per_sec"
     assert line["detail"]["processes"] == 2
     assert line["detail"]["rows"] == 2000
     assert line["detail"]["value_match"] is True
     assert line["detail"]["grad_l1_match"] is True
+    # Every emitted line names the device the policy resolved.
+    assert line["detail"]["device_kind"] == "cpu"
+    assert line["detail"]["device_count"] >= 1
 
 
 def test_csr_chunk_path_matches_rows_path(tmp_path):
@@ -501,7 +505,10 @@ def test_stream_scale_bench_mode(tmp_path):
     import sys as _sys
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    line = _run_stream_scale_bench(tmp_path, "--stream-scale", 3000)
+    rc, line, stderr = _run_stream_scale_bench(
+        tmp_path, "--stream-scale", 3000
+    )
+    assert rc == 0, stderr[-2000:]
     assert line["metric"] == "config5_stream_rows_per_sec"
     assert line["detail"]["rows"] == 3000
     assert line["detail"]["rss_bounded"] is True

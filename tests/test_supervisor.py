@@ -448,7 +448,6 @@ def test_kill_resurrect_cycle_zero_recompiles():
     compile events after warmup — the thread replica's re-warm hits the
     cached bucket programs, and the rejoin probes ride them."""
     import jax.monitoring
-    from jax._src import monitoring as monitoring_src
 
     model, data = _fixture(seed=31)
     session = TelemetrySession("test-zero-recompile")
@@ -478,7 +477,7 @@ def test_kill_resurrect_cycle_zero_recompiles():
                 )
                 pos = (pos + 4) % data.num_examples
         finally:
-            monitoring_src._unregister_event_listener_by_callback(listener)
+            jax.monitoring.unregister_event_listener(listener)
         assert fleet.compilations == compiled
     assert compile_events == []
 

@@ -21,11 +21,10 @@ candidate device pieces with the chained methodology
      transpose at the full-E shape
   e. sublane-gather stage (take_along_axis axis=0 within [8,128] groups)
 
-Verdict rule: pipeline cost/direction ~= 2 x (c) + (d).  If that lands
-under ~35 ms at E=2^25, the xchg kernel beats autodiff's 531 ms step by
-enough to clear 10 steps/s end-to-end; between 35-120 ms it still beats
-1.881 steps/s; above that the unfused v1 (13 HBM passes) is the only
-win and is marginal.
+Verdict rule: pipeline cost/direction ~= 2 x (c) + (d), to be compared
+with the measured autodiff step at the same shape (not measured on the
+current chip).  NOTE (PR 21): on the v5e Mosaic refuses the chunk kernel's
+CH-wide lane gather, so probe (c) does not lower there as written.
 """
 
 import argparse
