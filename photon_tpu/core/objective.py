@@ -49,17 +49,22 @@ def _fm_segment_grad(per_row: Array, fm: FeatureMajorAux, dim: int) -> Array:
     """
     s, _ = fm.ids.shape
     ns = per_row.shape[0] // s
-    rows = fm.rows + (jnp.arange(s, dtype=fm.rows.dtype) * ns)[:, None]
-    contrib = jnp.take(per_row, rows.reshape(-1), axis=0).reshape(s, -1) * fm.vals
+    with jax.named_scope("fm/gather"):
+        rows = fm.rows + (jnp.arange(s, dtype=fm.rows.dtype) * ns)[:, None]
+        contrib = (
+            jnp.take(per_row, rows.reshape(-1), axis=0).reshape(s, -1)
+            * fm.vals
+        )
 
     def _block(c, i):
         return jax.ops.segment_sum(
             c, i, num_segments=dim, indices_are_sorted=True
         )
 
-    if s == 1:
-        return _block(contrib[0], fm.ids[0])
-    return jnp.sum(jax.vmap(_block)(contrib, fm.ids), axis=0)
+    with jax.named_scope("fm/segment_sum"):
+        if s == 1:
+            return _block(contrib[0], fm.ids[0])
+        return jnp.sum(jax.vmap(_block)(contrib, fm.ids), axis=0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,14 +192,26 @@ class GlmObjective:
         return self._xu_product(kernel, w_eff, batch) + batch.offset - correction
 
     # -- value / gradient ------------------------------------------------------
+    # The phases of one value+gradient evaluation carry named scopes
+    # (HLO metadata only): ``valuegrad/margins`` (the forward X·w: the
+    # gather of w at the ids), ``valuegrad/loss`` (the pointwise loss, its
+    # derivative, the L2 term) and ``valuegrad/grad`` (the reduction into
+    # the coefficients, with the kernel's own stages under it:
+    # ``fm/gather``, ``fm/segment_sum``, ``pallas/gather``,
+    # ``pallas/reduce``).  On the autodiff path the gradient is the
+    # transpose of the forward, so its scatter-add reads
+    # ``transpose(jvp(valuegrad/margins))`` — see ops/KERNEL_NOTES.md.
     def data_value(self, w: Array, batch: Batch) -> Array:
-        z = self._margins(w, batch)
-        return jnp.sum(batch.weight * self.loss.value(z, batch.label))
+        with jax.named_scope("valuegrad/margins"):
+            z = self._margins(w, batch)
+        with jax.named_scope("valuegrad/loss"):
+            return jnp.sum(batch.weight * self.loss.value(z, batch.label))
 
     def value(self, w: Array, batch: Batch) -> Array:
         v = self.data_value(w, batch)
         if not _static_zero(self.l2_weight):
-            v = v + 0.5 * self.l2_weight * jnp.dot(w, w)
+            with jax.named_scope("valuegrad/loss"):
+                v = v + 0.5 * self.l2_weight * jnp.dot(w, w)
         return v
 
     # -- static-sparsity fast path --------------------------------------------
@@ -268,15 +285,18 @@ class GlmObjective:
         ``g = F (Xᵀ dz - s Σ dz)`` — one extra scalar sum and two
         elementwise ops over the same sorted segment sum (the sparse batch
         never densifies, mirroring hessian_diagonal's algebra)."""
-        z = self._margins_for_kernel(kernel, w, batch)
-        v = jnp.sum(batch.weight * self.loss.value(z, batch.label))
-        dz = batch.weight * self.loss.d1(z, batch.label)
-        g = self._segment_grad(kernel, dz, batch, w.shape[0])
-        norm = self.normalization
-        if norm is not None:
-            if norm.shifts is not None:
-                g = g - norm.shifts * jnp.sum(dz)
-            g = g * norm.factors_or_ones(w.shape[0])
+        with jax.named_scope("valuegrad/margins"):
+            z = self._margins_for_kernel(kernel, w, batch)
+        with jax.named_scope("valuegrad/loss"):
+            v = jnp.sum(batch.weight * self.loss.value(z, batch.label))
+            dz = batch.weight * self.loss.d1(z, batch.label)
+        with jax.named_scope("valuegrad/grad"):
+            g = self._segment_grad(kernel, dz, batch, w.shape[0])
+            norm = self.normalization
+            if norm is not None:
+                if norm.shifts is not None:
+                    g = g - norm.shifts * jnp.sum(dz)
+                g = g * norm.factors_or_ones(w.shape[0])
         return v, g
 
     def _fast_data_hessian_vector(
@@ -302,8 +322,10 @@ class GlmObjective:
         if kernel is not None:
             val, g = self._fast_data_value_and_grad(w, batch, kernel)
             if not _static_zero(self.l2_weight):
-                val = val + 0.5 * self.l2_weight * jnp.dot(w, w)
-                g = g + self.l2_weight * w
+                with jax.named_scope("valuegrad/loss"):
+                    val = val + 0.5 * self.l2_weight * jnp.dot(w, w)
+                with jax.named_scope("valuegrad/grad"):
+                    g = g + self.l2_weight * w
             return val, g
         return jax.value_and_grad(self.value)(w, batch)
 

@@ -89,6 +89,21 @@ class OptimizerResult(NamedTuple):
     # loop (newton_cg); None elsewhere — a None leaf is an empty pytree
     # subtree, so existing jit/vmap programs are unchanged.
     cg_iterations: Array | None = None
+    # int32 objective (value+grad) evaluations the fit ran: the initial
+    # point, every line-search trial, the final polish steps.  What the
+    # rooflines must multiply a pass over the data by; ``iterations + 1`` is
+    # a floor on it.  None from solvers that do not count (the streamed
+    # host-driven L-BFGS).
+    evaluations: Array | None = None
+    # int32 line-search trials among them (one per iteration when every
+    # first step is accepted; each backtrack adds one).  None from solvers
+    # with no line search (TRON).
+    line_search_steps: Array | None = None
+
+
+def _optional_int(count) -> int | None:
+    """Host int of an optional device count (summed over a vmapped axis)."""
+    return None if count is None else int(np.sum(np.asarray(count)))
 
 
 class OptimizationStatesTracker:
@@ -106,6 +121,8 @@ class OptimizationStatesTracker:
         self.converged = bool(result.converged)
         self.reason_code = int(result.reason)
         self.wall_time_s = wall_time_s
+        self.evaluations = _optional_int(result.evaluations)
+        self.line_search_steps = _optional_int(result.line_search_steps)
 
     @property
     def convergence_reason(self) -> str:
@@ -123,12 +140,21 @@ class OptimizationStatesTracker:
 
     def record_to(self, registry, **labels) -> None:
         """Push this run's summary into a telemetry metrics registry
-        (photon_tpu.telemetry; duck-typed so the optimizer layer stays
-        import-free of it): solve counts, iteration totals, a stop-reason
-        breakdown, solve-seconds distribution, and final value/|grad|."""
+        (photon_tpu.telemetry; duck-typed so ``core/optimizers`` stays
+        import-free of it — ``core/problem.py``, one layer up, is what hands
+        the process registry its deferred counts): solve counts, iteration totals, a stop-reason
+        breakdown, solve-seconds distribution, and the final value."""
         labels = {k: str(v) for k, v in labels.items()}
         registry.counter("optimizer.solves", **labels).inc()
         registry.counter("optimizer.iterations", **labels).inc(self.iterations)
+        if self.evaluations is not None:
+            registry.counter("optimizer.evaluations", **labels).inc(
+                self.evaluations
+            )
+        if self.line_search_steps is not None:
+            registry.counter("optimizer.line_search_steps", **labels).inc(
+                self.line_search_steps
+            )
         if self.converged:
             registry.counter("optimizer.converged_solves", **labels).inc()
         registry.counter(
@@ -141,9 +167,6 @@ class OptimizationStatesTracker:
         if len(self.values):
             registry.gauge("optimizer.final_value", **labels).set(
                 float(self.values[-1])
-            )
-            registry.gauge("optimizer.final_grad_norm", **labels).set(
-                float(self.grad_norms[-1])
             )
 
     def summary(self) -> str:

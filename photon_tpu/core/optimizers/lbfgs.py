@@ -34,6 +34,7 @@ from photon_tpu.core.optimizers.base import (
 Array = jax.Array
 
 _ARMIJO_C1 = 1e-4
+_POLISH_STEPS = 2  # full steps after the loop, one evaluation each
 _PAIR_EPS = 1e-10
 
 
@@ -46,13 +47,18 @@ class _LineSearchState(NamedTuple):
     halt: Array  # stop without success (out of steps / inactive lane)
 
 
-def _backtracking_line_search(fun, w, d, f0, dir_deriv, t0, max_steps, active):
-    """Armijo backtracking from step ``t0``, halving on failure.
+def _backtracking_line_search(fun, w, d, f0, dir_deriv, t0, max_steps, active,
+                              scope: str = "line_search"):
+    """Armijo backtracking from step ``t0``, halving on failure.  ``scope``
+    names the trials' operations in the device trace (``lbfgs/line_search``,
+    ``newton/gradient``: the trials are where a Newton step's gradient is
+    evaluated).
 
-    Returns (t, f_t, g_t, success).  The acceptance test lives in the loop
-    condition, so exactly one (value, grad) evaluation happens per trial —
-    an accepted first step costs a single evaluation.  Inert when ``active``
-    is False.
+    Returns (t, f_t, g_t, success, trials).  The acceptance test lives in
+    the loop condition, so exactly one (value, grad) evaluation happens per
+    trial — an accepted first step costs a single evaluation, and ``trials``
+    (int32) is the number of evaluations this search ran.  Inert when
+    ``active`` is False.
     """
 
     def trial(t):
@@ -61,7 +67,8 @@ def _backtracking_line_search(fun, w, d, f0, dir_deriv, t0, max_steps, active):
         ok = (f <= f0 + _ARMIJO_C1 * t * dir_deriv) & jnp.isfinite(f)
         return f, g, ok
 
-    f_i, g_i, ok_i = trial(t0)
+    with jax.named_scope(scope):
+        f_i, g_i, ok_i = trial(t0)
 
     def cond(s: _LineSearchState):
         return ~(s.ok | s.halt)
@@ -78,8 +85,9 @@ def _backtracking_line_search(fun, w, d, f0, dir_deriv, t0, max_steps, active):
         t=jnp.asarray(t0), f=f_i, g=g_i, ok=ok_i,
         it=jnp.asarray(0, jnp.int32), halt=~active,
     )
-    final = lax.while_loop(cond, body, init)
-    return final.t, final.f, final.g, final.ok
+    with jax.named_scope(scope):
+        final = lax.while_loop(cond, body, init)
+    return final.t, final.f, final.g, final.ok, final.it + 1
 
 
 def _two_loop_direction(g, S, Y, rho, num_pairs, insert_pos, gamma, m):
@@ -121,6 +129,7 @@ class _State(NamedTuple):
     insert_pos: Array
     gamma: Array
     it: Array
+    ls: Array  # line-search trials so far (one objective evaluation each)
     active: Array
     reason: Array
     hv: Array
@@ -157,6 +166,7 @@ def lbfgs(
         insert_pos=jnp.asarray(0, jnp.int32),
         gamma=jnp.asarray(1.0, w0.dtype),
         it=jnp.asarray(0, jnp.int32),
+        ls=jnp.asarray(0, jnp.int32),
         active=~conv0,
         reason=jnp.where(
             conv0, ConvergenceReason.GRADIENT_TOLERANCE, ConvergenceReason.NOT_CONVERGED
@@ -168,9 +178,10 @@ def lbfgs(
         return s.active
 
     def body(s: _State):
-        dvec = _two_loop_direction(
-            s.g, s.S, s.Y, s.rho, s.num_pairs, s.insert_pos, s.gamma, m
-        )
+        with jax.named_scope("lbfgs/direction"):
+            dvec = _two_loop_direction(
+                s.g, s.S, s.Y, s.rho, s.num_pairs, s.insert_pos, s.gamma, m
+            )
         dir_deriv = jnp.dot(s.g, dvec)
         # Fall back to steepest descent if the direction is not a descent one.
         bad = dir_deriv >= 0.0
@@ -179,8 +190,9 @@ def lbfgs(
         gnorm = jnp.linalg.norm(s.g)
         t0 = jnp.where(s.num_pairs == 0, 1.0 / jnp.maximum(gnorm, 1.0), 1.0)
 
-        t, f_new, g_new, ls_ok = _backtracking_line_search(
-            fun, s.w, dvec, s.f, dir_deriv, t0, config.max_line_search, s.active
+        t, f_new, g_new, ls_ok, trials = _backtracking_line_search(
+            fun, s.w, dvec, s.f, dir_deriv, t0, config.max_line_search,
+            s.active, scope="lbfgs/line_search",
         )
 
         w_new = s.w + t * dvec
@@ -221,7 +233,7 @@ def lbfgs(
             w=w_out, f=f_out, g=g_out,
             S=S_new, Y=Y_new, rho=rho_new,
             num_pairs=num_pairs, insert_pos=insert_pos, gamma=gamma,
-            it=it_new, active=still_active,
+            it=it_new, ls=s.ls + trials, active=still_active,
             reason=reason.astype(jnp.int32),
             hv=hv, hg=hg, hvalid=hvalid,
         )
@@ -264,7 +276,7 @@ def lbfgs(
         ), None
 
     (w_out, f_out, g_out), _ = lax.scan(
-        polish, (final.w, final.f, final.g), None, length=2
+        polish, (final.w, final.f, final.g), None, length=_POLISH_STEPS
     )
     return OptimizerResult(
         w=w_out,
@@ -276,4 +288,7 @@ def lbfgs(
         history_value=final.hv,
         history_grad_norm=final.hg,
         history_valid=final.hvalid,
+        # The initial point, every line-search trial, the two polish steps.
+        evaluations=final.ls + (1 + _POLISH_STEPS),
+        line_search_steps=final.ls,
     )

@@ -54,6 +54,7 @@ Array = jax.Array
 # enough to keep Cholesky defined on flat directions, orders of magnitude
 # below any curvature that moves the solution at the 1e-5 parity tolerance.
 _RIDGE = 1e-9
+_POLISH_STEPS = 2  # full steps after the loop, one evaluation each
 
 
 class _State(NamedTuple):
@@ -61,6 +62,7 @@ class _State(NamedTuple):
     f: Array
     g: Array
     it: Array
+    ls: Array  # line-search trials so far (one objective evaluation each)
     active: Array
     reason: Array
     hv: Array
@@ -95,6 +97,7 @@ def newton(
     init = _State(
         w=w0, f=f0, g=g0,
         it=jnp.asarray(0, jnp.int32),
+        ls=jnp.asarray(0, jnp.int32),
         active=~conv0,
         reason=jnp.where(
             conv0, ConvergenceReason.GRADIENT_TOLERANCE,
@@ -106,22 +109,33 @@ def newton(
     def cond(s: _State):
         return s.active
 
-    def body(s: _State):
-        h = hess(s.w)
-        ridge = _RIDGE * (1.0 + jnp.max(jnp.abs(jnp.diagonal(h))))
-        chol = jax.scipy.linalg.cho_factor(h + ridge * eye)
-        step = -jax.scipy.linalg.cho_solve(chol, s.g)
-        dir_deriv = jnp.dot(s.g, step)
-        # A failed factorization (non-PD curvature -> NaN) or a non-descent
-        # step falls back to steepest descent for this iteration.
-        bad = ~jnp.all(jnp.isfinite(step)) | (dir_deriv >= 0.0)
-        step = jnp.where(bad, -s.g, step)
-        dir_deriv = jnp.where(bad, -jnp.dot(s.g, s.g), dir_deriv)
-        t0 = jnp.where(bad, 1.0 / jnp.maximum(jnp.linalg.norm(s.g), 1.0), 1.0)
+    def solve(w, g):
+        """The Newton step ``-(H(w) + ridge I)^-1 g``."""
+        with jax.named_scope("newton/hessian"):
+            h = hess(w)
+            ridge = _RIDGE * (1.0 + jnp.max(jnp.abs(jnp.diagonal(h))))
+            h = h + ridge * eye
+        with jax.named_scope("newton/cholesky"):
+            chol = jax.scipy.linalg.cho_factor(h)
+            return -jax.scipy.linalg.cho_solve(chol, g)
 
-        t, f_new, g_new, ls_ok = _backtracking_line_search(
+    def body(s: _State):
+        step = solve(s.w, s.g)
+        with jax.named_scope("newton/step"):
+            dir_deriv = jnp.dot(s.g, step)
+            # A failed factorization (non-PD curvature -> NaN) or a
+            # non-descent step falls back to steepest descent for this
+            # iteration.
+            bad = ~jnp.all(jnp.isfinite(step)) | (dir_deriv >= 0.0)
+            step = jnp.where(bad, -s.g, step)
+            dir_deriv = jnp.where(bad, -jnp.dot(s.g, s.g), dir_deriv)
+            t0 = jnp.where(
+                bad, 1.0 / jnp.maximum(jnp.linalg.norm(s.g), 1.0), 1.0
+            )
+
+        t, f_new, g_new, ls_ok, trials = _backtracking_line_search(
             fun, s.w, step, s.f, dir_deriv, t0, config.max_line_search,
-            s.active,
+            s.active, scope="newton/gradient",
         )
         w_new = s.w + t * step
 
@@ -152,7 +166,7 @@ def newton(
 
         new = _State(
             w=w_out, f=f_out, g=g_out,
-            it=it_new, active=still_active,
+            it=it_new, ls=s.ls + trials, active=still_active,
             reason=reason.astype(jnp.int32),
             hv=hv, hg=hg, hvalid=hvalid,
         )
@@ -173,10 +187,7 @@ def newton(
     # the stepped point stays finite.
     def polish(carry, _):
         w, f, g = carry
-        h = hess(w)
-        ridge = _RIDGE * (1.0 + jnp.max(jnp.abs(jnp.diagonal(h))))
-        chol = jax.scipy.linalg.cho_factor(h + ridge * eye)
-        step = -jax.scipy.linalg.cho_solve(chol, g)
+        step = solve(w, g)
         near = jnp.all(jnp.isfinite(step)) & (
             jnp.linalg.norm(step)
             <= 1e-3 * jnp.maximum(jnp.linalg.norm(w), 1.0)
@@ -191,7 +202,7 @@ def newton(
         ), None
 
     (w_out, f_out, g_out), _ = lax.scan(
-        polish, (final.w, final.f, final.g), None, length=2
+        polish, (final.w, final.f, final.g), None, length=_POLISH_STEPS
     )
     return OptimizerResult(
         w=w_out,
@@ -203,4 +214,7 @@ def newton(
         history_value=final.hv,
         history_grad_norm=final.hg,
         history_valid=final.hvalid,
+        # The initial point, every line-search trial, the two polish steps.
+        evaluations=final.ls + (1 + _POLISH_STEPS),
+        line_search_steps=final.ls,
     )

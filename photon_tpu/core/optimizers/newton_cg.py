@@ -181,6 +181,7 @@ def newton_cg(
         active: Array
         reason: Array
         cg: Array
+        ls: Array
         hv: Array
         hg: Array
         hvalid: Array
@@ -194,6 +195,7 @@ def newton_cg(
             ConvergenceReason.NOT_CONVERGED,
         ).astype(jnp.int32),
         cg=jnp.asarray(0, jnp.int32),
+        ls=jnp.asarray(0, jnp.int32),
         hv=hv0, hg=hg0, hvalid=hvalid0,
     )
 
@@ -217,9 +219,9 @@ def newton_cg(
         dir_deriv = jnp.where(bad, -jnp.dot(s.g, s.g), dir_deriv)
         t0 = jnp.where(bad, 1.0 / jnp.maximum(gnorm, 1.0), 1.0)
 
-        t, f_new, g_new, ls_ok = _backtracking_line_search(
+        t, f_new, g_new, ls_ok, trials = _backtracking_line_search(
             fun, s.w, step, s.f, dir_deriv, t0, config.max_line_search,
-            s.active,
+            s.active, scope="newton/gradient",
         )
         w_new = s.w + t * step
 
@@ -253,6 +255,7 @@ def newton_cg(
             it=it_new, active=still_active,
             reason=reason.astype(jnp.int32),
             cg=s.cg + cg_it,
+            ls=s.ls + trials,
             hv=hv_h, hg=hg_h, hvalid=hvalid_h,
         )
         return tree_where(s.active, new, s)
@@ -303,4 +306,7 @@ def newton_cg(
         history_grad_norm=final.hg,
         history_valid=final.hvalid,
         cg_iterations=cg_out,
+        # The initial point, every line-search trial, the two polish steps.
+        evaluations=final.ls + 3,
+        line_search_steps=final.ls,
     )

@@ -82,6 +82,7 @@ class _State(NamedTuple):
     insert_pos: Array
     gamma: Array
     it: Array
+    ls: Array  # line-search trials so far (one objective evaluation each)
     active: Array
     reason: Array
     hv: Array
@@ -125,6 +126,7 @@ def owlqn(
         insert_pos=jnp.asarray(0, jnp.int32),
         gamma=jnp.asarray(1.0, w0.dtype),
         it=jnp.asarray(0, jnp.int32),
+        ls=jnp.asarray(0, jnp.int32),
         active=~conv0,
         reason=jnp.where(
             conv0, ConvergenceReason.GRADIENT_TOLERANCE, ConvergenceReason.NOT_CONVERGED
@@ -222,7 +224,7 @@ def owlqn(
             w=w_out, f=f_out, g=g_out,
             S=S_new, Y=Y_new, rho=rho_new,
             num_pairs=num_pairs, insert_pos=insert_pos, gamma=gamma,
-            it=it_new, active=still_active,
+            it=it_new, ls=s.ls + ls.it + 1, active=still_active,
             reason=reason.astype(jnp.int32),
             hv=hv, hg=hg, hvalid=hvalid,
         )
@@ -280,4 +282,7 @@ def owlqn(
         history_value=final.hv,
         history_grad_norm=final.hg,
         history_valid=final.hvalid,
+        # The initial point, every line-search trial, the two polish steps.
+        evaluations=final.ls + 3,
+        line_search_steps=final.ls,
     )

@@ -268,4 +268,7 @@ def tron(
         history_value=final.hv,
         history_grad_norm=final.hg,
         history_valid=final.hvalid,
+        # The initial point plus one trial point per trust-region iteration
+        # (TRON has no line search: line_search_steps stays None).
+        evaluations=final.it + 1,
     )

@@ -140,11 +140,16 @@ def cached_solver(optimizer: str, cfg: OptimizerConfig, variance: str,
 @functools.lru_cache(maxsize=32)
 def _cached_solver(optimizer: str, cfg: OptimizerConfig, variance: str,
                    vmapped: bool):
+    from photon_tpu.utils.device import named_jit
+
     run = functools.partial(_run_fit, optimizer=optimizer, cfg=cfg,
                             variance=variance)
     if vmapped:
         run = jax.vmap(run, in_axes=(None, 0, 0))
-    return jax.jit(run)
+    # The device program's published name: jit_glm_fit_lbfgs, or
+    # jit_entity_fit_lbfgs for the vmapped bucket solve.
+    kind = "entity_fit" if vmapped else "glm_fit"
+    return named_jit(f"{kind}_{optimizer.replace('-', '_')}", run)
 
 
 class GlmOptimizationProblem:
@@ -175,7 +180,25 @@ class GlmOptimizationProblem:
             if dim is None:
                 raise ValueError("need w0 or dim")
             w0 = jnp.zeros(dim, jnp.float32)
-        return self.solver()(self.objective, batch, w0)
+        coefficients, result = self.solver()(self.objective, batch, w0)
+        if result.evaluations is not None and not isinstance(
+            result.evaluations, jax.core.Tracer
+        ):
+            # The counts live on the device until the fit ends: handed to
+            # the process registry as they are, fetched at its next
+            # snapshot, never on this (possibly timed) path.  (Under an
+            # enclosing trace there is no value to hand over.)
+            from photon_tpu.telemetry import process_registry
+
+            registry = process_registry()
+            registry.counter("optimizer.evaluations").inc_deferred(
+                result.evaluations
+            )
+            if result.line_search_steps is not None:
+                registry.counter("optimizer.line_search_steps").inc_deferred(
+                    result.line_search_steps
+                )
+        return coefficients, result
 
     def compute_variances(self, w: Array, batch: Batch) -> Optional[Array]:
         return _compute_variances(

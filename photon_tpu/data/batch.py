@@ -260,22 +260,30 @@ def attach_feature_major(
     """
     if not isinstance(batch, SparseBatch) or batch.ids.ndim != 2:
         raise ValueError("feature-major layout requires a 2-D SparseBatch")
+    from photon_tpu import telemetry
+    from photon_tpu.utils.device import count_h2d
+
     n, k = batch.ids.shape
     if n % shards:
         raise ValueError(f"rows ({n}) not divisible by shards ({shards}); pad first")
     ns = n // shards
-    ids = np.asarray(batch.ids).reshape(shards, ns * k)
-    vals = np.asarray(batch.vals).reshape(shards, ns * k)
-    rows = np.broadcast_to(
-        np.repeat(np.arange(ns, dtype=np.int32), k), (shards, ns * k)
-    )
-    order = np.argsort(ids, axis=1, kind="stable")
-    take = np.take_along_axis
-    batch = batch._replace(fm=FeatureMajorAux(
-        ids=jnp.asarray(take(ids, order, axis=1)),
-        rows=jnp.asarray(take(rows, order, axis=1)),
-        vals=jnp.asarray(take(vals, order, axis=1)),
-    ))
+    # The host argsort and the reorder gathers of the flat entries (plus
+    # the fetch of ids/vals when the batch is already on the device).
+    with telemetry.span("layout.feature_major", entries=n * k, shards=shards):
+        ids = np.asarray(batch.ids).reshape(shards, ns * k)
+        vals = np.asarray(batch.vals).reshape(shards, ns * k)
+        rows = np.broadcast_to(
+            np.repeat(np.arange(ns, dtype=np.int32), k), (shards, ns * k)
+        )
+        order = np.argsort(ids, axis=1, kind="stable")
+        take = np.take_along_axis
+        fm = FeatureMajorAux(
+            ids=jnp.asarray(take(ids, order, axis=1)),
+            rows=jnp.asarray(take(rows, order, axis=1)),
+            vals=jnp.asarray(take(vals, order, axis=1)),
+        )
+    count_h2d("feature_major", fm)
+    batch = batch._replace(fm=fm)
     if aligned_forward and aligned_dim is None:
         raise ValueError(
             "aligned_forward requires aligned_dim (the transposed layout "
@@ -319,7 +327,8 @@ def attach_feature_major(
             )
         from photon_tpu.ops.pallas_gather import layout_content_hash
 
-        base_hash = layout_content_hash(ids_np, vals_np)
+        with telemetry.span("layout.cache_key"):
+            base_hash = layout_content_hash(ids_np, vals_np)
         layout = load_or_build_aligned_layout(
             ids_np, vals_np, aligned_dim, base_hash=base_hash
         )
@@ -406,10 +415,13 @@ def _attach_aligned_sharded(
     vals_blocks = vals_np.reshape(shards, ns, k)
     from photon_tpu.ops.pallas_gather import layout_content_hash
 
-    base_hashes = [
-        layout_content_hash(ids_blocks[s], vals_blocks[s])
-        for s in range(shards)
-    ]
+    from photon_tpu import telemetry
+
+    with telemetry.span("layout.cache_key"):
+        base_hashes = [
+            layout_content_hash(ids_blocks[s], vals_blocks[s])
+            for s in range(shards)
+        ]
     layouts = [
         load_or_build_aligned_layout(
             ids_blocks[s], vals_blocks[s], aligned_dim,

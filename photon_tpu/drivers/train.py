@@ -303,7 +303,8 @@ def _run_streaming(args: argparse.Namespace, logger, session,
                     checkpointer.load("auto"), fingerprint,
                     f"lambda={lam:g}",
                 )
-        with logger.timed(f"train-lambda-{lam}"):
+        with logger.timed(f"train-lambda-{lam}", span=False), \
+                session.span("train.lambda", reg_weight=float(lam)):
             t0 = time.monotonic()
             result = streaming_lbfgs(
                 objective, w_start, opt_config,
@@ -601,8 +602,9 @@ def _run_resident(args: argparse.Namespace, logger, session,
                     variance_computation=args.variance_computation,
                 ),
             )
-            with logger.timed(f"train-lambda-{lam}"), \
-                    maybe_profile(args.profile_dir):
+            with logger.timed(f"train-lambda-{lam}", span=False), \
+                    maybe_profile(args.profile_dir), \
+                    session.span("train.lambda", reg_weight=float(lam)):
                 t0 = time.monotonic()
                 coefficients, result = problem.run(batch, w_start)
                 jax.block_until_ready(coefficients.means)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_tpu.utils import pow2_at_least
+from photon_tpu.utils.device import named_jit
 
 from photon_tpu.core.losses import get_loss
 
@@ -34,7 +35,10 @@ def _weights_or_ones(scores, weights):
     return weights
 
 
-@jax.jit
+# The metric programs' published device names are jit_metric_<what>
+# (README "Telemetry"): validation's device time is read off the trace by
+# that prefix, whatever the Python functions are called.
+@functools.partial(named_jit, "metric_auc")
 def area_under_roc_curve(scores: Array, labels: Array, weights: Array | None = None) -> Array:
     """Weighted, tie-corrected AUC (Mann-Whitney U formulation).
 
@@ -43,7 +47,11 @@ def area_under_roc_curve(scores: Array, labels: Array, weights: Array | None = N
     vectorized (the reference's AreaUnderROCCurveEvaluator computes the same
     statistic via Spark's ranking).
     """
-    w = _weights_or_ones(scores, weights)
+    with jax.named_scope("validation/auc"):
+        return _auc(scores, labels, _weights_or_ones(scores, weights))
+
+
+def _auc(scores: Array, labels: Array, w: Array) -> Array:
     pos_w = w * labels
     neg_w = w * (1.0 - labels)
     order = jnp.argsort(scores)
@@ -62,30 +70,31 @@ def area_under_roc_curve(scores: Array, labels: Array, weights: Array | None = N
     return jnp.where((wpos > 0) & (wneg > 0), num / (wpos * wneg), 0.5)
 
 
-@jax.jit
+@functools.partial(named_jit, "metric_rmse")
 def rmse(scores: Array, labels: Array, weights: Array | None = None) -> Array:
-    w = _weights_or_ones(scores, weights)
-    se = w * (scores - labels) ** 2
-    return jnp.sqrt(jnp.sum(se) / jnp.maximum(jnp.sum(w), 1e-30))
+    with jax.named_scope("validation/rmse"):
+        w = _weights_or_ones(scores, weights)
+        se = w * (scores - labels) ** 2
+        return jnp.sqrt(jnp.sum(se) / jnp.maximum(jnp.sum(w), 1e-30))
 
 
-def _mean_loss(loss_name: str) -> Callable:
+def _mean_loss(loss_name: str, short: str) -> Callable:
     loss = get_loss(loss_name)
 
-    @jax.jit
     def metric(scores: Array, labels: Array, weights: Array | None = None) -> Array:
-        w = _weights_or_ones(scores, weights)
-        return jnp.sum(w * loss.value(scores, labels)) / jnp.maximum(
-            jnp.sum(w), 1e-30
-        )
+        with jax.named_scope(f"validation/{short}"):
+            w = _weights_or_ones(scores, weights)
+            return jnp.sum(w * loss.value(scores, labels)) / jnp.maximum(
+                jnp.sum(w), 1e-30
+            )
 
-    return metric
+    return named_jit(f"metric_{short}", metric)
 
 
-logistic_loss_metric = _mean_loss("logistic")
-poisson_loss_metric = _mean_loss("poisson")
-squared_loss_metric = _mean_loss("squared")
-smoothed_hinge_loss_metric = _mean_loss("smoothed_hinge")
+logistic_loss_metric = _mean_loss("logistic", "logloss")
+poisson_loss_metric = _mean_loss("poisson", "poisson_loss")
+squared_loss_metric = _mean_loss("squared", "squared_loss")
+smoothed_hinge_loss_metric = _mean_loss("smoothed_hinge", "hinge_loss")
 
 
 def precision_at_k(
@@ -175,7 +184,9 @@ def _segmented_cumsum(x: Array, new_seg: Array) -> Array:
     return total
 
 
-@functools.partial(jax.jit, static_argnames=("num_segments",))
+@functools.partial(
+    named_jit, "metric_sharded_auc", static_argnames=("num_segments",)
+)
 def _sharded_auc_kernel(
     scores: Array, labels: Array, weights: Array, codes: Array,
     num_segments: int,
@@ -215,7 +226,10 @@ def _sharded_auc_kernel(
     return jnp.sum(auc) / jnp.maximum(count, 1), count
 
 
-@functools.partial(jax.jit, static_argnames=("num_segments", "k"))
+@functools.partial(
+    named_jit, "metric_sharded_precision",
+    static_argnames=("num_segments", "k"),
+)
 def _sharded_precision_kernel(
     scores: Array, labels: Array, weights: Array, codes: Array,
     num_segments: int, k: int,

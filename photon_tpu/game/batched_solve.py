@@ -169,8 +169,12 @@ def cached_newton_solver(problem: ProblemConfig):
 
 @functools.lru_cache(maxsize=32)
 def _cached_newton_solver(cfg: OptimizerConfig, variance: str):
+    from photon_tpu.utils.device import named_jit
+
     run = functools.partial(_run_newton_fit, cfg=cfg, variance=variance)
-    return jax.jit(jax.vmap(run, in_axes=(None, 0, 0)))
+    return named_jit(
+        "entity_solve_newton", jax.vmap(run, in_axes=(None, 0, 0))
+    )
 
 
 def _run_newton_cg_fit(objective, batch, w0, *, cfg: OptimizerConfig,
@@ -209,8 +213,12 @@ def cached_newton_cg_solver(problem: ProblemConfig):
 
 @functools.lru_cache(maxsize=32)
 def _cached_newton_cg_solver(cfg: OptimizerConfig, variance: str):
+    from photon_tpu.utils.device import named_jit
+
     run = functools.partial(_run_newton_cg_fit, cfg=cfg, variance=variance)
-    return jax.jit(jax.vmap(run, in_axes=(None, 0, 0)))
+    return named_jit(
+        "entity_solve_newton_cg", jax.vmap(run, in_axes=(None, 0, 0))
+    )
 
 
 def record_bin_telemetry(telemetry, coordinate: str, bin_stats: list,

@@ -64,6 +64,7 @@ from photon_tpu.game.model import (
 )
 from photon_tpu.models.glm import Coefficients, model_for_task
 from photon_tpu.telemetry import NULL_SESSION
+from photon_tpu.utils.device import count_h2d, named_jit
 from photon_tpu.parallel.mesh import (
     DATA_AXIS,
     first_axis_name,
@@ -78,14 +79,14 @@ from photon_tpu.parallel.mesh import (
 Array = jax.Array
 
 
-@jax.jit
+@functools.partial(named_jit, "gather_rows")
 def _gather_rows(offsets: Array, row_index: Array) -> Array:
     """Device row gather: the fixed effect's downsample selection applied to
     a device-resident offsets vector."""
     return offsets[row_index]
 
 
-@jax.jit
+@functools.partial(named_jit, "gather_bucket_offsets")
 def _gather_bucket_offsets(offsets: Array, row_index: Array, mask: Array) -> Array:
     """Per-bucket offset gather on device: ``offsets[row_index] * mask``
     against the pre-uploaded ``[E, R]`` row-index/mask buffers — replaces the
@@ -93,14 +94,15 @@ def _gather_bucket_offsets(offsets: Array, row_index: Array, mask: Array) -> Arr
     return offsets[row_index] * mask
 
 
-@jax.jit
+@functools.partial(named_jit, "accumulate_solve_stats")
 def _accumulate_solve_stats(
     acc: Array, entity_index: Array, num_entities, converged: Array,
     iterations: Array, good: Array, cg_iterations: Array | None = None,
+    bin_slot: Array | None = None,
 ) -> Array:
-    """Fold one bucket's solve results into the per-coordinate ``[6]``
-    int32 stats accumulator ``[entities, converged, iterations_max,
-    quarantined, cg_iters, cg_entities]`` — entirely on device, so a coordinate's
+    """Fold one bucket's solve results into the per-coordinate int32 stats
+    accumulator ``[entities, converged, iterations_max, quarantined,
+    cg_iters, cg_entities, *bin_iterations]`` — entirely on device, so a coordinate's
     train() emits NO host sync of its own: the descent loop drains every
     coordinate's accumulator (plus the score-table guard flags) in ONE
     ``device_get`` per outer iteration.  Padded entities (``entity_index
@@ -113,7 +115,12 @@ def _accumulate_solve_stats(
     and the SAME bins' real entities into ``cg_entities`` — the correct
     per-entity-mean denominator when a coordinate mixes CG and non-CG
     bins (projected buckets can differ in solve_dim); other routes
-    contribute 0 to both."""
+    contribute 0 to both.  Past the six totals the accumulator may carry
+    one slot per bin: with ``bin_slot`` (a scalar index) this bucket's own
+    lockstep iteration count — the most iterations any of its real
+    entities ran, which is what its whole ``[E, R]`` program ran — lands in
+    slot ``6 + bin_slot``, so every bin's count of every descent iteration
+    rides the same drain (``solves.newton_iterations``)."""
     real = entity_index < num_entities
     real_i = real.astype(jnp.int32)
     if cg_iterations is None:
@@ -121,17 +128,19 @@ def _accumulate_solve_stats(
     else:
         cg = (cg_iterations.astype(jnp.int32) * real_i).sum()
         cg_ents = real_i.sum()
-    return jnp.stack([
+    lockstep = jnp.max(jnp.where(real, iterations.astype(jnp.int32), 0))
+    totals = jnp.stack([
         acc[0] + real_i.sum(),
         acc[1] + ((converged & good).astype(jnp.int32) * real_i).sum(),
-        jnp.maximum(
-            acc[2],
-            jnp.max(jnp.where(real, iterations.astype(jnp.int32), 0)),
-        ),
+        jnp.maximum(acc[2], lockstep),
         acc[3] + ((~good).astype(jnp.int32) * real_i).sum(),
         acc[4] + cg,
         acc[5] + cg_ents,
     ])
+    per_bin = acc[6:]
+    if bin_slot is not None:
+        per_bin = per_bin.at[bin_slot].set(lockstep)
+    return jnp.concatenate([totals, per_bin])
 
 
 @jax.jit
@@ -170,6 +179,12 @@ class DeferredSolveStats:
                 # descent loop always passes the batched host_vec instead.
                 host_vec = np.asarray(self.device)
             stats = {k: int(host_vec[i]) for i, k in enumerate(self.KEYS)}
+            if len(host_vec) > len(self.KEYS):
+                # One lockstep iteration count per bin (see
+                # _accumulate_solve_stats); ``extra`` says what each bin is.
+                stats["bin_iterations"] = [
+                    int(v) for v in host_vec[len(self.KEYS):]
+                ]
             stats.update(self.extra)
             self._resolved = stats
         return self._resolved
@@ -225,7 +240,7 @@ def _foreign_src_idx(device_data, model_keys) -> np.ndarray:
     return src_idx
 
 
-def prefetch_warm_joins(coordinates, initial_model, telemetry=None) -> int:
+def prefetch_warm_joins(coordinates, initial_model) -> int:
     """Schedule the FIRST-HIT foreign-vocabulary warm-start key joins on
     the io pool so they overlap the fixed-effect coordinate's training
     instead of blocking the first random coordinate's train() (ROADMAP
@@ -239,11 +254,10 @@ def prefetch_warm_joins(coordinates, initial_model, telemetry=None) -> int:
     ``descent.host_transfer_bytes{path=warm_start}`` accounting is
     untouched — it meters the table transfers in ``_align_foreign_table``,
     which still run at consume time.  Returns the number of joins
-    scheduled (``descent.warm_join_prefetch`` counts them)."""
+    scheduled."""
     from photon_tpu.game.model import RandomEffectModel
     from photon_tpu.utils import io_pool
 
-    telemetry = telemetry or NULL_SESSION
     scheduled = 0
     for name, coord in coordinates.items():
         device_data = getattr(coord, "device_data", None)
@@ -273,9 +287,6 @@ def prefetch_warm_joins(coordinates, initial_model, telemetry=None) -> int:
             cache.pop(next(iter(cache)))
         cache[id(model_keys)] = (model_keys, fut)
         scheduled += 1
-        telemetry.counter(
-            "descent.warm_join_prefetch", coordinate=name
-        ).inc()
     return scheduled
 
 
@@ -365,6 +376,7 @@ def _scoring_feats(coord) -> tuple:
             coord.data.shard(coord.config.shard_name), _score_pad(coord)
         )
         dev_feats = put_sharded(leaves, coord.mesh)
+        count_h2d("scoring_cache", dev_feats)
         holder._score_feats = (dev_feats, dense)
         holder._score_cache_bytes += sum(
             leaf.nbytes for leaf in jax.tree.leaves(dev_feats)
@@ -648,6 +660,7 @@ class FixedEffectDeviceData:
                 shard, label, offset, weight, row_capacity
             )
         self.batch = shard_to_batch(shard, label, offset, weight)
+        count_h2d("fixed_shard", self.batch)
         self._train_rows_dev: Optional[Array] = None
         # Device scoring cache (residual engine): full-row-order shard
         # features + residency accounting, filled by _scoring_feats.
@@ -781,20 +794,26 @@ class RandomEffectDeviceData:
         from photon_tpu.game.batched_solve import bin_layout
         from photon_tpu.game.data import merge_buckets
 
+        from photon_tpu import telemetry
+
         n_shards = mesh_shards(self.mesh)
         for group in bin_layout(raw_buckets):
-            merged = merge_buckets([raw_buckets[i] for i in group])
-            live_entities = merged.num_entities
-            live_rows = int((merged.row_weight > 0).sum())
-            if self.row_split:
-                # Entities replicated, each entity's ROWS sharded over the
-                # mesh (solve_entities_row_split); pad row capacity, not
-                # entities.
-                merged = pad_bucket_rows(merged, n_shards)
-            else:
-                merged = pad_bucket_entities(
-                    merged, n_shards, self.dataset.num_entities
-                )
+            with telemetry.span(
+                "layout.entity_bins", column=self.config.entity_column,
+                buckets=len(group),
+            ):
+                merged = merge_buckets([raw_buckets[i] for i in group])
+                live_entities = merged.num_entities
+                live_rows = int((merged.row_weight > 0).sum())
+                if self.row_split:
+                    # Entities replicated, each entity's ROWS sharded over
+                    # the mesh (solve_entities_row_split); pad row
+                    # capacity, not entities.
+                    merged = pad_bucket_rows(merged, n_shards)
+                else:
+                    merged = pad_bucket_entities(
+                        merged, n_shards, self.dataset.num_entities
+                    )
             self.buckets.append(merged)
             self.bin_stats.append({
                 "capacity": merged.row_capacity,
@@ -824,18 +843,25 @@ class RandomEffectDeviceData:
                 self._place(jnp.asarray(feats.ids)),
                 self._place(jnp.asarray(feats.vals)),
             )
-        return {
+        dev = {
             "feats": dev_feats,
             "dense": isinstance(feats, DenseShard),
             "label": self._place(jnp.asarray(bucket.label)),
             "weight": self._place(jnp.asarray(bucket.row_weight)),
             "entity_index": jnp.asarray(bucket.entity_index),
+            # This bin's slot among the stats accumulator's per-bin
+            # iteration counts (_accumulate_solve_stats), on the device once.
+            "bin_slot": jnp.asarray(len(self.device_buckets), jnp.int32),
             "proj": proj,
             "solve_dim": solve_dim,
             "w0": self._place_w0(
                 jnp.zeros((bucket.num_entities, solve_dim), jnp.float32)
             ),
         }
+        count_h2d("entity_bins", (
+            dev["feats"], dev["label"], dev["weight"], dev["entity_index"],
+        ))
+        return dev
 
     def _sharding(self, ndim: int):
         # The mesh's one physical axis — the same axis the score tables
@@ -1650,7 +1676,8 @@ class RandomEffectCoordinate:
         # all.  The descent loop drains every coordinate's accumulator in
         # its single per-iteration stats/quarantine sync
         # (descent.host_syncs).
-        acc = jnp.zeros(6, jnp.int32)
+        n_bins = len(self.device_data.buckets)
+        acc = jnp.zeros(6 + n_bins, jnp.int32)
         from photon_tpu.fault.injection import consume_nan_injection
         from photon_tpu.game.projection import (
             IndexMapBucketProjection,
@@ -1744,6 +1771,7 @@ class RandomEffectCoordinate:
                 acc, entity_idx, num_entities, result.converged,
                 result.iterations, good,
                 cg_iterations=getattr(result, "cg_iterations", None),
+                bin_slot=dev["bin_slot"],
             )
         model = RandomEffectModel(
             table=table[:num_entities],
@@ -1753,7 +1781,15 @@ class RandomEffectCoordinate:
             task_type=self.task_type,
             variances=None if var_table is None else var_table[:num_entities],
         )
-        return model, DeferredSolveStats(acc)
+        # What each per-bin iteration count multiplies at the drain: the
+        # bin's route and its padded entities x row capacity.
+        return model, DeferredSolveStats(acc, extra={
+            "bin_routes": list(routes),
+            "bin_cells": [
+                st["total_entities"] * st["capacity"]
+                for st in self.device_data.bin_stats
+            ],
+        })
 
     def score(self, model: RandomEffectModel) -> np.ndarray:
         return model.score(self.data)

@@ -299,6 +299,22 @@ def keys_match(keys, ref, ref_array: Optional[np.ndarray] = None) -> bool:
 
 
 def build_random_effect_dataset(
+    data: GameDataset, entity_column: str, shard_name: str, **kwargs
+) -> RandomEffectDataset:
+    """:func:`_build_random_effect_dataset` under the ``layout.entity_bins``
+    span (GAME's host binning; the bin merge and padding in
+    ``game/coordinate`` open the same span)."""
+    from photon_tpu import telemetry
+
+    with telemetry.span(
+        "layout.entity_bins", column=entity_column, rows=data.num_examples
+    ):
+        return _build_random_effect_dataset(
+            data, entity_column, shard_name, **kwargs
+        )
+
+
+def _build_random_effect_dataset(
     data: GameDataset,
     entity_column: str,
     shard_name: str,

@@ -71,12 +71,25 @@ def _record_coordinate_info(telemetry, name: str, info) -> None:
         telemetry.counter("re_solver.entities", coordinate=name).inc(
             info["entities"]
         )
-        telemetry.counter("re_solver.converged_entities", coordinate=name).inc(
-            info.get("converged", 0)
-        )
         telemetry.gauge("re_solver.iterations_max", coordinate=name).set(
             info.get("iterations_max", 0)
         )
+        # Every bin's lockstep Newton iterations of THIS descent iteration
+        # (they rode the boundary drain in the stats vector), and the cells
+        # those iterations touched: padded entities x row capacity x
+        # iterations.  Counters, so a fit's total is every iteration's
+        # work, not the last one's (the re_solver.iterations_max gauge).
+        for b, (its, route, cells) in enumerate(zip(
+            info.get("bin_iterations", ()), info.get("bin_routes", ()),
+            info.get("bin_cells", ()),
+        )):
+            if route.startswith("newton"):
+                telemetry.counter(
+                    "solves.newton_iterations", coordinate=name, bin=b
+                ).inc(its)
+                telemetry.counter(
+                    "solves.cells", coordinate=name, bin=b
+                ).inc(its * cells)
         cg = info.get("cg_iters", 0)
         if cg:
             # Newton-CG bins only (ISSUE 14): mean inner-CG iterations per
@@ -153,15 +166,12 @@ class CoordinateDescent:
         """The residual state for this run: the device engine, or the host
         float64 path (escape hatch)."""
         cls = ResidualEngine if self.residual_mode == "device" else HostResiduals
-        with self.telemetry.span(
-            "descent.residuals.init", mode=self.residual_mode
-        ):
-            return cls(
-                self.training_data.offset,
-                names=list(self.coordinates),
-                mesh=self._mesh(),
-                telemetry=self.telemetry,
-            )
+        return cls(
+            self.training_data.offset,
+            names=list(self.coordinates),
+            mesh=self._mesh(),
+            telemetry=self.telemetry,
+        )
 
     def _build_validation(self):
         """The validation engine + scoring cache for a device-mode run (the
@@ -173,13 +183,12 @@ class CoordinateDescent:
                 telemetry=self.telemetry,
             )
             self._validation_cache = cache
-        with self.telemetry.span("descent.validation.init"):
-            engine = ValidationEngine(
-                self.validation_data.offset,
-                names=list(self.coordinates),
-                mesh=self._mesh(),
-                telemetry=self.telemetry,
-            )
+        engine = ValidationEngine(
+            self.validation_data.offset,
+            names=list(self.coordinates),
+            mesh=self._mesh(),
+            telemetry=self.telemetry,
+        )
         return engine, cache
 
     def _score(self, coord, model):
@@ -305,8 +314,7 @@ class CoordinateDescent:
             # The final iteration drains: a completed fit returns only
             # after its last checkpoint is PUBLISHED, and a publish failure
             # from the tail iteration surfaces here, never silently.
-            with self.telemetry.span("descent.checkpoint.drain"):
-                checkpointer.drain()
+            checkpointer.drain()
         return result
 
     def _run(
@@ -355,27 +363,23 @@ class CoordinateDescent:
                 ),
                 "this descent",
             )
-            with self.telemetry.span(
-                "descent.resume", iteration=resume_state.iteration
-            ):
-                models = dict(resume_state.models)
-                residuals.load_rows(resume_state.residual_rows)
-                if val_engine is not None:
-                    # The validation table is NOT snapshotted: re-scoring
-                    # the restored models against the cached features is
-                    # the same deterministic kernel an uninterrupted run
-                    # used to fill these rows.
-                    for name, model in models.items():
-                        val_engine.update(name, val_cache.score(model))
-                best_model = GameModel(
-                    dict(resume_state.best_models), self.task_type
-                )
-                best_metrics = dict(resume_state.best_metrics)
-                best_iteration = resume_state.best_iteration
-                history = list(resume_state.history)
-                quarantined_total = resume_state.quarantined
-                start_iteration = resume_state.iteration + 1
-            self.telemetry.counter("descent.resumes").inc()
+            models = dict(resume_state.models)
+            residuals.load_rows(resume_state.residual_rows)
+            if val_engine is not None:
+                # The validation table is NOT snapshotted: re-scoring
+                # the restored models against the cached features is
+                # the same deterministic kernel an uninterrupted run
+                # used to fill these rows.
+                for name, model in models.items():
+                    val_engine.update(name, val_cache.score(model))
+            best_model = GameModel(
+                dict(resume_state.best_models), self.task_type
+            )
+            best_metrics = dict(resume_state.best_metrics)
+            best_iteration = resume_state.best_iteration
+            history = list(resume_state.history)
+            quarantined_total = resume_state.quarantined
+            start_iteration = resume_state.iteration + 1
             self.logger.info(
                 "resumed descent after iteration %d", resume_state.iteration
             )
@@ -398,9 +402,7 @@ class CoordinateDescent:
             # the join has run beside that compute instead of blocking it.
             from photon_tpu.game.coordinate import prefetch_warm_joins
 
-            prefetch_warm_joins(
-                self.coordinates, initial_model, telemetry=self.telemetry
-            )
+            prefetch_warm_joins(self.coordinates, initial_model)
 
         # Drain guard flags from the seeding/resume updates BEFORE the loop:
         # a rejected seed row belongs to the INITIAL model, not to whatever
@@ -470,8 +472,7 @@ class CoordinateDescent:
             if preemption_requested():
                 telemetry.counter("descent.preempted").inc()
                 if checkpointer is not None and hasattr(checkpointer, "drain"):
-                    with telemetry.span("descent.checkpoint.drain"):
-                        checkpointer.drain()
+                    checkpointer.drain()
                     self.logger.info(
                         "preempted (%s) before iteration %d: last completed "
                         "iteration's checkpoint published; exiting",
@@ -503,7 +504,11 @@ class CoordinateDescent:
                         continue
                     prev_iterates[name] = models.get(name)
                     offsets = residuals.offsets_for(name)
-                    with self.logger.timed(f"iter{it}-{name}"):
+                    with self.logger.timed(f"iter{it}-{name}", span=False), \
+                            telemetry.span(
+                                "descent.coordinate", iteration=it,
+                                coordinate=name,
+                            ):
                         model, info = coord.train(
                             offsets, initial_model=models.get(name)
                         )

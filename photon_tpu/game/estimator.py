@@ -664,7 +664,7 @@ class GameEstimator:
                 )
                 continue
             with self.telemetry.span("estimator.fit", configuration=label), \
-                    self.logger.timed(f"fit-{label}"):
+                    self.logger.timed(f"fit-{label}", span=False):
                 if self.stream_chunks:
                     from photon_tpu.game.stream_descent import (
                         StreamedCoordinateDescent,

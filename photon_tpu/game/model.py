@@ -36,6 +36,7 @@ from photon_tpu.game.data import (
 from photon_tpu.parallel.mesh import to_host
 from photon_tpu.models.glm import Coefficients, GeneralizedLinearModel, model_for_task
 from photon_tpu.utils import pow2_at_least
+from photon_tpu.utils.device import named_jit
 
 Array = jax.Array
 
@@ -62,7 +63,7 @@ def shard_to_batch(
     )
 
 
-@partial(jax.jit, static_argnames=("dense",))
+@partial(named_jit, "score_fixed", static_argnames=("dense",))
 def _fixed_margins(w: Array, feats, dense: bool) -> Array:
     if dense:
         return feats @ w
@@ -106,7 +107,7 @@ def serving_gather_margins(table, safe_idx: Array, feats, dense: bool) -> Array:
     )
 
 
-@partial(jax.jit, static_argnames=("dense",))
+@partial(named_jit, "score_random", static_argnames=("dense",))
 def _random_margins(table: Array, entity_idx: Array, feats, dense: bool) -> Array:
     """Margins via gather of per-row entity coefficients; unseen entities -> 0."""
     safe = jnp.maximum(entity_idx, 0)

@@ -76,6 +76,7 @@ from photon_tpu.parallel.mesh import (
     to_host,
 )
 from photon_tpu.telemetry import NULL_SESSION
+from photon_tpu.utils.device import named_jit
 
 Array = jax.Array
 
@@ -145,7 +146,9 @@ def _neumaier_rows(scores: Array) -> tuple[Array, Array]:
     return total, comp
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+@functools.partial(
+    named_jit, "score_table_update", donate_argnums=(0, 1, 2)
+)
 def _set_row_and_resum(
     scores: Array, total: Array, comp: Array, c, new_row: Array
 ) -> tuple[Array, Array, Array, Array]:
@@ -164,20 +167,21 @@ def _set_row_and_resum(
     outer iteration and quarantines the offending coordinate.
     """
     del total, comp  # recomputed below; parameters exist to donate buffers
-    ok = jnp.all(jnp.isfinite(new_row))
-    scores = scores.at[c].set(jnp.where(ok, new_row, scores[c]))
-    new_total, new_comp = _neumaier_rows(scores)
+    with jax.named_scope("residuals/update"):
+        ok = jnp.all(jnp.isfinite(new_row))
+        scores = scores.at[c].set(jnp.where(ok, new_row, scores[c]))
+        new_total, new_comp = _neumaier_rows(scores)
     return scores, new_total, new_comp, ok
 
 
-@jax.jit
+@functools.partial(named_jit, "score_table_resum")
 def _resum_rows(scores: Array) -> tuple[Array, Array]:
     """Fresh compensated total of a (non-donated) table — the table-growth
     path rebuilds total/comp after appending rows."""
     return _neumaier_rows(scores)
 
 
-@jax.jit
+@functools.partial(named_jit, "residuals_offsets")
 def _offsets_kernel(base: Array, total: Array, comp: Array,
                     scores: Array, c) -> Array:
     """Training offsets for coordinate ``c``: ``base + Σ_{k≠c} scores[k]``
@@ -185,7 +189,7 @@ def _offsets_kernel(base: Array, total: Array, comp: Array,
     return base + ((total - scores[c]) + comp)
 
 
-@jax.jit
+@functools.partial(named_jit, "validation_composite")
 def _composite_kernel(base: Array, total: Array, comp: Array) -> Array:
     """Composite margin over ALL coordinates: ``base + Σ_k scores[k]`` as
     ``base + (total + comp)`` — the validation engine's scoring output."""
@@ -444,10 +448,9 @@ class ResidualEngine(_DeviceScoreTable):
         jitted device kernel; float32, shape ``[n_pad]``, sharded over the
         data axis (padding rows carry whatever the base padding holds —
         weight-0 rows never read them)."""
-        with self.telemetry.span("residuals.offsets", coordinate=name):
-            return _offsets_kernel(
-                self.base, self.total, self.comp, self.scores, self._row[name]
-            )
+        return _offsets_kernel(
+            self.base, self.total, self.comp, self.scores, self._row[name]
+        )
 
 
 class ValidationEngine(_DeviceScoreTable):
@@ -466,8 +469,7 @@ class ValidationEngine(_DeviceScoreTable):
     def composite(self) -> Array:
         """Composite validation margin ``base + Σ_k scores[k]`` — float32,
         ``[n_pad]``, sharded; padded rows carry weight 0 for every metric."""
-        with self.telemetry.span("validation.composite"):
-            return _composite_kernel(self.base, self.total, self.comp)
+        return _composite_kernel(self.base, self.total, self.comp)
 
 
 class HostResiduals:

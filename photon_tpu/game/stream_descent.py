@@ -783,8 +783,7 @@ class StreamedCoordinateDescent:
 
             complete("descent.iteration")
         if checkpointer is not None and hasattr(checkpointer, "drain"):
-            with self.telemetry.span("descent.checkpoint.drain"):
-                checkpointer.drain()
+            checkpointer.drain()
         return result
 
     def _run(
@@ -817,33 +816,28 @@ class StreamedCoordinateDescent:
             config_key, locked=locked, warm_start=initial_model is not None
         )
         models: Dict[str, object] = {}
-        with telemetry.span(
-            "descent.residuals.init", mode=STREAM_RESIDUAL_MODE,
-            spilled=self.spill is not None,
-        ):
-            if self.spill is not None:
-                residuals = SpilledResidualTable(
-                    self.training_data.offset, names=list(self.coordinates),
-                    plan=self.plan, store=self.spill.store,
-                    cache=self.spill.cache, telemetry=telemetry,
-                )
-                if resume_state is None:
-                    # A fresh fit must not read a previous run's published
-                    # tiles as its zero state.
-                    residuals.reset_store()
-            else:
-                residuals = TiledResidualTable(
-                    self.training_data.offset, names=list(self.coordinates),
-                    plan=self.plan, telemetry=telemetry,
-                )
+        if self.spill is not None:
+            residuals = SpilledResidualTable(
+                self.training_data.offset, names=list(self.coordinates),
+                plan=self.plan, store=self.spill.store,
+                cache=self.spill.cache, telemetry=telemetry,
+            )
+            if resume_state is None:
+                # A fresh fit must not read a previous run's published
+                # tiles as its zero state.
+                residuals.reset_store()
+        else:
+            residuals = TiledResidualTable(
+                self.training_data.offset, names=list(self.coordinates),
+                plan=self.plan, telemetry=telemetry,
+            )
         val_table = None
         if self.validation_data is not None and self.evaluators is not None:
-            with telemetry.span("descent.validation.init"):
-                val_table = TiledValidationTable(
-                    self.validation_data.offset,
-                    names=list(self.coordinates),
-                    plan=self._val_plan(), telemetry=telemetry,
-                )
+            val_table = TiledValidationTable(
+                self.validation_data.offset,
+                names=list(self.coordinates),
+                plan=self._val_plan(), telemetry=telemetry,
+            )
 
         best_model: Optional[GameModel] = None
         best_metrics: Dict[str, float] = {}
@@ -860,74 +854,70 @@ class StreamedCoordinateDescent:
             )
 
             require_fingerprint(resume_state, fp, "this streamed descent")
-            with telemetry.span(
-                "descent.resume", iteration=resume_state.iteration
-            ):
-                models = dict(resume_state.models)
-                stream_meta = resume_state.stream or {}
-                saved_digests = stream_meta.get("tile_digests")
-                rows = resume_state.residual_rows
-                if rows:
-                    residuals.load_rows(rows)
-                elif hasattr(residuals, "attach_resume"):
-                    # Spilled checkpoint: the tiles were REFERENCED, not
-                    # re-saved — adopt the on-disk part files (reads are
-                    # digest-verified; corruption is refused loudly).
-                    residuals.attach_resume()
-                if saved_digests is not None:
-                    rebuilt = residuals.tile_digests()
-                    if rebuilt != list(saved_digests) and not rows:
-                        # Referenced tiles are stale (a kill tore the
-                        # update sequence mid-write-back, or the spill
-                        # residency changed between runs).  The tiles are
-                        # a pure function of the checkpointed models over
-                        # the fingerprinted data+plan: rebuild them
-                        # deterministically and re-verify.
-                        telemetry.counter("tiles.rebuilt").inc()
-                        self.logger.info(
-                            "on-disk tiles do not match the checkpoint; "
-                            "rebuilding from the checkpointed models"
-                        )
-                        if hasattr(residuals, "reset_store"):
-                            # Spilled table: dropping the part files IS
-                            # the zero state — no stale-tile reads, no
-                            # zero-tile publishes that the model rebuild
-                            # below would immediately overwrite.
-                            residuals.reset_store()
-                        else:
-                            residuals.clear()
-                        for name, coord_model in models.items():
-                            residuals.update(
-                                name,
-                                self.coordinates[name].score_stream(
-                                    coord_model
-                                ),
-                            )
-                        residuals.drain_guard_flags()  # checkpointed = guarded
-                        rebuilt = residuals.tile_digests()
-                    if rebuilt != list(saved_digests):
-                        raise CheckpointError(
-                            "score-tile digests do not match the "
-                            "checkpoint's (per-chunk state diverged); "
-                            "refusing to resume"
-                        )
-                if val_table is not None:
-                    for name, model in models.items():
-                        val_table.update(
-                            name, self._score_validation(model)
-                        )
-                    val_table.drain_guard_flags()  # checkpointed = guarded
-                if resume_state.best_models:
-                    best_model = GameModel(
-                        dict(resume_state.best_models), self.task_type
+            models = dict(resume_state.models)
+            stream_meta = resume_state.stream or {}
+            saved_digests = stream_meta.get("tile_digests")
+            rows = resume_state.residual_rows
+            if rows:
+                residuals.load_rows(rows)
+            elif hasattr(residuals, "attach_resume"):
+                # Spilled checkpoint: the tiles were REFERENCED, not
+                # re-saved — adopt the on-disk part files (reads are
+                # digest-verified; corruption is refused loudly).
+                residuals.attach_resume()
+            if saved_digests is not None:
+                rebuilt = residuals.tile_digests()
+                if rebuilt != list(saved_digests) and not rows:
+                    # Referenced tiles are stale (a kill tore the
+                    # update sequence mid-write-back, or the spill
+                    # residency changed between runs).  The tiles are
+                    # a pure function of the checkpointed models over
+                    # the fingerprinted data+plan: rebuild them
+                    # deterministically and re-verify.
+                    telemetry.counter("tiles.rebuilt").inc()
+                    self.logger.info(
+                        "on-disk tiles do not match the checkpoint; "
+                        "rebuilding from the checkpointed models"
                     )
-                best_metrics = dict(resume_state.best_metrics)
-                best_iteration = resume_state.best_iteration
-                history = list(resume_state.history)
-                quarantined_total = resume_state.quarantined
-                start_iteration = resume_state.iteration + 1
-                resume_cursor = int(stream_meta.get("cursor", 0))
-            telemetry.counter("descent.resumes").inc()
+                    if hasattr(residuals, "reset_store"):
+                        # Spilled table: dropping the part files IS
+                        # the zero state — no stale-tile reads, no
+                        # zero-tile publishes that the model rebuild
+                        # below would immediately overwrite.
+                        residuals.reset_store()
+                    else:
+                        residuals.clear()
+                    for name, coord_model in models.items():
+                        residuals.update(
+                            name,
+                            self.coordinates[name].score_stream(
+                                coord_model
+                            ),
+                        )
+                    residuals.drain_guard_flags()  # checkpointed = guarded
+                    rebuilt = residuals.tile_digests()
+                if rebuilt != list(saved_digests):
+                    raise CheckpointError(
+                        "score-tile digests do not match the "
+                        "checkpoint's (per-chunk state diverged); "
+                        "refusing to resume"
+                    )
+            if val_table is not None:
+                for name, model in models.items():
+                    val_table.update(
+                        name, self._score_validation(model)
+                    )
+                val_table.drain_guard_flags()  # checkpointed = guarded
+            if resume_state.best_models:
+                best_model = GameModel(
+                    dict(resume_state.best_models), self.task_type
+                )
+            best_metrics = dict(resume_state.best_metrics)
+            best_iteration = resume_state.best_iteration
+            history = list(resume_state.history)
+            quarantined_total = resume_state.quarantined
+            start_iteration = resume_state.iteration + 1
+            resume_cursor = int(stream_meta.get("cursor", 0))
             self.logger.info(
                 "resumed streamed descent at iteration %d coordinate cursor "
                 "%d", start_iteration, resume_cursor,
@@ -950,9 +940,7 @@ class StreamedCoordinateDescent:
             # training — ISSUE 10 satellite; shared with the resident loop.
             from photon_tpu.game.coordinate import prefetch_warm_joins
 
-            prefetch_warm_joins(
-                self.coordinates, initial_model, telemetry=telemetry
-            )
+            prefetch_warm_joins(self.coordinates, initial_model)
 
         # Seed-guard drain: rejected seed rows belong to the initial model
         # (same semantics as the resident loop).
@@ -1002,8 +990,7 @@ class StreamedCoordinateDescent:
         def preempt_exit(where: str):
             telemetry.counter("descent.preempted").inc()
             if checkpointer is not None and hasattr(checkpointer, "drain"):
-                with telemetry.span("descent.checkpoint.drain"):
-                    checkpointer.drain()
+                checkpointer.drain()
                 hint = "resume with --resume auto"
             else:
                 hint = ("no checkpointer configured — a restart begins "
@@ -1044,7 +1031,11 @@ class StreamedCoordinateDescent:
                         )
                     prev = models.get(name)
                     offsets = _TiledOffsets(residuals, name)
-                    with self.logger.timed(f"iter{it}-{name}"):
+                    with self.logger.timed(f"iter{it}-{name}", span=False), \
+                            telemetry.span(
+                                "descent.coordinate", iteration=it,
+                                coordinate=name,
+                            ):
                         model, info = coord.train(
                             offsets, initial_model=models.get(name)
                         )

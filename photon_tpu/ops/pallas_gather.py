@@ -50,7 +50,7 @@ import numpy as np
 from jax import tree_util
 from jax.experimental import pallas as pl
 
-from photon_tpu.utils.device import pallas_interpret
+from photon_tpu.utils.device import count_h2d, pallas_interpret
 
 Array = jax.Array
 
@@ -201,20 +201,41 @@ def load_or_build_aligned_layout(
     behind the content-keyed disk cache.  ``base_hash`` (from
     :func:`layout_content_hash`) lets a caller building BOTH directions
     pay the content hash once."""
+    from photon_tpu import telemetry
+
+    # One span over the whole call, the cache's file IO as child spans: the
+    # bin-packing itself is layout.aligned_pack minus layout.cache_read and
+    # layout.cache_write.
+    with telemetry.span(
+        "layout.aligned_pack", entries=int(np.size(ids)),
+        transposed=transposed,
+    ):
+        return _load_or_build_aligned_layout(
+            np.asarray(ids), np.asarray(vals, np.float32), dim, transposed,
+            base_hash,
+        )
+
+
+def _load_or_build_aligned_layout(ids, vals, dim, transposed, base_hash):
     import logging
     import os
 
-    ids = np.asarray(ids)
-    vals = np.asarray(vals, np.float32)
+    from photon_tpu import telemetry
+
+    counter = telemetry.process_registry().counter
     path = _layout_cache_path(ids, vals, dim, transposed, base_hash)
     if path is not None and os.path.exists(path):
         try:
-            with np.load(path) as z:
-                return AlignedLayout(
+            with telemetry.span("layout.cache_read"), np.load(path) as z:
+                layout = AlignedLayout(
                     lo=z["lo"], vals=z["vals"], rows=z["rows"],
                     slab_of_tile=z["slab_of_tile"], dup_map=z["dup_map"],
                     src=z["src"], n_entries=int(z["n_entries"]),
                 )
+            counter("layout.cache_bytes", op="read").inc(
+                os.path.getsize(path)
+            )
+            return layout
         except Exception as exc:  # noqa: BLE001 — corrupt cache = rebuild
             logging.getLogger("photon_tpu.pallas_gather").warning(
                 "layout cache read failed (%s); rebuilding", exc
@@ -225,16 +246,20 @@ def load_or_build_aligned_layout(
     )
     if path is not None:
         try:
-            os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-            tmp = path + f".tmp{os.getpid()}"
-            with open(tmp, "wb") as f:
-                np.savez(
-                    f, lo=layout.lo, vals=layout.vals, rows=layout.rows,
-                    slab_of_tile=layout.slab_of_tile,
-                    dup_map=layout.dup_map, src=layout.src,
-                    n_entries=np.int64(layout.n_entries),
-                )
-            os.replace(tmp, path)
+            with telemetry.span("layout.cache_write"):
+                os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+                tmp = path + f".tmp{os.getpid()}"
+                with open(tmp, "wb") as f:
+                    np.savez(
+                        f, lo=layout.lo, vals=layout.vals, rows=layout.rows,
+                        slab_of_tile=layout.slab_of_tile,
+                        dup_map=layout.dup_map, src=layout.src,
+                        n_entries=np.int64(layout.n_entries),
+                    )
+                os.replace(tmp, path)
+            counter("layout.cache_bytes", op="write").inc(
+                os.path.getsize(path)
+            )
         except Exception as exc:  # noqa: BLE001 — best-effort cache
             logging.getLogger("photon_tpu.pallas_gather").warning(
                 "layout cache write failed (%s)", exc
@@ -465,7 +490,7 @@ def stack_device_layouts(layouts: "list[AlignedLayout]") -> AlignedLayoutDev:
         np.argsort(p.dup_map, kind="stable").astype(np.int32)
         for p in padded
     ]
-    return AlignedLayoutDev(
+    dev = AlignedLayoutDev(
         lo=jnp.asarray(np.stack([p.lo for p in padded])),
         vals=jnp.asarray(np.stack([p.vals for p in padded])),
         rows=jnp.asarray(np.stack([p.rows for p in padded])),
@@ -478,6 +503,8 @@ def stack_device_layouts(layouts: "list[AlignedLayout]") -> AlignedLayoutDev:
             p.dup_map[perm] for p, perm in zip(padded, perms)
         ])),
     )
+    count_h2d("aligned", dev)
+    return dev
 
 
 def _gather_kernel(smap_ref, w_ref, lo_ref, v_ref, o_ref):
@@ -581,7 +608,7 @@ tree_util.register_dataclass(
 def device_layout(layout: AlignedLayout) -> AlignedLayoutDev:
     """Put an :class:`AlignedLayout` on device with the gradient statics."""
     perm = np.argsort(layout.dup_map, kind="stable").astype(np.int32)
-    return AlignedLayoutDev(
+    dev = AlignedLayoutDev(
         lo=jnp.asarray(layout.lo),
         vals=jnp.asarray(layout.vals),
         rows=jnp.asarray(layout.rows),
@@ -590,6 +617,8 @@ def device_layout(layout: AlignedLayout) -> AlignedLayoutDev:
         grad_perm=jnp.asarray(perm),
         sorted_feats=jnp.asarray(layout.dup_map[perm]),
     )
+    count_h2d("aligned", dev)
+    return dev
 
 
 def _position_reduce_kernel(smap_ref, pv_ref, lo_ref, o_ref):
@@ -662,10 +691,13 @@ def aligned_segment_grad(
     """
     if interpret is None:
         interpret = pallas_interpret()
-    pv = (
-        jnp.take(per_row, al.rows.reshape(-1), axis=0).reshape(al.rows.shape)
-        * al.vals
-    ).astype(jnp.float32)
+    with jax.named_scope("pallas/gather"):
+        pv = (
+            jnp.take(per_row, al.rows.reshape(-1), axis=0).reshape(
+                al.rows.shape
+            )
+            * al.vals
+        ).astype(jnp.float32)
     return aligned_reduce(pv, al, dim, interpret=interpret)
 
 
@@ -682,13 +714,15 @@ def aligned_reduce(
     here."""
     if interpret is None:
         interpret = pallas_interpret()
-    partial = _position_partial_sums(
-        al.slab_of_tile, pv, al.lo, n_slabs=al.n_slabs, interpret=bool(interpret)
-    )
-    flat = jnp.take(partial.reshape(-1), al.grad_perm, axis=0)
-    return jax.ops.segment_sum(
-        flat, al.sorted_feats, num_segments=dim, indices_are_sorted=True
-    )
+    with jax.named_scope("pallas/reduce"):
+        partial = _position_partial_sums(
+            al.slab_of_tile, pv, al.lo, n_slabs=al.n_slabs,
+            interpret=bool(interpret),
+        )
+        flat = jnp.take(partial.reshape(-1), al.grad_perm, axis=0)
+        return jax.ops.segment_sum(
+            flat, al.sorted_feats, num_segments=dim, indices_are_sorted=True
+        )
 
 
 def aligned_grad_reference(

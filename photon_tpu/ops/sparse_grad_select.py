@@ -286,7 +286,11 @@ def _select(e_total, dim, n_rows, has_fm, has_aligned, has_benes,
         # where ``program_id`` has no evaluation rule — every pallas probe
         # was refused that way on the chip, PR 21.)  A probe that fails
         # outright raises: there is no default kernel to fall back to.
-        with jax.core.eval_context():
+        from photon_tpu import telemetry
+
+        candidates = 1 + int(bool(has_fm)) + int(with_pallas)
+        with telemetry.span("kernels.probe", candidates=candidates, size=e), \
+                jax.core.eval_context():
             _CACHE[key] = _measure(
                 e, dim, n, with_pallas, with_fm=bool(has_fm)
             )

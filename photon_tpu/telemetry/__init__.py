@@ -9,9 +9,18 @@ profiling').  One :class:`TelemetrySession` spans one driver run and owns:
   and the GAME descent loop;
 - a :class:`~photon_tpu.telemetry.tracing.Tracer` — nested wall-clock spans
   (``PhotonLogger.timed`` phases feed it automatically once the session is
-  attached to the logger);
+  attached to the logger), each also an annotation in the profiler's trace
+  and a ``span.seconds`` / ``span.count`` total in the session's registry;
 - finalization into ``<output-dir>/telemetry/`` run-report artifacts
   (:mod:`photon_tpu.telemetry.report`).
+
+Beside the sessions there is ONE process-wide registry
+(:func:`process_registry`) for facts recorded far from any session: layout
+builds, the kernel probe, kernel selections and refusals, the evaluation
+count of a fit run through ``GlmOptimizationProblem.run`` directly.  Library
+code opens such spans with the module-level :func:`span`; every run report
+and both benchmark runners append the process registry's counters to their
+own (``utils.device.kernel_metrics``).
 
 Telemetry is on by default and gated twice: per-run by the drivers'
 ``--no-telemetry`` flag, globally by ``PHOTON_TELEMETRY=off`` (or 0/false).
@@ -73,6 +82,9 @@ class _NullMetric:
     def inc(self, amount: float = 1.0) -> None:
         pass
 
+    def inc_deferred(self, amount) -> None:
+        pass
+
     def set(self, value: float) -> None:
         pass
 
@@ -123,7 +135,7 @@ class TelemetrySession:
         self.enabled = enabled
         self.write = True
         self.registry = MetricsRegistry() if enabled else _NullRegistry()
-        self.tracer = Tracer() if enabled else None
+        self.tracer = Tracer(registry=self.registry) if enabled else None
         self.started_at = time.time()
         self._t0 = time.monotonic()
         self.run_id = (
@@ -164,9 +176,10 @@ class TelemetrySession:
         from photon_tpu.utils.device import kernel_metrics
 
         metrics = self.registry.snapshot()
-        # Kernel refusals/selections are process-wide facts (recorded at
-        # trace time, far from any session): every report of the process
-        # carries them, so a refused kernel can never go unseen.
+        # The process registry's counters (kernel refusals/selections,
+        # layout spans and bytes: recorded far from any session) ride in
+        # every report of the process, so a refused kernel can never go
+        # unseen.
         metrics["counters"] = metrics["counters"] + kernel_metrics()
         report = {
             "driver": self.driver,
@@ -228,3 +241,24 @@ class TelemetrySession:
 
 
 NULL_SESSION = TelemetrySession("null", enabled=False)
+
+# The process registry and its tracer.  The tracer keeps no Span objects (a
+# long-lived process would grow without bound): its spans live in the
+# profiler's trace and in span.seconds / span.count.
+_PROCESS_REGISTRY = MetricsRegistry()
+_PROCESS_TRACER = Tracer(registry=_PROCESS_REGISTRY, keep=False)
+
+
+def process_registry() -> MetricsRegistry:
+    """The one process-wide registry (module docstring).  Not gated by
+    ``PHOTON_TELEMETRY``: a kernel refusal must reach the log and every
+    report whatever the run-scoped switch says."""
+    return _PROCESS_REGISTRY
+
+
+def span(name: str, **attributes):
+    """A span of library code that runs with no session in reach
+    (``attach_feature_major``, the kernel probe, entity binning): in the
+    profiler's trace under ``name``, and in the process registry's
+    ``span.seconds`` / ``span.count``."""
+    return _PROCESS_TRACER.span(name, **attributes)

@@ -8,12 +8,17 @@ the structured run report (:mod:`photon_tpu.telemetry.report`).
 
 Instruments are created lazily and keyed by ``(name, labels)`` so call sites
 can re-request a metric (``registry.counter("optimizer.runs", lam="0.1")``)
-without holding a handle.  All values are host-side Python floats — nothing
-here touches JAX or devices.
+without holding a handle.  All values are host-side Python floats.  The one
+edge to the device is :meth:`Counter.inc_deferred`: a count that lives on the
+device (an optimizer's evaluation count, returned by an asynchronously
+dispatched program) is handed over as it is and fetched later, so counting
+never blocks the path that dispatched it.  JAX is touched only to fetch such
+scalars, never imported here.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 from typing import Dict, List, Tuple
 
@@ -27,11 +32,14 @@ def _label_key(labels: Dict[str, object]) -> LabelKey:
 class Counter:
     """Monotonically increasing count (rows scored, solves run, ...)."""
 
-    __slots__ = ("_lock", "_value")
+    __slots__ = ("_lock", "_value", "_pending")
 
-    def __init__(self, lock: threading.RLock):
+    def __init__(self, lock: threading.RLock, pending: list | None = None):
         self._lock = lock
         self._value = 0.0
+        # The owning registry's list of (counter, device scalar) pairs not
+        # yet folded into a host total (see inc_deferred).
+        self._pending = [] if pending is None else pending
 
     def inc(self, amount: float = 1.0) -> None:
         if amount < 0:
@@ -39,9 +47,56 @@ class Counter:
         with self._lock:
             self._value += float(amount)
 
+    def inc_deferred(self, amount) -> None:
+        """Add a count that is still on the device: ``amount`` is a scalar
+        ``jax.Array`` (typically the output of a program dispatched a moment
+        ago) and is NOT fetched here.  It joins the host total at the
+        registry's next :meth:`MetricsRegistry.snapshot` (one batched
+        fetch).  A long-lived process that never snapshots holds a bounded
+        backlog: once ``_SWEEP_AT`` scalars are pending, those whose
+        programs have finished (``is_ready()``: no wait) are folded here."""
+        with self._lock:
+            self._pending.append((self, amount))
+            if len(self._pending) >= _SWEEP_AT:
+                _fold(self._pending, only_ready=True)
+
     @property
     def value(self) -> float:
+        """The host total; deferred increments not yet folded (see
+        :meth:`inc_deferred`) are not in it until the next snapshot."""
         return self._value
+
+
+# Pending deferred increments at which inc_deferred starts folding the ready
+# ones: high enough that a benchmark window or a sweep never fetches on its
+# timed path, low enough that the backlog is a few KB.
+_SWEEP_AT = 64
+
+
+def _fold(pending: list, only_ready: bool) -> None:
+    """Fold ``pending`` (counter, device scalar) pairs into their counters'
+    host totals, in place, under the registry lock the caller holds: all of
+    them with one batched ``device_get``, or — ``only_ready`` — just those
+    whose value is already computed (``is_ready()``: no wait)."""
+    if not pending:
+        return
+    if only_ready:
+        take = [
+            i for i, (_, v) in enumerate(pending)
+            if getattr(v, "is_ready", lambda: True)()
+        ]
+    else:
+        take = list(range(len(pending)))
+    if not take:
+        return
+    values = [pending[i][1] for i in take]
+    jax_mod = sys.modules.get("jax")
+    if jax_mod is not None:
+        values = jax_mod.device_get(values)
+    for i, v in zip(take, values):
+        pending[i][0]._value += float(v)
+    for i in reversed(take):
+        del pending[i]
 
 
 class Gauge:
@@ -137,6 +192,7 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.RLock()
         self._metrics: Dict[Tuple[str, LabelKey], Tuple[str, object]] = {}
+        self._pending: list = []  # deferred counter increments, unfetched
 
     def _get(self, kind: str, name: str, labels: Dict[str, object]):
         key = (name, _label_key(labels))
@@ -150,7 +206,10 @@ class MetricsRegistry:
                         f"as {existing_kind}, requested as {kind}"
                     )
                 return metric
-            metric = self._KINDS[kind](self._lock)
+            if kind == "counter":
+                metric = Counter(self._lock, self._pending)
+            else:
+                metric = self._KINDS[kind](self._lock)
             self._metrics[key] = (kind, metric)
             return metric
 
@@ -163,6 +222,13 @@ class MetricsRegistry:
     def histogram(self, name: str, **labels) -> Histogram:
         return self._get("histogram", name, labels)
 
+    def clear(self) -> None:
+        """Forget every instrument and pending increment (tests isolate the
+        process registry with it; handles taken earlier are orphaned)."""
+        with self._lock:
+            self._metrics.clear()
+            del self._pending[:]
+
     def snapshot(self) -> dict:
         """JSON-ready ``{"counters": [...], "gauges": [...], "histograms":
         [...]}``, each entry ``{"name", "labels", ...value(s)}``, sorted by
@@ -171,6 +237,7 @@ class MetricsRegistry:
         mid-``observe`` count/sum pair can never tear)."""
         out: dict = {"counters": [], "gauges": [], "histograms": []}
         with self._lock:
+            _fold(self._pending, only_ready=False)
             for (name, labels), (kind, metric) in sorted(self._metrics.items()):
                 entry = {"name": name, "labels": dict(labels)}
                 if kind == "histogram":
@@ -208,6 +275,7 @@ class MetricsRegistry:
         lines: List[str] = []
         typed: set = set()
         with self._lock:
+            _fold(self._pending, only_ready=False)
             for (name, labels), (kind, metric) in sorted(self._metrics.items()):
                 pname = sanitize(name)
                 labels = dict(labels)

@@ -82,6 +82,69 @@ def _fmt_labels(labels: dict) -> str:
     return ", ".join(f"{k}={v}" for k, v in sorted(labels.items())) or "—"
 
 
+def _counter_totals(report: dict, name: str, *label_keys: str) -> dict:
+    """``{label values (a tuple, in ``label_keys`` order): total}`` of the
+    counters called ``name``; a missing label reads ``—``."""
+    totals: dict = {}
+    for m in (report.get("metrics") or {}).get("counters") or []:
+        if m["name"] == name:
+            labels = m.get("labels", {})
+            key = tuple(labels.get(k, "—") for k in label_keys)
+            totals[key] = totals.get(key, 0) + m["value"]
+    return totals
+
+
+def _render_program_work_section(report: dict) -> list:
+    """What the program timed and counted under its own names (README
+    "Telemetry").  Spans by name: count, total and mean from ``span.seconds``
+    / ``span.count`` — the session's spans and the process registry's (the
+    layout build, the kernel probe), which no session keeps as ``Span``
+    objects.  Optimizer work: objective evaluations and line-search trials
+    beside the iterations they served (a backtracking fit shows as trials
+    above iterations; the row with no coordinate is the process registry's
+    total of the fits run through ``GlmOptimizationProblem.run``).  Layout
+    bytes: what the layout build handed to the
+    device (``layout.h2d_bytes{what}``) and moved through the layout cache
+    (``layout.cache_bytes{op}``).  Each table is absent when nothing was
+    recorded under its names."""
+    lines: list = []
+    seconds = _counter_totals(report, "span.seconds", "span")
+    counts = _counter_totals(report, "span.count", "span")
+    if seconds:
+        lines += ["", "## Spans by name", "",
+                  "| span | count | total (s) | mean (s) |", "|---|---|---|---|"]
+        for (name,), total in sorted(seconds.items(), key=lambda kv: -kv[1]):
+            n = counts.get((name,))
+            lines.append(
+                f"| {name} | {_fmt(None if n is None else int(n))} "
+                f"| {total:.3f} | {_fmt(total / n if n else None)} |"
+            )
+    work = {
+        column: _counter_totals(report, f"optimizer.{column}", "coordinate")
+        for column in ("solves", "iterations", "evaluations",
+                       "line_search_steps")
+    }
+    if work["evaluations"]:
+        lines += ["", "## Optimizer work", "",
+                  "| coordinate | solves | iterations | evaluations "
+                  "| line-search trials |", "|---|---|---|---|---|"]
+        for key in sorted(set().union(*work.values())):
+            lines.append(
+                f"| {key[0]} | " + " | ".join(
+                    _fmt(work[column].get(key)) for column in work
+                ) + " |"
+            )
+    uploads = _counter_totals(report, "layout.h2d_bytes", "what")
+    cache = _counter_totals(report, "layout.cache_bytes", "op")
+    if uploads or cache:
+        lines += ["", "## Layout bytes", "", "| what | MiB |", "|---|---|"]
+        for (what,), b in sorted(uploads.items()):
+            lines.append(f"| to device: {what} | {b / 2**20:.1f} |")
+        for (op,), b in sorted(cache.items()):
+            lines.append(f"| layout cache {op} | {b / 2**20:.1f} |")
+    return lines
+
+
 def _render_pipeline_section(report: dict) -> list:
     """The checkpoint-publisher / io-pool pipeline at a glance: how long
     the training loop actually blocked on checkpoint IO vs how long the
@@ -222,11 +285,18 @@ def _render_entity_solves_section(report: dict) -> list:
         entry[m["name"]] = m["value"]
     if not by_bin:
         return []
+    # The work each bin program did over the whole run (counters, every
+    # descent iteration): lockstep Newton iterations, and the padded
+    # entity x row cells those iterations touched.
+    iterations = _counter_totals(
+        report, "solves.newton_iterations", "coordinate", "bin")
+    cells = _counter_totals(report, "solves.cells", "coordinate", "bin")
     lines = [
         "", "## Entity solves", "",
         "| coordinate | bin | capacity | route | live entities "
-        "| padded entities | padded fraction |",
-        "|---|---|---|---|---|---|---|",
+        "| padded entities | padded fraction | Newton iterations "
+        "| cells touched |",
+        "|---|---|---|---|---|---|---|---|---|",
     ]
     for (coord, b) in sorted(by_bin):
         e = by_bin[(coord, b)]
@@ -235,7 +305,9 @@ def _render_entity_solves_section(report: dict) -> list:
             f"| {e.get('route', '—')} "
             f"| {_fmt(e.get('solves.bin_occupancy'))} "
             f"| {_fmt(e.get('solves.bin_entities_padded'))} "
-            f"| {_fmt(e.get('solves.padded_fraction'))} |"
+            f"| {_fmt(e.get('solves.padded_fraction'))} "
+            f"| {_fmt(iterations.get((coord, b)))} "
+            f"| {_fmt(cells.get((coord, b)))} |"
         )
     return lines
 
@@ -619,6 +691,7 @@ def render_markdown(report: dict) -> str:
         for name, secs in sorted(totals.items(), key=lambda kv: -kv[1]):
             lines.append(f"| {name} | {secs:.3f} |")
 
+    lines += _render_program_work_section(report)
     lines += _render_pipeline_section(report)
     lines += _render_streaming_section(report)
     lines += _render_entity_solves_section(report)

@@ -5,10 +5,28 @@ The structured upgrade of the reference's ``Timed { }`` phase logs
 span with a parent (nesting reconstructs the phase tree: driver run →
 fit-config → descent iteration → coordinate solve), wall-clock duration,
 free-form attributes, and an ok/error status recorded even when the region
-raises.  Spans are process-local and host-side — device-level profiling
-stays with ``jax.profiler`` (:func:`photon_tpu.utils.logging.maybe_profile`);
-these spans answer "where did the run's wall-clock go" without a trace
-viewer.
+raises.
+
+One span, three sinks.  ``Tracer.span(name, **attributes)`` is the single
+instrumentation point and feeds:
+
+1. the span tree of the run report (the kept :class:`Span` objects);
+2. the profiler's trace: the span's life is also a
+   ``jax.profiler.TraceAnnotation(name, **attributes)``, so whenever a trace
+   is being taken (``--profile-dir``, the benchmark's ``--trace 1``) the span
+   sits on the profiler's clock in the host plane beside the device's
+   ``XLA Ops`` line; with no trace on it costs one inactive TraceMe.  JAX is
+   used only if the process has ALREADY imported it (the index driver runs
+   jax-free; same rule as ``report.capture_environment``);
+3. the metrics registry given to the tracer: on exit the span adds its
+   duration to ``span.seconds{span=<name>}`` and 1 to
+   ``span.count{span=<name>}``, so totals by name survive without keeping
+   every ``Span`` (a tracer built with ``keep=False`` keeps none).
+
+Span NAMES are a closed, documented set (README "Telemetry"): a name never
+embeds a counter, an id, a parameter value or a path — those are
+attributes — so ``span.seconds`` has one row per instrumented place and a
+trace reader can match a name across runs.
 
 The active-span stack is thread-local, so spans opened on IO-pool worker
 threads become roots of their own trees instead of corrupting the main
@@ -19,9 +37,31 @@ from __future__ import annotations
 
 import contextlib
 import json
+import sys
 import threading
 import time
 from typing import Iterator, List, Optional
+
+
+def _trace_annotation(name: str, attributes: dict):
+    """The profiler annotation for a span, or a null context in a process
+    that has not imported JAX.  Attributes ride as TraceMe metadata (the
+    trace viewer's and ``ProfileData``'s per-event stats); only plain
+    scalars go — a metrics dict set later via ``set_attribute`` stays in the
+    span tree."""
+    jax_mod = sys.modules.get("jax")
+    if jax_mod is None:
+        return contextlib.nullcontext()
+    return jax_mod.profiler.TraceAnnotation(name, **{
+        # TraceMe packs metadata as "name#k=v,k=v#": those three characters
+        # inside a value (a sweep label "fixed=1,per_user=1") would split it.
+        k: v.translate(_TRACEME_SAFE) if isinstance(v, str) else v
+        for k, v in attributes.items()
+        if isinstance(v, (str, int, float, bool))
+    })
+
+
+_TRACEME_SAFE = str.maketrans({"#": "_", ",": ";", "=": ":"})
 
 
 class Span:
@@ -68,12 +108,16 @@ class Span:
 class Tracer:
     """Creates spans, tracks the per-thread active stack, keeps finished
     spans for export (append order == completion order, children before
-    parents)."""
+    parents) unless ``keep`` is False, and records every span's duration
+    into ``registry`` (``span.seconds`` / ``span.count`` by name) when one
+    is given."""
 
-    def __init__(self):
+    def __init__(self, registry=None, keep: bool = True):
         self._lock = threading.Lock()
         self._local = threading.local()
         self._next_id = 0
+        self._registry = registry
+        self._keep = keep
         self.finished: List[Span] = []
 
     def _stack(self) -> List[Span]:
@@ -96,10 +140,13 @@ class Tracer:
         sp = Span(name, span_id, parent, time.time(),
                   threading.current_thread().name)
         sp.attributes.update(attributes)
-        t0 = time.monotonic()
+        # Built before the push: if it raises, no span is left on the stack.
+        annotation = _trace_annotation(name, attributes)
         stack.append(sp)
+        t0 = time.monotonic()
         try:
-            yield sp
+            with annotation:
+                yield sp
         except BaseException as e:
             sp.status = "error"
             sp.error = f"{type(e).__name__}: {e}"
@@ -107,8 +154,14 @@ class Tracer:
         finally:
             sp.duration_s = time.monotonic() - t0
             stack.pop()
-            with self._lock:
-                self.finished.append(sp)
+            if self._keep:
+                with self._lock:
+                    self.finished.append(sp)
+            if self._registry is not None:
+                self._registry.counter("span.seconds", span=name).inc(
+                    sp.duration_s
+                )
+                self._registry.counter("span.count", span=name).inc()
 
     def export(self) -> List[dict]:
         with self._lock:

@@ -12,6 +12,12 @@ One home for the three facts every layer used to work out for itself:
   (:func:`record_kernel_refusal` / :func:`record_kernel_selected`) — a
   refusal is a WARNING plus a ``kernels.refused{kernel=…}`` counter in
   every run report of the process, never a quiet switch to another path.
+  The counts live in the process registry
+  (``photon_tpu.telemetry.process_registry``): this module holds no metric
+  state of its own;
+- what its device programs are called (:func:`named_jit`): a jitted
+  program takes its name from the function it wraps, and a name the
+  program chose survives a refactor where ``jit__unknown`` does not.
 
 JAX is imported lazily: the indexing driver and telemetry import this
 module without initializing a backend.
@@ -21,15 +27,6 @@ from __future__ import annotations
 
 import logging
 import sys
-import threading
-from typing import Dict
-
-_lock = threading.Lock()
-# Process-wide like the backend itself: kernel capability is a property of
-# (process, device), and the probes that feed these run at trace time,
-# far from any run-scoped telemetry session.
-_refused: Dict[str, dict] = {}
-_selected: Dict[str, int] = {}
 
 
 def backend_initialized() -> bool:
@@ -74,14 +71,46 @@ def pallas_interpret() -> bool:
     )
 
 
+def named_jit(name: str, fun, **jit_kwargs):
+    """``jax.jit(fun)`` as the device program ``jit_<name>``: the profiler's
+    ``XLA Modules`` line, the HLO module and the compile-cache key all take
+    the wrapped function's ``__name__``, which for a ``functools.partial``
+    or a lambda is ``_unknown`` / ``<lambda>``.  The published names are
+    listed in README "Telemetry"."""
+    import functools
+
+    import jax
+
+    @functools.wraps(fun)
+    def program(*args, **kwargs):
+        return fun(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kwargs)
+
+
+def count_h2d(what: str, tree) -> None:
+    """Add the bytes of a layout or shard just handed to the device (any
+    pytree of arrays) to ``layout.h2d_bytes{what}`` in the process registry.
+    Uploads are asynchronous: this is a byte count, not a span that would
+    have to block."""
+    import jax
+
+    from photon_tpu.telemetry import process_registry
+
+    process_registry().counter("layout.h2d_bytes", what=what).inc(
+        sum(leaf.nbytes for leaf in jax.tree.leaves(tree))
+    )
+
+
 def record_kernel_refusal(kernel: str, exc: BaseException) -> str:
     """The compiler (or an on-device parity gate) refused ``kernel``: log
     it once per occurrence at WARNING and count it for the run report.
     Returns the first line of the error, the part worth repeating."""
+    from photon_tpu.telemetry import process_registry
+
     first = (str(exc).strip().splitlines() or [type(exc).__name__])[0][:400]
-    with _lock:
-        entry = _refused.setdefault(kernel, {"count": 0, "error": first})
-        entry["count"] += 1
+    process_registry().counter("kernels.refused", kernel=kernel).inc()
     logging.getLogger("photon_tpu.kernels").warning(
         "kernel %s refused on this device: %s", kernel, first
     )
@@ -89,26 +118,18 @@ def record_kernel_refusal(kernel: str, exc: BaseException) -> str:
 
 
 def record_kernel_selected(kernel: str) -> None:
-    with _lock:
-        _selected[kernel] = _selected.get(kernel, 0) + 1
+    from photon_tpu.telemetry import process_registry
 
-
-def kernel_refusals() -> Dict[str, dict]:
-    with _lock:
-        return {k: dict(v) for k, v in _refused.items()}
+    process_registry().counter("kernels.selected", kernel=kernel).inc()
 
 
 def kernel_metrics() -> list:
-    """Counter rows (registry-snapshot shape) for the run report."""
-    with _lock:
-        rows = [
-            {"name": "kernels.refused", "labels": {"kernel": k},
-             "value": float(v["count"])}
-            for k, v in sorted(_refused.items())
-        ]
-        rows += [
-            {"name": "kernels.selected", "labels": {"kernel": k},
-             "value": float(n)}
-            for k, n in sorted(_selected.items())
-        ]
-    return rows
+    """Every counter of the process registry, as registry-snapshot rows
+    (``{"name", "labels", "value"}``): kernel refusals and selections, the
+    layout and probe spans (``span.seconds`` / ``span.count``), bytes handed
+    to the device, the evaluation counts of fits run with no session.  The
+    run report and both benchmark runners append these rows to their own
+    counters; the name is from when the kernels' were the only ones."""
+    from photon_tpu.telemetry import process_registry
+
+    return process_registry().snapshot()["counters"]

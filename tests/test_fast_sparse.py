@@ -379,7 +379,18 @@ def test_select_kernel_availability_fallbacks(monkeypatch):
     assert not sel.aligned_layout_wanted()
 
 
-def test_measure_correctness_gate_excludes_bad_pallas(monkeypatch):
+def _refused() -> dict:
+    """``{kernel: count}`` of the process registry's ``kernels.refused``."""
+    from photon_tpu.utils import device
+
+    return {
+        row["labels"]["kernel"]: row["value"]
+        for row in device.kernel_metrics()
+        if row["name"] == "kernels.refused"
+    }
+
+
+def test_measure_correctness_gate_excludes_bad_pallas(monkeypatch, caplog):
     """A Mosaic kernel that miscompiles — or that the compiler refuses —
     on the live backend must be DISQUALIFIED by the probe's on-device
     check, never timed into production eligibility, and never quietly: the
@@ -387,9 +398,15 @@ def test_measure_correctness_gate_excludes_bad_pallas(monkeypatch):
     every run report of the process.  A correct kernel passes."""
     import photon_tpu.ops.pallas_gather as pg
     import photon_tpu.ops.sparse_grad_select as sel
-    from photon_tpu.telemetry import TelemetrySession
-    from photon_tpu.utils import device
+    from photon_tpu.telemetry import TelemetrySession, process_registry
 
+    import logging
+
+    # Straight onto the kernels' logger: an earlier PhotonLogger("photon_tpu")
+    # in this process stops propagation to the root handler caplog owns.
+    klog = logging.getLogger("photon_tpu.kernels")
+    klog.addHandler(caplog.handler)
+    monkeypatch.setattr(klog, "propagate", False)
     real = pg.aligned_segment_grad
 
     def garbage(per_row, al, dim, interpret=None):
@@ -403,22 +420,25 @@ def test_measure_correctness_gate_excludes_bad_pallas(monkeypatch):
     def correct(per_row, al, dim, interpret=None):
         return real(per_row, al, dim, interpret=True)  # CPU-safe, right math
 
-    monkeypatch.setattr(device, "_refused", {})
+    process_registry().clear()
     monkeypatch.setattr(pg, "aligned_segment_grad", garbage)
-    choice = sel._measure(1 << 12, 256, 256, with_pallas=True)
+    with caplog.at_level("WARNING", logger="photon_tpu.kernels"):
+        choice = sel._measure(1 << 12, 256, 256, with_pallas=True)
     assert choice in ("fm", "autodiff"), "garbage pallas must be excluded"
-    assert device.kernel_refusals()["pallas"]["error"].startswith(
-        "parity failed"
-    )
+    assert _refused() == {"pallas": 1.0}
+    assert "kernel pallas refused on this device: parity failed" in caplog.text
 
-    monkeypatch.setattr(device, "_refused", {})
+    process_registry().clear()
+    caplog.clear()
     monkeypatch.setattr(pg, "aligned_segment_grad", refused)
-    choice = sel._measure(1 << 12, 256, 256, with_pallas=True)
+    with caplog.at_level("WARNING", logger="photon_tpu.kernels"):
+        choice = sel._measure(1 << 12, 256, 256, with_pallas=True)
     assert choice in ("fm", "autodiff"), "a refused pallas must be excluded"
-    assert device.kernel_refusals()["pallas"] == {
-        "count": 1,
-        "error": "Mosaic failed to compile TPU kernel: Not implemented",
-    }
+    assert _refused() == {"pallas": 1.0}
+    assert caplog.text.rstrip().endswith(
+        "kernel pallas refused on this device: "
+        "Mosaic failed to compile TPU kernel: Not implemented"
+    )
     report = TelemetrySession("t").build_report()
     assert {
         "name": "kernels.refused", "labels": {"kernel": "pallas"},
@@ -431,11 +451,12 @@ def test_measure_correctness_gate_excludes_bad_pallas(monkeypatch):
                   "Not implemented",
     }
 
-    monkeypatch.setattr(device, "_refused", {})
+    process_registry().clear()
     monkeypatch.setattr(pg, "aligned_segment_grad", correct)
     choice2 = sel._measure(1 << 12, 256, 256, with_pallas=True)
     assert choice2 in ("fm", "autodiff", "pallas")  # gate passed; timing decides
-    assert not device.kernel_refusals()
+    assert not _refused()
+    klog.removeHandler(caplog.handler)
 
 
 def test_probe_cap_env_override(monkeypatch):
@@ -547,9 +568,9 @@ def test_selection_probe_measures_under_enclosing_trace(monkeypatch):
     import jax.numpy as jnp
 
     import photon_tpu.ops.sparse_grad_select as sg
-    from photon_tpu.utils import device
+    from photon_tpu.telemetry import process_registry
 
-    monkeypatch.setattr(device, "_refused", {})
+    process_registry().clear()
     # The interpreter stands in for Mosaic: same kernel-body trace.
     monkeypatch.setattr(sg, "_pallas_eligible", lambda: True)
     saved = dict(sg._CACHE)
@@ -575,7 +596,13 @@ def test_selection_probe_measures_under_enclosing_trace(monkeypatch):
 
         jax.jit(f)(jnp.ones(2))
         assert calls, "the probe must have measured under the trace"
-        assert not device.kernel_refusals(), device.kernel_refusals()
+        assert not _refused(), _refused()
+        # The probe ran under its span (process registry: no session here).
+        assert any(
+            row["name"] == "span.count"
+            and row["labels"] == {"span": "kernels.probe"}
+            for row in process_registry().snapshot()["counters"]
+        )
     finally:
         sg._CACHE.clear()
         sg._CACHE.update(saved)

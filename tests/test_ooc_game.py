@@ -541,7 +541,6 @@ def test_warm_join_prefetch_overlaps_and_matches(game_data):
     scheduled = prefetch_warm_joins(
         {"re0": coord},
         GameModel({"re0": foreign}, "linear_regression"),
-        telemetry=coord.telemetry,
     )
     assert scheduled == 1
     from concurrent.futures import Future

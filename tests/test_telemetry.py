@@ -520,8 +520,12 @@ def test_train_driver_writes_run_report(trained_run):
     assert counters[("optimizer.solves", (("lam", "2"), ("optimizer", "lbfgs")))] == 1
     assert counters[("train.sweep_entries", ())] == 2
     span_names = {s["name"] for s in report["spans"]}
-    assert {"load-data", "train-lambda-0.5", "train-lambda-2.0",
-            "save-models"} <= span_names
+    # Span names are a closed set: the lambda is an attribute, not a name.
+    assert {"load-data", "train.lambda", "save-models"} <= span_names
+    assert sorted(
+        s["attributes"]["reg_weight"] for s in report["spans"]
+        if s["name"] == "train.lambda"
+    ) == [0.5, 2.0]
     assert report["environment"]["jax"]["backend"] == "cpu"
     # Spans mirror the logger's phase-times dict.
     assert set(report["phase_totals"]) == span_names
@@ -661,3 +665,55 @@ def test_game_driver_telemetry(tmp_path):
     assert any("metrics" in s.get("attributes", {}) for s in iter_spans)
     gauges = {e["name"] for e in report["metrics"]["gauges"]}
     assert "descent.validation_metric" in gauges
+    # The run report reads the program's own names (ISSUE 27): spans by
+    # name, the optimizer's evaluations, the per-bin solve work, the bytes
+    # the layout build handed to the device.
+    text = render_markdown(report)
+    for heading in ("## Spans by name", "## Optimizer work",
+                    "## Entity solves", "## Layout bytes"):
+        assert heading in text, heading
+    assert "| descent.iteration | 2 |" in text
+    assert "| to device: entity_bins |" in text
+    evaluations = counters[("optimizer.evaluations", (("coordinate", "fixed"),))]
+    trials = counters[
+        ("optimizer.line_search_steps", (("coordinate", "fixed"),))
+    ]
+    assert "| fixed | 2 | " in text
+    assert f"| {evaluations:g} | {trials:g} |" in text
+    its = counters[
+        ("solves.newton_iterations", (("bin", "0"), ("coordinate", "per0")))
+    ]
+    cells = counters[("solves.cells", (("bin", "0"), ("coordinate", "per0")))]
+    assert f"| {its:g} | {cells:g} |" in text
+
+
+def test_report_program_work_sections_from_counters():
+    """Each table of the program-work section reads its counters exactly and
+    is absent when they are."""
+    session = TelemetrySession("work")
+    with session.span("descent.coordinate", coordinate="fixed"):
+        pass
+    with session.span("descent.coordinate", coordinate="per_user"):
+        pass
+    session.counter("optimizer.solves", coordinate="fixed").inc(2)
+    session.counter("optimizer.iterations", coordinate="fixed").inc(13)
+    session.counter("optimizer.evaluations", coordinate="fixed").inc(19)
+    session.counter("optimizer.line_search_steps", coordinate="fixed").inc(13)
+    session.counter("layout.h2d_bytes", what="aligned").inc(3 * 2**20)
+    session.counter("layout.cache_bytes", op="write").inc(2**19)
+    report = session.build_report()
+    text = render_markdown(report)
+    (row,) = [
+        line for line in text.splitlines()
+        if line.startswith("| descent.coordinate |")
+        and line.count("|") == 5  # not the two-column phase table's row
+    ]
+    total = sum(s["duration_s"] for s in report["spans"])
+    assert row.split(" | ")[1:3] == ["2", f"{total:.3f}"]
+    assert "| fixed | 2 | 13 | 19 | 13 |" in text
+    assert "| to device: aligned | 3.0 |" in text
+    assert "| layout cache write | 0.5 |" in text
+
+    plain = render_markdown({"driver": "t", "metrics": {"counters": []}})
+    for heading in ("Spans by name", "Optimizer work", "Layout bytes"):
+        assert heading not in plain

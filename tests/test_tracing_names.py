@@ -1,0 +1,604 @@
+"""One span system, one process registry, stable device names (ISSUE 27).
+
+What a later perf PR leans on when it says "the trace shows the saving
+here": the program's spans are in the profiler's trace under their own
+names, totals by span name survive in the registry, counts that live on the
+device never add a fetch to the timed path, the optimizers count the
+evaluations they run, and the device programs and their phases carry names
+the program chose.
+"""
+
+from __future__ import annotations
+
+import glob
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from photon_tpu import telemetry
+from photon_tpu.telemetry import NULL_SESSION, MetricsRegistry, TelemetrySession
+from photon_tpu.utils import device
+from photon_tpu.utils.device import named_jit
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _counters(registry) -> dict:
+    return {
+        (c["name"], tuple(sorted(c["labels"].items()))): c["value"]
+        for c in registry.snapshot()["counters"]
+    }
+
+
+# -- one span, three sinks -----------------------------------------------------
+
+
+def _trace_events(trace_dir: str) -> list:
+    """``(plane, line, name, start_ns, end_ns, stats)`` of every event."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    return [
+        (plane.name, line.name, ev.name, int(ev.start_ns),
+         int(ev.start_ns + ev.duration_ns), dict(ev.stats))
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for ev in line.events
+    ]
+
+
+@pytest.mark.parametrize("opened_by", ["session", "process"])
+def test_span_is_in_the_profiler_trace_and_encloses_its_work(
+        tmp_path, opened_by):
+    work = named_jit(
+        "traced_work", lambda x: jnp.sum(jnp.sin(x) @ jnp.cos(x).T)
+    )
+    x = jnp.ones((128, 128))
+    work(x).block_until_ready()  # compiled before the trace starts
+    span = (
+        TelemetrySession("t").span if opened_by == "session"
+        else telemetry.span
+    )
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with span("descent.coordinate", iteration=3, coordinate="re0"):
+            work(x).block_until_ready()
+        work(x).block_until_ready()  # outside the span
+    finally:
+        jax.profiler.stop_trace()
+    events = _trace_events(str(tmp_path))
+    (found,) = [e for e in events if e[2] == "descent.coordinate"]
+    plane, _, _, start, end, stats = found
+    assert plane.startswith("/host:")
+    assert stats["iteration"] == 3 and stats["coordinate"] == "re0"
+    # The device-side events of the program (XLA:CPU runs them on host
+    # threads, tagged with their module) on the same clock: the first run
+    # of the work lies inside the span, the second after it.
+    ops = sorted(
+        (s, e) for _, _, _, s, e, st in events
+        if st.get("hlo_module") == "jit_traced_work"
+    )
+    inside = [(s, e) for s, e in ops if start <= s and e <= end]
+    after = [(s, e) for s, e in ops if s >= end]
+    assert inside and after and len(inside) + len(after) == len(ops)
+
+
+def test_span_totals_equal_the_spans_own_durations():
+    session = TelemetrySession("t")
+    for i in range(3):
+        with session.span("descent.coordinate", iteration=i):
+            with session.span("residuals.update"):
+                pass
+    by_name: dict = {}
+    for sp in session.tracer.finished:
+        by_name.setdefault(sp.name, []).append(sp.duration_s)
+    counters = _counters(session.registry)
+    for name, durations in by_name.items():
+        key = (("span", name),)
+        assert counters[("span.count", key)] == len(durations) == 3
+        assert counters[("span.seconds", key)] == pytest.approx(
+            sum(durations), rel=1e-12
+        )
+    assert session.tracer.phase_totals() == pytest.approx({
+        name: sum(d) for name, d in by_name.items()
+    })
+
+
+def test_process_span_keeps_totals_but_no_span_objects():
+    registry = telemetry.process_registry()
+    before = _counters(registry).get(
+        ("span.count", (("span", "layout.feature_major"),)), 0.0
+    )
+    with telemetry.span("layout.feature_major", entries=8) as sp:
+        sp.set_attribute("note", "kept on the span only")
+    assert _counters(registry)[
+        ("span.count", (("span", "layout.feature_major"),))
+    ] == before + 1
+    assert telemetry._PROCESS_TRACER.finished == []
+
+
+def test_disabled_session_and_error_spans():
+    with NULL_SESSION.span("descent.iteration", iteration=0) as sp:
+        sp.set_attribute("k", 1)
+    NULL_SESSION.counter("optimizer.evaluations").inc_deferred(jnp.ones(()))
+    assert NULL_SESSION.registry.snapshot()["counters"] == []
+    session = TelemetrySession("t")
+    with pytest.raises(RuntimeError):
+        with session.span("estimator.fit"):
+            raise RuntimeError("boom")
+    (sp,) = session.tracer.finished
+    assert sp.status == "error" and "boom" in sp.error
+    assert _counters(session.registry)[
+        ("span.count", (("span", "estimator.fit"),))
+    ] == 1
+
+
+def test_jax_free_process_spans_stay_jax_free():
+    # ``import photon_tpu`` itself pulls jax in (the package imports its
+    # math core): telemetry is loaded under a bare stand-in for the package,
+    # which is all a jax-free tool needs of it.
+    code = (
+        "import sys, types\n"
+        "pkg = types.ModuleType('photon_tpu')\n"
+        "pkg.__path__ = ['photon_tpu']\n"
+        "sys.modules['photon_tpu'] = pkg\n"
+        "from photon_tpu import telemetry\n"
+        "s = telemetry.TelemetrySession('index')\n"
+        "with s.span('scan', files=3):\n"
+        "    pass\n"
+        "with telemetry.span('layout.entity_bins'):\n"
+        "    pass\n"
+        "c = telemetry.process_registry().counter('optimizer.evaluations')\n"
+        "c.inc_deferred(4)\n"
+        "rows = telemetry.process_registry().snapshot()['counters']\n"
+        "assert 'jax' not in sys.modules, 'telemetry imported jax'\n"
+        "assert s.tracer.phase_totals().keys() == {'scan'}\n"
+        "print(sorted((r['name'], r['value']) for r in rows\n"
+        "             if r['name'] != 'span.seconds'))\n"
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        cwd=REPO, timeout=120,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert done.stdout.strip() == (
+        "[('optimizer.evaluations', 4.0), ('span.count', 1.0)]"
+    )
+
+
+# -- one process registry ------------------------------------------------------
+
+
+def test_kernel_metrics_rows_keep_their_shape():
+    telemetry.process_registry().clear()
+    assert device.kernel_metrics() == []
+    device.record_kernel_selected("autodiff")
+    device.record_kernel_selected("autodiff")
+    device.record_kernel_selected("fm")
+    first = device.record_kernel_refusal(
+        "pallas", RuntimeError("Mosaic failed to compile\nsecond line")
+    )
+    assert first == "Mosaic failed to compile"
+    # Byte for byte what utils/device.py's own dicts used to give.
+    assert device.kernel_metrics() == [
+        {"name": "kernels.refused", "labels": {"kernel": "pallas"},
+         "value": 1.0},
+        {"name": "kernels.selected", "labels": {"kernel": "autodiff"},
+         "value": 2.0},
+        {"name": "kernels.selected", "labels": {"kernel": "fm"},
+         "value": 1.0},
+    ]
+    # ... and every other counter of the process registry rides along, into
+    # every run report of the process.
+    with telemetry.span("kernels.probe", candidates=2, size=1024):
+        pass
+    names = [row["name"] for row in device.kernel_metrics()]
+    assert names == ["kernels.refused", "kernels.selected",
+                     "kernels.selected", "span.count", "span.seconds"]
+    report = TelemetrySession("t").build_report()
+    assert {"name": "span.count", "labels": {"span": "kernels.probe"},
+            "value": 1.0} in report["metrics"]["counters"]
+    assert not any(
+        isinstance(value, (dict, list, set))
+        for name, value in vars(device).items() if not name.startswith("__")
+    ), "utils/device.py holds no metric state of its own"
+
+
+class _Pending:
+    """A device scalar whose program has not finished: counts fetches."""
+
+    def __init__(self, value):
+        self.value, self.ready, self.fetches = value, False, 0
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        self.fetches += 1
+        return np.asarray(self.value, dtype)
+
+
+def test_deferred_increment_is_not_fetched_at_inc_time(monkeypatch):
+    registry = MetricsRegistry()
+    counter = registry.counter("optimizer.evaluations")
+    gets = []
+    real_get = jax.device_get
+    monkeypatch.setattr(
+        jax, "device_get", lambda x: gets.append(x) or real_get(x)
+    )
+    slow, slower = _Pending(11), _Pending(13)
+    counter.inc_deferred(slow)
+    counter.inc_deferred(slower)
+    assert gets == [] and slow.fetches == slower.fetches == 0
+    assert counter.value == 0.0  # not in the host total yet
+    # Ready or not, nothing is fetched while the backlog is short ...
+    slow.ready = True
+    third = _Pending(1)
+    counter.inc_deferred(third)
+    assert gets == [] and counter.value == 0.0
+    # ... and once it is long, only what is ready is folded: no wait.
+    from photon_tpu.telemetry import registry as registry_module
+
+    monkeypatch.setattr(registry_module, "_SWEEP_AT", 4)
+    counter.inc_deferred(_Pending(0))
+    assert counter.value == 11.0 and len(gets) == 1 and len(gets[0]) == 1
+    assert slower.fetches == third.fetches == 0
+    # snapshot(): one batched fetch of everything pending, exact.
+    (row,) = registry.snapshot()["counters"]
+    assert row["value"] == 25.0 and len(gets) == 2 and len(gets[1]) == 3
+    assert registry.snapshot()["counters"][0]["value"] == 25.0
+    assert len(gets) == 2  # nothing pending, nothing fetched
+
+
+def test_deferred_increment_of_a_device_array_is_exact():
+    registry = MetricsRegistry()
+    total = 0
+    for i in range(5):
+        registry.counter("optimizer.evaluations").inc_deferred(
+            jnp.asarray(7 + i, jnp.int32)
+        )
+        total += 7 + i
+    assert "optimizer_evaluations 45" in registry.to_prometheus()
+    assert _counters(registry)[("optimizer.evaluations", ())] == total
+
+
+# -- the work counts -----------------------------------------------------------
+
+
+def _tiny_logistic():
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((64, 4)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, 2, 64), jnp.float32)
+
+    def value(w):
+        z = x @ w
+        return jnp.sum(jnp.logaddexp(0.0, z) - y * z) + 0.5 * jnp.dot(w, w)
+
+    return value
+
+
+@pytest.mark.parametrize("optimizer", ["lbfgs", "newton"])
+def test_evaluations_equal_a_hand_count(optimizer):
+    from photon_tpu.core.optimizers import OptimizerConfig, lbfgs, newton
+
+    value = _tiny_logistic()
+    calls = [0]
+
+    def fun(w):
+        jax.debug.callback(lambda: calls.__setitem__(0, calls[0] + 1))
+        return jax.value_and_grad(value)(w)
+
+    config = OptimizerConfig(max_iterations=25)
+    if optimizer == "lbfgs":
+        result = lbfgs(fun, jnp.zeros(4), config)
+    else:
+        result = newton(fun, jnp.zeros(4), config, hess=jax.hessian(value))
+    jax.block_until_ready(result.w)
+    jax.effects_barrier()
+    assert int(result.evaluations) == calls[0]
+    # The initial point, the line-search trials, the two polish steps; one
+    # trial an iteration at least.
+    steps = int(result.line_search_steps)
+    assert int(result.evaluations) == 1 + steps + 2
+    assert steps >= int(result.iterations) > 0
+
+
+def test_problem_run_hands_its_counts_to_the_process_registry():
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.core.optimizers import (
+        OptimizationStatesTracker,
+        OptimizerConfig,
+    )
+    from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
+    from photon_tpu.data.batch import dense_batch
+
+    rng = np.random.default_rng(0)
+    batch = dense_batch(
+        rng.standard_normal((96, 5)).astype(np.float32),
+        rng.integers(0, 2, 96).astype(np.float32),
+    )
+    reg = RegularizationContext("l2", 1.0)
+    problem = GlmOptimizationProblem(
+        GlmObjective.create("logistic_regression", reg),
+        ProblemConfig(regularization=reg,
+                      optimizer_config=OptimizerConfig(max_iterations=12)),
+    )
+    telemetry.process_registry().clear()
+    want = {"evaluations": 0, "line_search_steps": 0}
+    for _ in range(2):
+        _, result = problem.run(batch, dim=5)
+        tracker = OptimizationStatesTracker(result)
+        want["evaluations"] += tracker.evaluations
+        want["line_search_steps"] += tracker.line_search_steps
+    counters = _counters(telemetry.process_registry())
+    assert counters[("optimizer.evaluations", ())] == want["evaluations"] > 0
+    assert counters[("optimizer.line_search_steps", ())] == (
+        want["line_search_steps"]
+    )
+    # The tracker records the same counts under a session's labels.
+    session = TelemetrySession("t")
+    tracker.record_to(session.registry, coordinate="fixed")
+    assert _counters(session.registry)[
+        ("optimizer.evaluations", (("coordinate", "fixed"),))
+    ] == tracker.evaluations
+
+
+def _game_fixture(iters: int):
+    from photon_tpu.core.objective import RegularizationContext
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import ProblemConfig
+    from photon_tpu.data.synthetic import make_game_dataset
+    from photon_tpu.game.coordinate import (
+        FixedEffectCoordinateConfig,
+        RandomEffectCoordinateConfig,
+    )
+    from photon_tpu.game.data import split_game_dataset
+    from photon_tpu.game.estimator import GameOptimizationConfiguration
+
+    def problem(lam, its):
+        return ProblemConfig(
+            regularization=RegularizationContext("l2", lam),
+            optimizer_config=OptimizerConfig(max_iterations=its),
+        )
+
+    data, _ = make_game_dataset(40, 5, 6, 3, seed=7)
+    train, val = split_game_dataset(data, 0.25)
+    config = GameOptimizationConfiguration(
+        coordinates={
+            "fixed": FixedEffectCoordinateConfig("global", problem(0.01, 8)),
+            "re0": RandomEffectCoordinateConfig("re0", "re0", problem(1.0, 6)),
+        },
+        descent_iterations=iters, name="names",
+    )
+    return train, val, config
+
+
+def test_newton_iterations_count_every_descent_iteration():
+    """``solves.newton_iterations`` sums every bin's lockstep count over
+    BOTH descent iterations (the ``re_solver.iterations_max`` gauge keeps
+    only the last one's), rides the one boundary drain, and the spans of
+    the fit nest under their closed names."""
+    import ast
+
+    from photon_tpu.game.estimator import GameEstimator
+
+    train, val, config = _game_fixture(iters=2)
+    session = TelemetrySession("t")
+    (result,) = GameEstimator(
+        "logistic_regression", train, val, telemetry=session
+    ).fit([config])
+    history = [
+        ast.literal_eval(h["coordinates"]["re0"])
+        for h in result.descent.history
+    ]
+    assert len(history) == 2
+    counters = _counters(session.registry)
+    n_bins = len(history[0]["bin_iterations"])
+    for h in history:
+        assert set(h["bin_routes"]) == {"newton"}
+        assert max(h["bin_iterations"]) == h["iterations_max"] > 0
+    for b in range(n_bins):
+        labels = (("bin", str(b)), ("coordinate", "re0"))
+        its = sum(h["bin_iterations"][b] for h in history)
+        assert counters[("solves.newton_iterations", labels)] == its
+        assert counters[("solves.cells", labels)] == sum(
+            h["bin_iterations"][b] * h["bin_cells"][b] for h in history
+        )
+    if n_bins == 1:
+        assert counters[
+            ("solves.newton_iterations", (("bin", "0"), ("coordinate", "re0")))
+        ] == sum(h["iterations_max"] for h in history)
+    # Still ONE host sync an iteration: the per-bin counts rode the drain.
+    assert counters[("descent.host_syncs", (("kind", "stats"),))] == 2
+    # The fixed effect's evaluations are counted per coordinate.
+    assert counters[
+        ("optimizer.evaluations", (("coordinate", "fixed"),))
+    ] >= counters[("optimizer.iterations", (("coordinate", "fixed"),))] + 2
+    # Closed span names, nested: estimator.fit > descent.iteration >
+    # descent.coordinate; the iteration and the coordinate are attributes.
+    spans = {sp.span_id: sp for sp in session.tracer.finished}
+    names = {sp.name for sp in spans.values()}
+    assert {"estimator.fit", "descent.iteration", "descent.coordinate",
+            "residuals.update", "descent.validate"} <= names
+    assert not any(re.search(r"\d", name) for name in names), names
+    coordinate_spans = [
+        sp for sp in spans.values() if sp.name == "descent.coordinate"
+    ]
+    assert sorted(
+        (sp.attributes["iteration"], sp.attributes["coordinate"])
+        for sp in coordinate_spans
+    ) == [(0, "fixed"), (0, "re0"), (1, "fixed"), (1, "re0")]
+    for sp in coordinate_spans:
+        parent = spans[sp.parent_id]
+        assert parent.name == "descent.iteration"
+        assert spans[parent.parent_id].name == "estimator.fit"
+    assert counters[
+        ("span.count", (("span", "descent.coordinate"),))
+    ] == 4
+    # Entity binning ran with no session in reach: the process registry.
+    assert _counters(telemetry.process_registry())[
+        ("span.count", (("span", "layout.entity_bins"),))
+    ] >= 2
+
+
+# -- stable device names -------------------------------------------------------
+
+
+def _hlo(lowered) -> tuple:
+    text = lowered.compile().as_text()
+    return text.split(",", 1)[0], set(re.findall(r'op_name="([^"]+)"', text))
+
+
+def _scopes(op_names: set, program: str) -> set:
+    """The named-scope paths found in a program's ``op_name`` metadata."""
+    found = set()
+    for name in op_names:
+        assert "jit(_unknown)" not in name and "<lambda>" not in name, name
+        if f"jit({program})" in name:
+            found |= set(re.findall(
+                r"((?:valuegrad|lbfgs|newton|fm|pallas|residuals|validation)"
+                r"/[a-z_]+)", name,
+            ))
+    return found
+
+
+def test_glm_fit_lbfgs_hlo_carries_program_and_scope_names(monkeypatch):
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
+    from photon_tpu.data.batch import (
+        attach_feature_major,
+        sparse_batch_from_rows,
+    )
+
+    rng = np.random.default_rng(0)
+    rows = [
+        (rng.choice(32, 4, replace=False), rng.standard_normal(4))
+        for _ in range(24)
+    ]
+    batch = attach_feature_major(sparse_batch_from_rows(
+        rows, rng.integers(0, 2, 24).astype(np.float32)
+    ))
+    reg = RegularizationContext("l2", 1.0)
+    problem = GlmOptimizationProblem(
+        GlmObjective.create("logistic_regression", reg),
+        ProblemConfig(regularization=reg,
+                      optimizer_config=OptimizerConfig(max_iterations=3)),
+    )
+    w0 = jnp.zeros(32, jnp.float32)
+    wanted = {
+        "autodiff": {"valuegrad/margins", "valuegrad/loss"},
+        "fm": {"valuegrad/margins", "valuegrad/loss", "valuegrad/grad",
+               "fm/gather", "fm/segment_sum"},
+    }
+    ops = {}
+    for kernel, scopes in wanted.items():
+        monkeypatch.setenv("PHOTON_SPARSE_GRAD", kernel)
+        jax.clear_caches()
+        module, ops[kernel] = _hlo(
+            problem.solver().lower(problem.objective, batch, w0)
+        )
+        assert module == "HloModule jit_glm_fit_lbfgs"
+        assert _scopes(ops[kernel], "glm_fit_lbfgs") >= scopes | {
+            "lbfgs/direction", "lbfgs/line_search",
+        }, kernel
+    # Autodiff's gradient is the transpose of the forward: its scatter-add
+    # reads transpose(jvp(valuegrad/margins)), the forward's gather
+    # jvp(valuegrad/margins).
+    assert any(
+        "transpose(jvp(valuegrad/margins))" in name and "scatter" in name
+        for name in ops["autodiff"]
+    )
+    assert any(
+        "/jvp(valuegrad/margins)" in name and "gather" in name
+        for name in ops["autodiff"]
+    )
+    assert problem.solver(vmapped=True).__name__ == "entity_fit_lbfgs"
+
+
+def test_entity_solve_newton_hlo_carries_program_and_scope_names():
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import ProblemConfig
+    from photon_tpu.data.batch import DenseBatch
+    from photon_tpu.game.batched_solve import (
+        cached_newton_cg_solver,
+        cached_newton_solver,
+    )
+
+    reg = RegularizationContext("l2", 1.0)
+    config = ProblemConfig(
+        regularization=reg, optimizer_config=OptimizerConfig(max_iterations=4)
+    )
+    objective = GlmObjective.create("logistic_regression", reg)
+    rng = np.random.default_rng(1)
+    batch = DenseBatch(
+        jnp.asarray(rng.standard_normal((3, 8, 4)), jnp.float32),
+        jnp.asarray(rng.integers(0, 2, (3, 8)), jnp.float32),
+        jnp.zeros((3, 8), jnp.float32), jnp.ones((3, 8), jnp.float32),
+    )
+    w0 = jnp.zeros((3, 4), jnp.float32)
+    module, ops = _hlo(
+        cached_newton_solver(config).lower(objective, batch, w0)
+    )
+    assert module == "HloModule jit_entity_solve_newton"
+    assert _scopes(ops, "entity_solve_newton") >= {
+        "newton/hessian", "newton/cholesky", "newton/step",
+        "newton/gradient", "valuegrad/margins", "valuegrad/loss",
+    }
+    assert cached_newton_cg_solver(config).__name__ == (
+        "entity_solve_newton_cg"
+    )
+
+
+def test_published_program_names():
+    from photon_tpu.evaluation import metrics
+    from photon_tpu.game import coordinate, model, residuals
+
+    programs = {
+        metrics.area_under_roc_curve: "metric_auc",
+        metrics.logistic_loss_metric: "metric_logloss",
+        metrics.rmse: "metric_rmse",
+        metrics._sharded_auc_kernel: "metric_sharded_auc",
+        coordinate._gather_bucket_offsets: "gather_bucket_offsets",
+        residuals._set_row_and_resum: "score_table_update",
+        residuals._offsets_kernel: "residuals_offsets",
+        residuals._composite_kernel: "validation_composite",
+        model._fixed_margins: "score_fixed",
+        model._random_margins: "score_random",
+    }
+    for program, name in programs.items():
+        assert program.__name__ == name
+    scores = jnp.linspace(-1.0, 1.0, 16)
+    labels = (scores > 0).astype(jnp.float32)
+    module, ops = _hlo(
+        metrics.area_under_roc_curve.lower(scores, labels, jnp.ones(16))
+    )
+    assert module == "HloModule jit_metric_auc"
+    assert "validation/auc" in _scopes(ops, "metric_auc")
+    module, ops = _hlo(residuals._set_row_and_resum.lower(
+        jnp.ones((2, 8)), jnp.ones(8), jnp.zeros(8), 0, jnp.ones(8)
+    ))
+    assert module == "HloModule jit_score_table_update"
+    assert "residuals/update" in _scopes(ops, "score_table_update")
+
+
+def test_descent_loop_gained_no_sanctioned_host_sync():
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    from check_host_sync import main
+
+    assert main([]) == 0
+    with open(os.path.join(REPO, "photon_tpu", "game", "descent.py")) as f:
+        source = f.read()
+    # The markers the descent loop had before the per-bin counts rode its
+    # drain: the host validation path, the per-metric scalars, the drain.
+    assert source.count("host-sync:") == 3
