@@ -1,13 +1,17 @@
-"""Damped Newton with a batched Cholesky solve — the small-dim direct method.
+"""Damped Newton with a direct SPD solve — the small-dim direct method.
 
 The per-entity GAME solves are tiny strongly-convex GLMs (``dim`` in the
 tens): exactly the regime where a direct second-order method beats the
 quasi-Newton loops — Snap ML (PAPERS.md, 1803.06333) solves the same
-hierarchical-GLM subproblems with direct second-order methods, and "Large
-Scale Distributed Linear Algebra With TPUs" (PAPERS.md, 2112.09017) grounds
-the padded batched-factorization shape this vmaps into: under ``jax.vmap``
-the Hessians stack to ``[B, dim, dim]`` and the factorization becomes one
-batched Cholesky (``cho_factor``/``cho_solve``) per Newton iteration.
+hierarchical-GLM subproblems with direct second-order methods.  Under
+``jax.vmap`` the Hessians stack to ``[B, dim, dim]``, and
+:func:`spd_solve` factors and solves them in the form that shape wants:
+up to ``LANES_MAX_DIM`` an unrolled Cholesky with the batch on the lane
+axis (``[dim, dim, B]``: every intermediate is lane-dense in ``B``), above
+it XLA's batched ``cho_factor``/``cho_solve`` custom call, which is also
+what an unbatched call runs.  On the v5e the custom call spends 1.7 us on
+each 16 x 16 matrix, 16 of 128 lanes carrying data; it was 60 % of a GAME
+fit (PERF.md, PR 28).
 
 Same contract as :func:`~photon_tpu.core.optimizers.lbfgs.lbfgs`: a single
 ``lax.while_loop`` machine whose state updates are all masked on an
@@ -55,6 +59,94 @@ Array = jax.Array
 # below any curvature that moves the solution at the 1e-5 parity tolerance.
 _RIDGE = 1e-9
 _POLISH_STEPS = 2  # full steps after the loop, one evaluation each
+# Largest dim at which a BATCHED solve is unrolled with the batch on the
+# lane axis (`_spd_solve_lanes`); above it, and unbatched, XLA's Cholesky.
+# Fixed from the v5e (PERF.md, PR 28): 13,312 systems take 0.6 / 0.7 /
+# 2.6 ms at d = 8 / 16 / 32 against 11.7 / 23.4 / 48.3 ms through the
+# batched custom call, and the d = 32 unroll compiles in 4 s; past it
+# nothing is measured, and the unroll's work and compile grow as d^3.
+LANES_MAX_DIM = 32
+
+
+def _spd_solve_xla(h: Array, g: Array) -> Array:
+    return jax.scipy.linalg.cho_solve(jax.scipy.linalg.cho_factor(h), g)
+
+
+def _spd_solve_lanes(h: Array, g: Array) -> Array:
+    """``[B, d, d]``, ``[B, d]`` -> ``[B, d]``: Cholesky and both
+    substitutions, unrolled over ``d`` with the batch as the minor (lane)
+    axis — every intermediate is a ``[rows, B]`` or ``[rows, columns, B]``
+    slab of float32 elementwise work, one ``rsqrt`` and one divide a
+    column.  Only the lower triangle of ``h`` is used.  A matrix that is not
+    positive definite gives a non-finite solution in its own lane.
+
+    Right-looking: ``t`` holds the columns still to factor, ``g`` riding as
+    one more row under them, so the forward substitution is the same slab
+    arithmetic as the factor.  ``raw[j]`` is column ``j`` before its
+    scaling, ``L[j, j] * (L[j:, j], y[j])``, and the back substitution
+    solves ``raw[j][0] x[j] + sum_k raw[j][k] x[k] = raw[j][-1]``: linear
+    in entries that each carry one rounding of ``h``'s scale, with no pivot
+    computed twice.  (A pivot's reciprocal used again in the substitutions
+    is not safe under XLA: a fusion recomputes the cancelling sum behind it
+    with its own rounding, and at cond 1e4 a 1e-5 mismatch costs 50 x the
+    backward error.)"""
+    d = h.shape[-1]
+    t = lax.concatenate(
+        [jnp.moveaxis(h, 0, -1), jnp.moveaxis(g, 0, -1)[None]], 0
+    )  # [d + 1, d, B]
+    # ``lax`` calls, not operators: the unroll is traced three times a bin
+    # program, and an operator on a tracer costs five times its ``lax`` op.
+    raw = []
+    for j in range(d):
+        c = lax.index_in_dim(t, 0, axis=1, keepdims=False)
+        raw.append(c)  # rows j.., then g's: [d - j + 1, B]
+        if j + 1 < d:
+            inv = lax.rsqrt(lax.slice_in_dim(c, 0, 1))
+            col = lax.mul(lax.slice_in_dim(c, 1, None), inv)
+            t = lax.sub(
+                lax.slice(t, (1, 1, 0), t.shape),
+                lax.mul(
+                    lax.expand_dims(col, (1,)),
+                    lax.expand_dims(lax.slice_in_dim(col, 0, -1), (0,)),
+                ),
+            )
+    nan = jnp.full_like(raw[0][:1], jnp.nan)
+    x = raw[0][:0]  # x[j + 1:], so far none
+    for c in reversed(raw):  # L^T x = y, each row times L[j, j]
+        pivot = lax.slice_in_dim(c, 0, 1)
+        rhs = lax.sub(
+            lax.slice_in_dim(c, -1, None),
+            jnp.sum(lax.mul(lax.slice_in_dim(c, 1, -1), x), 0, keepdims=True),
+        )
+        x_j = lax.div(rhs, lax.select(lax.gt(pivot, 0.0), pivot, nan))
+        x = lax.concatenate([x_j, x], 0)
+    return jnp.moveaxis(x, 0, -1)
+
+
+def factorization_kind(dim: int) -> str:
+    """Which form a batched :func:`spd_solve` takes at static ``dim``:
+    ``lanes`` (unrolled, batch on the lane axis) or ``xla`` (the batched
+    ``Cholesky`` custom call) — what ``solves.factorization{kind}`` counts."""
+    return "lanes" if dim <= LANES_MAX_DIM else "xla"
+
+
+@jax.custom_batching.custom_vmap
+def spd_solve(h: Array, g: Array) -> Array:
+    """``h^-1 g`` for a symmetric positive definite ``h``.  Unbatched it is
+    ``cho_factor`` / ``cho_solve``; under ``vmap`` the same factor-and-solve
+    runs in the form the batch shape wants (:func:`factorization_kind`)."""
+    return _spd_solve_xla(h, g)
+
+
+@spd_solve.def_vmap
+def _spd_solve_vmap(axis_size, in_batched, h, g):
+    if not in_batched[0]:
+        h = jnp.broadcast_to(h, (axis_size, *h.shape))
+    if not in_batched[1]:
+        g = jnp.broadcast_to(g, (axis_size, *g.shape))
+    if factorization_kind(h.shape[-1]) == "lanes":
+        return _spd_solve_lanes(h, g), True
+    return jax.vmap(_spd_solve_xla)(h, g), True
 
 
 class _State(NamedTuple):
@@ -116,8 +208,7 @@ def newton(
             ridge = _RIDGE * (1.0 + jnp.max(jnp.abs(jnp.diagonal(h))))
             h = h + ridge * eye
         with jax.named_scope("newton/cholesky"):
-            chol = jax.scipy.linalg.cho_factor(h)
-            return -jax.scipy.linalg.cho_solve(chol, g)
+            return -spd_solve(h, g)
 
     def body(s: _State):
         step = solve(s.w, s.g)

@@ -13,9 +13,11 @@ it:
   restores the one-bucket-per-capacity loop (the escape hatch and the
   bench's bucket-loop baseline).
 - **Solver routing** — :func:`solver_route` picks, per bin, between the
-  batched-Cholesky damped Newton (``core.optimizers.newton`` vmapped over
-  the entity axis: ``[B, dim, dim]`` Hessians, one batched ``cho_factor``/
-  ``cho_solve`` per iteration — the 2112.09017 padded-factorization shape)
+  batched damped Newton (``core.optimizers.newton`` vmapped over the
+  entity axis: ``[B, dim, dim]`` Hessians, one ``newton.spd_solve`` per
+  iteration — an unrolled Cholesky with the entities on the lane axis up
+  to ``newton.LANES_MAX_DIM``, XLA's batched ``cho_factor``/``cho_solve``
+  above it; ``solves.factorization{kind}`` says which)
   for the common small-``solve_dim`` smooth case, and the existing vmapped
   L-BFGS/OWL-QN/TRON program for everything else (L1 bins, large dims,
   row-split placement) — so every existing ``problem`` config still solves.
@@ -56,7 +58,7 @@ import os
 import jax
 
 from photon_tpu.core.optimizers import OptimizerConfig
-from photon_tpu.core.optimizers.newton import newton
+from photon_tpu.core.optimizers.newton import factorization_kind, newton
 from photon_tpu.core.optimizers.newton_cg import newton_cg
 from photon_tpu.core.problem import ProblemConfig, _compute_variances, hvp_at_for
 from photon_tpu.models.glm import Coefficients
@@ -109,7 +111,7 @@ def bin_layout(buckets: tuple) -> list:
 
 def solver_route(problem: ProblemConfig, solve_dim: int,
                  row_split: bool = False) -> str:
-    """Which solver a bin runs: ``newton`` (batched Cholesky) for smooth
+    """Which solver a bin runs: ``newton`` (batched direct solve) for smooth
     small-dim problems, ``newton_cg`` (matrix-free Hessian-vector CG) for
     smooth bins past the dense-Hessian cap up to ``newton_cg_max_dim``,
     ``row_split`` under row-split placement, else ``vmapped`` (the
@@ -222,7 +224,7 @@ def _cached_newton_cg_solver(cfg: OptimizerConfig, variance: str):
 
 
 def record_bin_telemetry(telemetry, coordinate: str, bin_stats: list,
-                         routes: list) -> None:
+                         routes: list, solve_dims: list) -> None:
     """Export the bin layout's padding economics as gauges — the ISSUE 8
     observability satellite: ``solves.bin_occupancy`` (LIVE entities per
     bin), ``solves.bin_entities_padded`` (mesh-padding slots), and
@@ -233,8 +235,13 @@ def record_bin_telemetry(telemetry, coordinate: str, bin_stats: list,
     ``solves.routed{route}`` counter (ISSUE 14 satellite) counts the LIVE
     entities each route received — a silently-downgraded bin (L1,
     over-cap dim falling back to ``vmapped``) shows up in the run report
-    instead of being inferred from timings."""
-    for b, (stats, route) in enumerate(zip(bin_stats, routes)):
+    instead of being inferred from timings.  ``solves.factorization{kind}``
+    counts the LIVE entities of each ``newton`` bin by the form its
+    factor-and-solve takes at the bin's static solve dim
+    (``newton.factorization_kind``: ``lanes`` or ``xla``)."""
+    for b, (stats, route, dim) in enumerate(
+        zip(bin_stats, routes, solve_dims)
+    ):
         labels = dict(
             coordinate=coordinate, bin=str(b),
             capacity=str(stats["capacity"]), route=route,
@@ -242,6 +249,11 @@ def record_bin_telemetry(telemetry, coordinate: str, bin_stats: list,
         telemetry.counter(
             "solves.routed", coordinate=coordinate, route=route
         ).inc(stats["live_entities"])
+        if route == "newton":
+            telemetry.counter(
+                "solves.factorization", coordinate=coordinate,
+                kind=factorization_kind(dim),
+            ).inc(stats["live_entities"])
         telemetry.gauge("solves.bin_occupancy", **labels).set(
             stats["live_entities"]
         )

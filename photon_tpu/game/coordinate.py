@@ -1696,6 +1696,7 @@ class RandomEffectCoordinate:
                 getattr(self, "telemetry", NULL_SESSION),
                 getattr(self, "fault_name", self.config.shard_name),
                 self.device_data.bin_stats, routes,
+                [dev["solve_dim"] for dev in self.device_data.device_buckets],
             )
             self._bins_recorded = len(routes)
         for i, bucket in enumerate(self.device_data.buckets):

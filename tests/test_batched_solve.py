@@ -13,17 +13,29 @@ suite always used for cross-solver comparisons).
 """
 
 import contextlib
+import functools
 import os
 import types
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from photon_tpu.core.objective import RegularizationContext
 from photon_tpu.core.optimizers import OptimizerConfig
+from photon_tpu.core.optimizers.newton import (
+    _spd_solve_xla,
+    factorization_kind,
+    spd_solve,
+)
 from photon_tpu.core.problem import ProblemConfig
 from photon_tpu.data.synthetic import make_game_data
-from photon_tpu.game.batched_solve import bin_layout, solver_route
+from photon_tpu.game.batched_solve import (
+    bin_layout,
+    cached_newton_solver,
+    solver_route,
+)
 from photon_tpu.game.coordinate import (
     RandomEffectCoordinate,
     RandomEffectCoordinateConfig,
@@ -647,6 +659,181 @@ def test_residual_engine_grow_preserves_rows():
         np.testing.assert_allclose(got, want, atol=1e-6)
         with pytest.raises(ValueError, match="appends"):
             engine.grow(base_offset)
+
+
+# ---------------------------------------------------------------------------
+# The SPD factor-and-solve behind the Newton step (ISSUE 28): batched, the
+# batch rides the lane axis in an unrolled Cholesky; unbatched or above
+# ``LANES_MAX_DIM`` it is XLA's cho_factor / cho_solve
+# ---------------------------------------------------------------------------
+
+
+def _spd_systems(d, batch, cond, seed=0):
+    """``[batch, d, d]`` SPD matrices with eigenvalues spread log-uniformly
+    over ``[1, cond]`` (both ends present), and right-hand sides, float64."""
+    rng = np.random.default_rng(1000 * d + batch + seed)
+    q = np.linalg.qr(rng.normal(size=(batch, d, d)))[0]
+    eig = np.exp(rng.uniform(0.0, np.log(cond), size=(batch, d)))
+    eig[:, 0], eig[:, -1] = 1.0, cond
+    h = np.einsum("bij,bj,bkj->bik", q, eig, q)
+    return 0.5 * (h + h.transpose(0, 2, 1)), rng.normal(size=(batch, d))
+
+
+def _solve_errors(h, g, x):
+    """(forward, backward) relative errors of ``x`` as a solution of ``h x =
+    g``: against a float64 NumPy solve, and the residual over ``|h| |x|``
+    — the second is what a stable solver keeps at float32's rounding
+    whatever the conditioning."""
+    x = np.asarray(x, np.float64)
+    want = np.linalg.solve(h, g[..., None])[..., 0]
+    forward = np.linalg.norm(x - want, axis=1) / np.linalg.norm(want, axis=1)
+    residual = np.einsum("bij,bj->bi", h, x) - g
+    backward = np.linalg.norm(residual, axis=1) / (
+        np.linalg.norm(h, axis=(1, 2), ord=2) * np.linalg.norm(x, axis=1)
+    )
+    return forward.max(), backward.max()
+
+
+@functools.cache
+def _vmapped(fn):
+    return jax.jit(jax.vmap(fn))
+
+
+@pytest.mark.parametrize("cond", [10.0, 1e4], ids=["well", "ill"])
+@pytest.mark.parametrize("batch", [1, 7, 130])
+@pytest.mark.parametrize("d", [1, 2, 8, 16, 32, 40])
+def test_spd_solve_under_vmap_matches_lapack_and_f64(d, batch, cond):
+    """d = 40 is above ``LANES_MAX_DIM``: the same call, XLA's form."""
+    assert factorization_kind(d) == ("lanes" if d <= 32 else "xla")
+    h, g = _spd_systems(d, batch, cond if d > 1 else 1.0)
+    h32, g32 = jnp.asarray(h, jnp.float32), jnp.asarray(g, jnp.float32)
+    fwd, bwd = _solve_errors(h, g, _vmapped(spd_solve)(h32, g32))
+    ref_fwd, ref_bwd = _solve_errors(
+        h, g, _vmapped(_spd_solve_xla)(h32, g32)
+    )
+    # The residual is at float32's rounding at either conditioning, and no
+    # worse than 3 x LAPACK's own.  The distance from the float64 solution
+    # is bounded by cond x rounding for any stable float32 solver (one
+    # system's reading swings inside that bound), so it is held to 5e-6
+    # and 3 x LAPACK's where the conditioning allows, to the bound else.
+    assert bwd <= 5e-6 and bwd <= 3 * ref_bwd + 1e-7
+    if cond == 10.0:
+        assert fwd <= 5e-6 and fwd <= 3 * ref_fwd + 1e-7
+    else:
+        assert fwd <= cond * 2.0 ** -23
+
+
+@pytest.mark.parametrize("d", [2, 16])
+def test_spd_solve_non_positive_definite_lane_is_non_finite(d):
+    h, g = _spd_systems(d, 7, 10.0)
+    h32, g32 = jnp.asarray(h, jnp.float32), jnp.asarray(g, jnp.float32)
+    solve = _vmapped(spd_solve)
+    good = np.asarray(solve(h32, g32))
+    for bad_h in (-h32[3], h32[3].at[d - 1, d - 1].set(-1.0),
+                  jnp.zeros_like(h32[3])):
+        got = np.asarray(solve(h32.at[3].set(bad_h), g32))
+        assert not np.isfinite(got[3]).any()
+        keep = np.arange(7) != 3
+        np.testing.assert_array_equal(got[keep], good[keep])
+
+
+def test_spd_solve_unbatched_operand_and_nested_vmap():
+    h, g = _spd_systems(8, 5, 10.0)
+    h32, g32 = jnp.asarray(h, jnp.float32), jnp.asarray(g, jnp.float32)
+    want = np.linalg.solve(h, g[..., None])[..., 0]
+    one_h = jax.vmap(spd_solve, in_axes=(None, 0))(h32[0], g32)
+    np.testing.assert_allclose(
+        one_h, np.linalg.solve(h[0], g.T).T, rtol=2e-5, atol=1e-6)
+    one_g = jax.vmap(spd_solve, in_axes=(0, None))(h32, g32[0])
+    np.testing.assert_allclose(
+        one_g, np.linalg.solve(h, g[0][None, :, None])[..., 0],
+        rtol=2e-5, atol=1e-6)
+    nested = jax.vmap(jax.vmap(spd_solve))(
+        jnp.stack([h32, h32]), jnp.stack([g32, 2 * g32]))
+    np.testing.assert_allclose(nested[1], 2 * want, rtol=2e-5, atol=1e-6)
+
+
+def _newton_bin_batch(d, entities=6, rows=8):
+    from photon_tpu.data.batch import DenseBatch
+
+    rng = np.random.default_rng(0)
+    return DenseBatch(
+        x=jnp.asarray(rng.normal(size=(entities, rows, d)), jnp.float32),
+        label=jnp.asarray(rng.integers(0, 2, size=(entities, rows)),
+                          jnp.float32),
+        offset=jnp.zeros((entities, rows), jnp.float32),
+        weight=jnp.ones((entities, rows), jnp.float32),
+    )
+
+
+def _factorization_ops(lowered) -> bool:
+    text = lowered.as_text().lower()
+    return "cholesky" in text or "triangular_solve" in text
+
+
+@pytest.mark.parametrize("d,batched,xla_call", [
+    (16, True, False),   # the cell's width: the lane form, no custom call
+    (40, True, True),    # above LANES_MAX_DIM: XLA's batched Cholesky
+    (16, False, True),   # an unbatched newton is cho_factor / cho_solve
+])
+def test_entity_solve_newton_lowering(d, batched, xla_call):
+    from photon_tpu.core.objective import GlmObjective
+    from photon_tpu.game.batched_solve import _run_newton_fit
+
+    problem = _problem()
+    objective = GlmObjective.create(
+        "logistic_regression", problem.regularization)
+    batch = _newton_bin_batch(d)
+    w0 = jnp.zeros((batch.x.shape[0], d), jnp.float32)
+    if batched:
+        lowered = cached_newton_solver(problem).lower(objective, batch, w0)
+        assert "entity_solve_newton" in lowered.as_text()
+    else:
+        one = jax.tree.map(lambda leaf: leaf[0], batch)
+        lowered = jax.jit(functools.partial(
+            _run_newton_fit, cfg=problem.optimizer_config, variance="none",
+        )).lower(objective, one, w0[0])
+    assert _factorization_ops(lowered) == xla_call
+
+
+@pytest.mark.parametrize(
+    "task", ["logistic_regression", "poisson_regression"])
+def test_batched_newton_matches_unbatched_entity_by_entity(task, monkeypatch):
+    """A 3-bin toy coordinate through ``cached_newton_solver`` (the lane
+    form) against ``newton`` run one entity at a time (cho_factor /
+    cho_solve): the same fit to 1e-6."""
+    from photon_tpu.game.batched_solve import _run_newton_fit
+    from photon_tpu.game.coordinate import _bucket_offsets
+
+    monkeypatch.setenv("PHOTON_SOLVE_BIN_WASTE", "1.2")
+    monkeypatch.setenv("PHOTON_SOLVE_MAX_BINS", "3")
+    data = _dataset(n_entities=40, rows_mean=8, dim=8)
+    config = _config()
+    coord = RandomEffectCoordinate(data, config, task)
+    device_data = coord.device_data
+    assert len(device_data.buckets) == 3
+    assert coord._bin_routes() == ["newton"] * 3
+    solver = cached_newton_solver(config.problem)
+    single = jax.jit(functools.partial(
+        _run_newton_fit, cfg=config.problem.optimizer_config,
+        variance="none",
+    ))
+    offsets = np.zeros(data.num_examples, np.float32)
+    for i, bucket in enumerate(device_data.buckets):
+        batch = device_data.batch_for(
+            i, _bucket_offsets(device_data, i, bucket, offsets))
+        w0 = device_data.device_buckets[i]["w0"]
+        coefficients, result = solver(coord.problem.objective, batch, w0)
+        assert bool(np.all(np.asarray(result.converged)))
+        for e in range(w0.shape[0]):
+            want, _ = single(
+                coord.problem.objective,
+                jax.tree.map(lambda leaf: leaf[e], batch), w0[e],
+            )
+            np.testing.assert_allclose(
+                np.asarray(coefficients.means[e]), np.asarray(want.means),
+                atol=1e-6, rtol=0,
+            )
 
 
 # ---------------------------------------------------------------------------

@@ -509,6 +509,50 @@ def test_routed_entities_counter_per_route():
     assert routed(session2, "newton_cg") == 0
 
 
+def test_factorization_counter_follows_the_solve_dim():
+    """ISSUE 28 satellite: ``solves.factorization{coordinate,kind}`` counts
+    each ``newton`` bin's live entities once, under the form its
+    factor-and-solve takes at the bin's static solve dim (``lanes`` up to
+    ``LANES_MAX_DIM``, ``xla`` above), and the run report's "Entity solves"
+    section shows it.  Other routes factor nothing and count nothing."""
+    from photon_tpu.core.optimizers.newton import LANES_MAX_DIM
+    from photon_tpu.telemetry.report import render_markdown
+
+    def forms(session):
+        return {
+            (c["labels"]["coordinate"], c["labels"]["kind"]): c["value"]
+            for c in session.registry.snapshot()["counters"]
+            if c["name"] == "solves.factorization"
+        }
+
+    small = TelemetrySession("t-form-lanes")
+    data = _dataset(dim=6)
+    coord, _, _, routes = _train(data, _config(), telemetry=small)
+    assert set(routes) == {"newton"} and len(routes) > 1
+    assert forms(small) == {("per_entity", "lanes"): 40}
+    # A second descent iteration over the same layout does not count again.
+    coord.train(np.zeros(data.num_examples, np.float32))
+    assert forms(small) == {("per_entity", "lanes"): 40}
+
+    wide = TelemetrySession("t-form-xla")
+    dim = LANES_MAX_DIM + 4
+    _, _, _, routes = _train(_dataset(dim=dim), _config(), telemetry=wide)
+    assert set(routes) == {"newton"}
+    assert forms(wide) == {("per_entity", "xla"): 40}
+
+    cg = TelemetrySession("t-form-cg")
+    _train(_dataset(dim=6), _config(), telemetry=cg, **_FORCE_CG)
+    assert forms(cg) == {}
+
+    text = render_markdown({
+        "driver": "t", "run_id": "r", "status": "ok", "duration_s": 1.0,
+        "metrics": small.registry.snapshot(),
+    })
+    section = text[text.index("## Entity solves"):]
+    assert "| coordinate | factorization | live entities |" in section
+    assert "| per_entity | lanes | 40 |" in section
+
+
 # ---------------------------------------------------------------------------
 # Explicit newton_cg as a first-class optimizer (fixed effects too)
 # ---------------------------------------------------------------------------
