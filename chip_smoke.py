@@ -82,7 +82,7 @@ GAME_SPEC = "synthetic-game:" + ":".join(map(str, GAME_DIMS))
 
 # Kernels auto mode may pick: each must compile and match on this device.
 # xchg is an explicit opt-in whose status the table reports (ops/vperm.py).
-AUTO_KERNELS = ("autodiff", "fm", "pallas")
+AUTO_KERNELS = ("autodiff", "fm", "pallas", "blocked")
 TIME_LIMIT_S = 1150  # the contract allows 1200, compilation included
 
 

@@ -166,8 +166,14 @@ class GlmObjective:
         the same position-reduce kernel — KERNEL_NOTES.md option (a)); the
         benes path runs the slab gather + static Clos permutation
         (ops/benes.py — no random E-access); everything else takes the
-        row-major XLA gather.  The single dispatch point for margins AND
-        Hv's ``X v``."""
+        row-major XLA gather; the blocked path gathers inside VMEM over the
+        batch's entry tiles (ops/block_tiles.py).  The single dispatch point
+        for margins AND Hv's ``X v``."""
+        if kernel == "blocked":
+            from photon_tpu.ops.block_tiles import block_tiles_product
+
+            with jax.named_scope("blocked/xw"):
+                return block_tiles_product(u, batch.bt, batch.ids.shape[0])
         if kernel == "benes":
             from photon_tpu.ops.benes import benes_xu_product
 
@@ -180,7 +186,7 @@ class GlmObjective:
         return jnp.sum(jnp.take(u, batch.ids, axis=0) * batch.vals, axis=-1)
 
     def _margins_for_kernel(self, kernel: str, w: Array, batch: Batch) -> Array:
-        fwd_kernel = kernel == "benes" or (
+        fwd_kernel = kernel in ("benes", "blocked") or (
             kernel in ("pallas", "xchg") and batch.al_t is not None
         )
         if not fwd_kernel:
@@ -198,7 +204,9 @@ class GlmObjective:
     # derivative, the L2 term) and ``valuegrad/grad`` (the reduction into
     # the coefficients, with the kernel's own stages under it:
     # ``fm/gather``, ``fm/segment_sum``, ``pallas/gather``,
-    # ``pallas/reduce``).  On the autodiff path the gradient is the
+    # ``pallas/reduce``; the blocked kernel's two directions are
+    # ``blocked/xw`` under margins and ``blocked/xtdz`` under grad).  On
+    # the autodiff path the gradient is the
     # transpose of the forward, so its scatter-add reads
     # ``transpose(jvp(valuegrad/margins))`` — see ops/KERNEL_NOTES.md.
     def data_value(self, w: Array, batch: Batch) -> Array:
@@ -218,7 +226,8 @@ class GlmObjective:
     def _sparse_kernel(self, batch: Batch, dim: Optional[int] = None) -> Optional[str]:
         """Which static-layout gradient kernel applies to this batch:
         ``"fm"`` (pre-sorted segment sum over FeatureMajorAux), ``"pallas"``
-        (slab-aligned Mosaic reduce over AlignedLayoutDev), or ``None``
+        (slab-aligned Mosaic reduce over AlignedLayoutDev), ``"blocked"``
+        (both directions in VMEM over BlockTiles), or ``None``
         (autodiff — the unsorted scatter XLA lowers is faster on some
         platforms).  When the coefficient dim is known, the choice is the
         measured-on-this-backend selection (ops/sparse_grad_select.py)."""
@@ -235,27 +244,37 @@ class GlmObjective:
         has_xchg = batch.xchg is not None and (
             has_al or getattr(batch.xchg, "bounds", None) is not None
         )
-        if not (has_fm or has_al or has_xchg):
+        has_blocked = batch.bt is not None
+        if not (has_fm or has_al or has_xchg or has_blocked):
             return None
         if dim is None:
             if has_fm:
                 return "fm"
             if has_al:
                 return "pallas"
-            return "xchg"  # bounds-only route (streamed cumsum chunks)
+            if has_xchg:
+                return "xchg"  # bounds-only route (streamed cumsum chunks)
+            return "blocked"
         from photon_tpu.ops.sparse_grad_select import select_kernel
 
         n, k = batch.ids.shape
         choice = select_kernel(
             n * k, dim, n,
             has_fm=has_fm, has_aligned=has_al, has_benes=has_benes,
-            has_xchg=has_xchg,
+            has_xchg=has_xchg, has_blocked=has_blocked,
         )
         return None if choice == "autodiff" else choice
 
     def _segment_grad(self, kernel: str, per_row: Array, batch: Batch, dim: int) -> Array:
         """``g[f] = sum_e per_row[row_e] * val_e`` via the selected static
         layout (the reduction both the gradient and Hv share)."""
+        if kernel == "blocked":
+            from photon_tpu.ops.block_tiles import block_tiles_product
+
+            with jax.named_scope("blocked/xtdz"):
+                return block_tiles_product(
+                    per_row, batch.bt, dim, transpose=True
+                )
         if kernel == "xchg":
             from photon_tpu.ops.vperm import xchg_segment_grad
 
@@ -339,10 +358,11 @@ class GlmObjective:
         pallas kernel has no JVP rule (``pallas_call`` is not
         differentiable), so callers that re-differentiate the gradient
         (normalized Hv below) route it to the fm layout — always built
-        alongside the aligned one — or plain autodiff.  The benes path
-        contains the same pallas_call and routes identically."""
+        alongside the aligned one — or plain autodiff.  The benes
+        and blocked paths contain a pallas_call too and route
+        identically."""
         kernel = self._sparse_kernel(batch, int(w.shape[0]))
-        if kernel in ("pallas", "benes", "xchg"):
+        if kernel in ("pallas", "benes", "xchg", "blocked"):
             kernel = "fm" if batch.fm is not None else None
         if kernel is not None:
             _, g = self._fast_data_value_and_grad(w, batch, kernel)

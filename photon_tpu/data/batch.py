@@ -121,6 +121,12 @@ class SparseBatch(NamedTuple):
     # ``PHOTON_SPARSE_GRAD`` is ``xchg`` or ``auto``.  Requires ``al``
     # (and uses ``al_t`` for margins when present).
     xchg: Optional["object"] = None
+    # Optional row-block x feature-block entry tiles
+    # (ops/block_tiles.BlockTiles) for the `blocked` kernel: margins,
+    # gradient and Hv with both random accesses inside VMEM.  Built by
+    # ``attach_feature_major(..., aligned_dim=d)`` on single-block batches
+    # when ``PHOTON_SPARSE_GRAD`` is ``auto`` or ``blocked``.
+    bt: Optional["object"] = None
 
     @property
     def num_examples(self) -> int:
@@ -257,6 +263,15 @@ def attach_feature_major(
     (``batch.al_t``) — costs a second layout's host build and device
     memory, so it defaults to the ``PHOTON_SPARSE_MARGIN=pallas`` env
     opt-in.
+
+    A single-block batch (``shards == 1``, no ``geometry_gather``) given
+    ``aligned_dim`` also gets the row-block x feature-block entry tiles of
+    the ``blocked`` kernel (``batch.bt``, ops/block_tiles.py) when
+    ``sparse_grad_select.layouts_wanted`` says that kernel can be selected
+    (and, when it is the only one that can, no aligned layout).  A batch
+    whose grid of blocks outgrows the kernel's tile table goes without the
+    tiles, loudly (``kernels.refused{kernel=blocked}``), and selection goes
+    on among the other kernels.
     """
     if not isinstance(batch, SparseBatch) or batch.ids.ndim != 2:
         raise ValueError("feature-major layout requires a 2-D SparseBatch")
@@ -296,7 +311,10 @@ def attach_feature_major(
             load_or_build_aligned_layout,
         )
 
-        from photon_tpu.ops.sparse_grad_select import xchg_route_wanted
+        from photon_tpu.ops.sparse_grad_select import (
+            layouts_wanted,
+            xchg_route_wanted,
+        )
 
         ids_np = np.asarray(batch.ids)
         vals_np = np.asarray(batch.vals, np.float32)
@@ -325,6 +343,21 @@ def attach_feature_major(
                 want_xchg=want_xchg, order=order,
                 geometry_gather=geometry_gather,
             )
+        want_aligned, want_tiles = layouts_wanted(n * k)
+        if want_tiles:
+            from photon_tpu.ops import block_tiles
+            from photon_tpu.utils.device import record_kernel_refusal
+
+            if block_tiles.block_tile_geometry(n, aligned_dim, n * k) is None:
+                record_kernel_refusal(
+                    "blocked", ValueError(block_tiles.untileable(n, aligned_dim))
+                )
+            else:
+                batch = batch._replace(bt=block_tiles.attach_block_tiles(
+                    ids_np, vals_np, aligned_dim
+                ))
+            if not want_aligned:
+                return batch  # pinned: no other kernel's layout is read
         from photon_tpu.ops.pallas_gather import layout_content_hash
 
         with telemetry.span("layout.cache_key"):
@@ -558,6 +591,10 @@ def batch_astype(batch: Batch, dtype) -> Batch:
         out = out._replace(xchg=dataclasses.replace(
             out.xchg, vals_dest=out.xchg.vals_dest.astype(dtype)
         ))
+    if out.bt is not None:
+        from photon_tpu.ops.block_tiles import round_values
+
+        out = out._replace(bt=round_values(out.bt, dtype))
     return out
 
 
@@ -585,7 +622,7 @@ def pad_batch(batch: Batch, target_n: int) -> Batch:
     # vperm index planes most destructively).  Strip them (padded rows
     # carry only zero-value entries, so an aux rebuilt after padding is
     # equivalent) and let the caller re-attach at the final row count.
-    for aux in ("fm", "al", "al_t", "benes", "xchg"):
+    for aux in ("fm", "al", "al_t", "benes", "xchg", "bt"):
         if getattr(batch, aux, None) is not None:
             batch = batch._replace(**{aux: None})
     return jax.tree.map(_pad, batch)

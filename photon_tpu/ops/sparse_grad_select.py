@@ -1,6 +1,6 @@
 """Runtime selection of the sparse-gradient reduction kernel.
 
-The production gradient has three lowerings auto mode chooses between
+The production gradient has four lowerings auto mode chooses between
 (see ops/KERNEL_NOTES.md):
 
 - **fm** — the pre-sorted segment-sum over the static FeatureMajorAux
@@ -16,7 +16,14 @@ The production gradient has three lowerings auto mode chooses between
   instead of E).  Requires the batch to carry an AlignedLayoutDev
   (``attach_feature_major(..., aligned_dim=d)``); a candidate on TPU
   only (interpret mode on CPU is a test vehicle, orders of magnitude
-  slower).
+  slower);
+- **blocked** — row-block x feature-block entry tiles
+  (ops/block_tiles.block_tiles_product): no XLA gather or scatter in
+  either direction; the tile's window of the vector it reads and of the
+  vector it adds into both sit in VMEM: a lane gather reads, the MXU adds
+  (exact: three bfloat16 terms a float32).  Margins, gradient and Hv all
+  route through it.  Requires the batch to carry BlockTiles (same attach
+  call, single-block batches); a candidate on TPU only, like pallas.
 
 Which wins is a hardware property — so, like the reference's BLAS
 dispatch, the choice is made by a one-time EAGER measurement on the live
@@ -27,7 +34,7 @@ refuses (or that fails parity) is excluded LOUDLY: a WARNING and a
 ``kernels.refused{kernel=…}`` counter in the run report
 (utils/device.record_kernel_refusal), never a quiet switch of path.
 
-Override with ``PHOTON_SPARSE_GRAD=fm|autodiff|pallas|xchg|benes|auto``
+Override with ``PHOTON_SPARSE_GRAD=fm|autodiff|pallas|blocked|xchg|benes|auto``
 (default auto).  ``xchg`` (ops/vperm.py) and ``benes`` (ops/benes.py) are
 explicit opt-ins, never auto candidates: Mosaic on the v5e refuses the
 xchg chunk kernel's wide lane gather ("Not implemented: Multiple source
@@ -37,6 +44,7 @@ interpret mode only; benes was measured slower than every alternative.
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 
@@ -75,7 +83,9 @@ def _bucket(n: int) -> int:
 
 def _probe_problem(e: int, d: int, n: int):
     """A seeded [n, k] padded-COO problem of ~``e`` entries, its per-row
-    vector and the float64 NumPy gradient every kernel must reproduce."""
+    vector and the float64 NumPy gradient every kernel must reproduce; and a
+    coefficient vector with its float64 margins, for a kernel that replaces
+    the forward too."""
     import types
 
     rng = np.random.default_rng(0)
@@ -89,13 +99,16 @@ def _probe_problem(e: int, d: int, n: int):
         ref, ids.reshape(-1),
         (dz[:, None] * vals).reshape(-1).astype(np.float64),
     )
+    w = rng.standard_normal(d).astype(np.float32)
+    ref_xw = (w[ids].astype(np.float64) * vals).sum(axis=1)
     return types.SimpleNamespace(n=n, k=k, d=d, ids=ids, vals=vals, dz=dz,
-                                 ref=ref)
+                                 ref=ref, w=w, ref_xw=ref_xw)
 
 
 def _kernel_fn(name: str, p):
     """``dz -> g[d]`` through kernel ``name`` over the probe problem's
-    static layout (built here, host side)."""
+    static layout (built here, host side).  A kernel that also replaces the
+    margins carries that direction as ``fn.forward`` (``w -> Xw[n]``)."""
     import jax
     import jax.numpy as jnp
 
@@ -115,6 +128,19 @@ def _kernel_fn(name: str, p):
             jnp.take(dz, rows, axis=0) * sorted_vals, sorted_ids,
             num_segments=d, indices_are_sorted=True,
         )
+    if name == "blocked":
+        from photon_tpu.ops import block_tiles
+
+        bt = block_tiles.device_block_tiles(
+            block_tiles.build_block_tiles(p.ids, p.vals, d)
+        )
+
+        # Looked up at call time: tests substitute the kernel.
+        def fn(dz):
+            return block_tiles.block_tiles_product(dz, bt, d, transpose=True)
+
+        fn.forward = lambda w: block_tiles.block_tiles_product(w, bt, p.n)
+        return fn
     from photon_tpu.ops import pallas_gather
 
     layout = pallas_gather.build_aligned_layout(p.ids, p.vals, d)
@@ -135,7 +161,8 @@ def check_kernel(name: str, p) -> tuple:
     """Compile kernel ``name`` on the live backend and compare it with the
     NumPy reference on probe problem ``p``.  Returns ``(fn, status)``:
     ``fn`` is the ``dz -> g`` callable when the kernel compiled and
-    matched (else None), ``status`` one of ``"compiled+parity ok"``,
+    matched (else None; a kernel that replaces the margins too must match
+    in that direction as well), ``status`` one of ``"compiled+parity ok"``,
     ``"refused: <first line of the compiler error>"``, ``"parity failed:
     <max abs err>"``.  A refusal or parity failure is recorded
     (WARNING + ``kernels.refused``) — this is the one place a lowering
@@ -146,20 +173,24 @@ def check_kernel(name: str, p) -> tuple:
 
     try:
         fn = _kernel_fn(name, p)
-        got = np.asarray(fn(jnp.asarray(p.dz)))
+        pairs = [(np.asarray(fn(jnp.asarray(p.dz))), p.ref)]
+        if hasattr(fn, "forward"):
+            pairs.append((np.asarray(fn.forward(jnp.asarray(p.w))), p.ref_xw))
     except Exception as exc:  # noqa: BLE001 — recorded, never discarded
         return None, f"refused: {record_kernel_refusal(name, exc)}"
-    scale = max(float(np.abs(p.ref).max()), 1.0)
-    if not np.allclose(got, p.ref, rtol=2e-4, atol=1e-4 * scale):
-        err = float(np.abs(got - p.ref).max())
-        return None, record_kernel_refusal(
-            name, ValueError(f"parity failed: {err:.3g}")
-        )
+    for got, ref in pairs:
+        scale = max(float(np.abs(ref).max()), 1.0)
+        if not np.allclose(got, ref, rtol=2e-4, atol=1e-4 * scale):
+            err = float(np.abs(got - ref).max())
+            return None, record_kernel_refusal(
+                name, ValueError(f"parity failed: {err:.3g}")
+            )
     return fn, "compiled+parity ok"
 
 
 def kernel_report(e: int, d: int, n: int,
-                  kernels=("autodiff", "fm", "pallas", "xchg")) -> dict:
+                  kernels=("autodiff", "fm", "pallas", "blocked",
+                           "xchg")) -> dict:
     """``{kernel: status}`` of :func:`check_kernel` at one probe problem —
     the per-kernel compile/parity table ``chip_smoke.py`` prints."""
     p = _probe_problem(e, d, n)
@@ -167,7 +198,7 @@ def kernel_report(e: int, d: int, n: int,
 
 
 def _measure(e: int, d: int, n: int, with_pallas: bool,
-             with_fm: bool = True) -> str:
+             with_fm: bool = True, with_blocked: bool = False) -> str:
     import jax
     import jax.numpy as jnp
 
@@ -177,24 +208,38 @@ def _measure(e: int, d: int, n: int, with_pallas: bool,
     # verdict would be sanitized to autodiff by select_kernel.
     names = ["autodiff"] + (["fm"] if with_fm else []) + (
         ["pallas"] if with_pallas else []
-    )
-    dz = jnp.asarray(p.dz)
+    ) + (["blocked"] if with_blocked else [])
+    dz, w = jnp.asarray(p.dz), jnp.asarray(p.w)
+    ids, vals = jnp.asarray(p.ids), jnp.asarray(p.vals)
+
+    def evaluation(u, v, ids, vals, fn):
+        # The margins of a kernel that has no forward of its own are the
+        # row-major gather; its entries arrive as arguments (closed over,
+        # they would be 16 MB of constants in each candidate's program).
+        if hasattr(fn, "forward"):
+            xw = fn.forward(u)
+        else:
+            xw = jnp.sum(jnp.take(u, ids, axis=0) * vals, axis=-1)
+        return jnp.sum(xw) + jnp.sum(fn(v))
+
     timings = {}
     for name in names:
         fn, _ = check_kernel(name, p)
         if fn is None:
             continue
-        # Salt the argument per rep so no call can be served from a
-        # cache, prepare the salt OUTSIDE the timed window, and fetch the
-        # scalar host-side per rep (the sync a host copy cannot fake).
-        fj = jax.jit(lambda v, fn=fn: jnp.sum(fn(v)))
-        float(np.asarray(fj(dz)))  # compile + sync
+        # An evaluation is the margins and the gradient: a candidate is
+        # timed on both, so one that replaces the forward is ranked on all
+        # it changes.  Salt the argument per rep so no call can be served
+        # from a cache, prepare the salt OUTSIDE the timed window, and fetch
+        # the scalar host-side per rep (the sync a host copy cannot fake).
+        fj = jax.jit(functools.partial(evaluation, fn=fn))
+        float(np.asarray(fj(w, dz, ids, vals)))  # compile + sync
         ts = []
         for i in range(3):
             salted = dz + jnp.float32((i + 1) * 1e-12)
             jax.block_until_ready(salted)
             t0 = time.perf_counter()
-            float(np.asarray(fj(salted)))
+            float(np.asarray(fj(w, salted, ids, vals)))
             ts.append(time.perf_counter() - t0)
         timings[name] = float(np.median(ts))
     if not timings:
@@ -220,21 +265,23 @@ def select_kernel(
     has_aligned: bool = False,
     has_benes: bool = False,
     has_xchg: bool = False,
+    has_blocked: bool = False,
 ) -> str:
     """Pick the gradient kernel — ``"fm"``, ``"autodiff"``, ``"pallas"``,
-    ``"benes"``, or ``"xchg"`` — for this problem size on the current
-    backend, restricted to the layouts the batch actually carries."""
+    ``"blocked"``, ``"benes"``, or ``"xchg"`` — for this problem size on the
+    current backend, restricted to the layouts the batch actually carries."""
     from photon_tpu.utils.device import record_kernel_selected
 
     choice = _select(
-        e_total, dim, n_rows, has_fm, has_aligned, has_benes, has_xchg
+        e_total, dim, n_rows, has_fm, has_aligned, has_benes, has_xchg,
+        has_blocked,
     )
     record_kernel_selected(choice)
     return choice
 
 
 def _select(e_total, dim, n_rows, has_fm, has_aligned, has_benes,
-            has_xchg) -> str:
+            has_xchg, has_blocked) -> str:
     mode = os.environ.get("PHOTON_SPARSE_GRAD", "auto")
     if mode == "autodiff":
         return "autodiff"
@@ -244,6 +291,11 @@ def _select(e_total, dim, n_rows, has_fm, has_aligned, has_benes,
         # Forced pallas runs in interpret mode off-TPU (tests / parity
         # checks); it still needs the aligned layout on the batch.
         return "pallas" if has_aligned else ("fm" if has_fm else "autodiff")
+    if mode == "blocked":
+        # Same terms as forced pallas: needs the batch's entry tiles.
+        return "blocked" if has_blocked else (
+            "pallas" if has_aligned else ("fm" if has_fm else "autodiff")
+        )
     if mode == "xchg":
         # Explicit opt-in only: the chunk kernel does not lower on the v5e
         # (module docstring), so on a TPU this mode fails at compile —
@@ -265,11 +317,12 @@ def _select(e_total, dim, n_rows, has_fm, has_aligned, has_benes,
         return "autodiff"
 
     with_pallas = has_aligned and _pallas_eligible()
-    if not (has_fm or with_pallas):
+    with_blocked = has_blocked and _pallas_eligible()
+    if not (has_fm or with_pallas or with_blocked):
         return "autodiff"  # single-candidate set: nothing to measure
     key = (
         jax.default_backend(), _bucket(e_total), _bucket(dim),
-        with_pallas, bool(has_fm),
+        with_pallas, bool(has_fm), with_blocked,
     )
     if key not in _CACHE:
         scale = max(1, -(-e_total // _probe_cap()))  # ceil: cap probe size
@@ -288,18 +341,21 @@ def _select(e_total, dim, n_rows, has_fm, has_aligned, has_benes,
         # outright raises: there is no default kernel to fall back to.
         from photon_tpu import telemetry
 
-        candidates = 1 + int(bool(has_fm)) + int(with_pallas)
+        candidates = (
+            1 + int(bool(has_fm)) + int(with_pallas) + int(with_blocked)
+        )
         with telemetry.span("kernels.probe", candidates=candidates, size=e), \
                 jax.core.eval_context():
             _CACHE[key] = _measure(
-                e, dim, n, with_pallas, with_fm=bool(has_fm)
+                e, dim, n, with_pallas, with_fm=bool(has_fm),
+                with_blocked=with_blocked,
             )
         import logging
 
         # Logged because auto-selection is a wall-clock measurement: on a
         # machine near the kernel crossover two runs can pick different
         # kernels, whose different reduction orders give slightly different
-        # float results.  Pin PHOTON_SPARSE_GRAD=fm|autodiff|pallas for
+        # float results.  Pin PHOTON_SPARSE_GRAD=fm|autodiff|pallas|blocked for
         # bitwise same-seed reproducibility (SURVEY.md §5 determinism note).
         logging.getLogger("photon_tpu.sparse_grad").info(
             "sparse-grad kernel for backend=%s e~2^%d d~2^%d: %s",
@@ -313,22 +369,27 @@ def _select(e_total, dim, n_rows, has_fm, has_aligned, has_benes,
     return choice
 
 
-def aligned_layout_wanted(e_total: int | None = None) -> bool:
-    """Should batch builders pay the host-side aligned-layout construction?
-    True when a kernel that reads it is forced, or the pallas kernel could
-    win auto-selection on this backend (a TPU).  Builders call this so CPU
-    runs never pay the bin-packing cost for a kernel auto mode will not
-    pick.  Pass the entry count when known: below the probe floor auto
-    mode is guaranteed to run autodiff, so the build would be pure wasted
-    host time."""
+def layouts_wanted(e_total: int | None = None) -> tuple[bool, bool]:
+    """``(aligned, block_tiles)``: which static layouts a batch builder
+    should pay the host-side construction of, besides the feature-major aux.
+    A layout is wanted when a kernel that reads it is forced, or could win
+    auto-selection on this backend (compiled Mosaic: a TPU), so CPU runs
+    never pay for a kernel auto mode will not pick.  Pass the entry count
+    when known: below the probe floor auto mode is guaranteed to run
+    autodiff, so a build would be pure wasted host time."""
     mode = os.environ.get("PHOTON_SPARSE_GRAD", "auto")
-    if mode in ("pallas", "benes", "xchg"):
-        return True
     if mode != "auto":
-        return False
+        return mode in ("pallas", "benes", "xchg"), mode == "blocked"
     if e_total is not None and e_total < _probe_floor():
-        return False
-    return _pallas_eligible()
+        return False, False
+    return _pallas_eligible(), _pallas_eligible()
+
+
+def aligned_layout_wanted(e_total: int | None = None) -> bool:
+    """Should a batch builder hand ``attach_feature_major`` the coefficient
+    dimension (``aligned_dim``)?  When :func:`layouts_wanted` wants any
+    layout; the attach builds the ones it names."""
+    return any(layouts_wanted(e_total))
 
 
 def xchg_route_wanted() -> bool:

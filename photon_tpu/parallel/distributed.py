@@ -219,11 +219,11 @@ class DistributedGlmObjective:
     def _differentiable_grad(self, w: Array, batch: Batch) -> Array:
         """Gradient via a kernel jvp can differentiate THROUGH (the
         normalized-Hv path re-differentiates the gradient, and
-        ``pallas_call`` has no JVP rule): pallas/xchg route to the fm
+        ``pallas_call`` has no JVP rule): pallas/xchg/blocked route to the fm
         layout — always built alongside the aligned one — mirroring
         GlmObjective._differentiable_grad."""
         kernel = self._sparse_kernel(w, batch)
-        if kernel in ("pallas", "xchg", "benes"):
+        if kernel in ("pallas", "xchg", "benes", "blocked"):
             kernel = "fm" if batch.fm is not None else None
         if kernel is None:
             return jax.grad(self.value)(w, batch)

@@ -86,11 +86,14 @@ def shard_batch(
     padded = pad_batch(batch, target)
     if isinstance(padded, SparseBatch) and (
         padded.al is not None or padded.al_t is not None
+        or padded.bt is not None
     ):
         # Any pre-attached single-block aligned layouts cannot be
         # row-sharded; strip and (when aligned_dim says to) rebuild them
         # per shard below.
-        padded = padded._replace(al=None, al_t=None, xchg=None, benes=None)
+        padded = padded._replace(
+            al=None, al_t=None, xchg=None, benes=None, bt=None
+        )
     if build_fm and isinstance(padded, SparseBatch) and padded.ids.ndim == 2:
         if aligned_dim is not None:
             from photon_tpu.ops.sparse_grad_select import aligned_layout_wanted
