@@ -60,7 +60,6 @@ def _build_batch(n: int, k: int, d: int, seed: int = 0):
     margin = (w_true[ids] * vals).sum(axis=1)
     label = (rng.random(n) < 1.0 / (1.0 + np.exp(-margin))).astype(np.float32)
     from photon_tpu.data.batch import attach_feature_major
-    from photon_tpu.ops.sparse_grad_select import aligned_layout_wanted
 
     return attach_feature_major(SparseBatch(
         ids=jnp.asarray(ids),
@@ -68,7 +67,7 @@ def _build_batch(n: int, k: int, d: int, seed: int = 0):
         label=jnp.asarray(label),
         offset=jnp.zeros(n, jnp.float32),
         weight=jnp.ones(n, jnp.float32),
-    ), aligned_dim=d if aligned_layout_wanted(n * k) else None)
+    ), aligned_dim=d)
 
 
 # Peak HBM bandwidth by ``device_kind`` (GB/s).  An unknown device is an
@@ -3511,19 +3510,13 @@ def main() -> None:
     if kernel == "auto":
         from photon_tpu.ops.sparse_grad_select import select_kernel
 
-        kernel = "auto:" + select_kernel(
-            nnz, d, n, has_fm=batch.fm is not None,
-            has_aligned=batch.al is not None,
-            has_xchg=batch.xchg is not None,
-        )
+        kernel = "auto:" + select_kernel(batch, d)
     _emit("glm_grad_steps_per_sec", steps_per_sec, "steps/s", {
         "rows": n,
         "nnz_per_row": k,
         "dim": d,
         "dtype": bench_dtype,
         "kernel": kernel,
-        **({"xchg_reduce": os.environ.get("PHOTON_XCHG_REDUCE", "aligned")}
-           if "xchg" in kernel else {}),
         "dispatch": "fused" if fused else "per-step",
         "skew": os.environ.get("PHOTON_BENCH_SKEW", "uniform"),
         "platform": platform,

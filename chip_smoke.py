@@ -80,9 +80,6 @@ if REHEARSAL:
     KERNEL_TABLE_ROWS = 1 << 9
 GAME_SPEC = "synthetic-game:" + ":".join(map(str, GAME_DIMS))
 
-# Kernels auto mode may pick: each must compile and match on this device.
-# xchg is an explicit opt-in whose status the table reports (ops/vperm.py).
-AUTO_KERNELS = ("autodiff", "fm", "pallas", "blocked")
 TIME_LIMIT_S = 1150  # the contract allows 1200, compilation included
 
 
@@ -433,9 +430,10 @@ def leg_sparse(work_dir, device):
     for kernel, status in table.items():
         say(f"  kernel {kernel}: {status}")
     if not REHEARSAL:
-        bad = {k: s for k, s in table.items()
-               if k in AUTO_KERNELS and s != "compiled+parity ok"}
-        check(not bad, f"auto-candidate kernel(s) failed on the chip: {bad}")
+        # Every kernel is an auto candidate: each must compile and match
+        # on this device.
+        bad = {k: s for k, s in table.items() if s != "compiled+parity ok"}
+        check(not bad, f"kernel(s) failed on the chip: {bad}")
 
 
 # -- main -------------------------------------------------------------------

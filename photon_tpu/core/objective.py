@@ -28,7 +28,19 @@ from jax import tree_util
 
 from photon_tpu.core.losses import PointwiseLoss, get_loss
 from photon_tpu.core.normalization import NormalizationContext
-from photon_tpu.data.batch import Batch, DenseBatch, FeatureMajorAux, SparseBatch, margins
+from photon_tpu.data.batch import (
+    LAYOUT_FIELDS,
+    Batch,
+    DenseBatch,
+    FeatureMajorAux,
+    SparseBatch,
+    margins,
+)
+from photon_tpu.ops.sparse_grad_select import (
+    differentiable,
+    has_own_forward,
+    select_kernel,
+)
 
 Array = jax.Array
 
@@ -161,35 +173,26 @@ class GlmObjective:
 
     def _xu_product(self, kernel: str, u: Array, batch: Batch) -> Array:
         """Per-row ``X u`` products (no offset) through the selected
-        kernel's forward: the pallas path uses the TRANSPOSED aligned
-        layout when the batch carries one (``sum_e u[f_e] v_e`` per row via
-        the same position-reduce kernel — KERNEL_NOTES.md option (a)); the
-        benes path runs the slab gather + static Clos permutation
-        (ops/benes.py — no random E-access); everything else takes the
-        row-major XLA gather; the blocked path gathers inside VMEM over the
-        batch's entry tiles (ops/block_tiles.py).  The single dispatch point
-        for margins AND Hv's ``X v``."""
+        kernel's forward: the blocked path gathers inside VMEM over the
+        batch's entry tiles (ops/block_tiles.py); the pallas path uses the
+        TRANSPOSED aligned layout when the batch carries one
+        (``sum_e u[f_e] v_e`` per row via the same position-reduce kernel —
+        KERNEL_NOTES.md option (a)); everything else takes the row-major
+        XLA gather.  The single dispatch point for margins AND Hv's
+        ``X v``."""
+        if not has_own_forward(kernel, batch):
+            return jnp.sum(jnp.take(u, batch.ids, axis=0) * batch.vals, axis=-1)
         if kernel == "blocked":
             from photon_tpu.ops.block_tiles import block_tiles_product
 
             with jax.named_scope("blocked/xw"):
                 return block_tiles_product(u, batch.bt, batch.ids.shape[0])
-        if kernel == "benes":
-            from photon_tpu.ops.benes import benes_xu_product
+        from photon_tpu.ops.pallas_gather import aligned_segment_grad
 
-            n, k = batch.ids.shape
-            return benes_xu_product(u, batch.al, batch.benes, n, k)
-        if kernel in ("pallas", "xchg") and batch.al_t is not None:
-            from photon_tpu.ops.pallas_gather import aligned_segment_grad
-
-            return aligned_segment_grad(u, batch.al_t, batch.ids.shape[0])
-        return jnp.sum(jnp.take(u, batch.ids, axis=0) * batch.vals, axis=-1)
+        return aligned_segment_grad(u, batch.al_t, batch.ids.shape[0])
 
     def _margins_for_kernel(self, kernel: str, w: Array, batch: Batch) -> Array:
-        fwd_kernel = kernel in ("benes", "blocked") or (
-            kernel in ("pallas", "xchg") and batch.al_t is not None
-        )
-        if not fwd_kernel:
+        if not has_own_forward(kernel, batch):
             # Single home of the normalization algebra for the XLA forward.
             return self._margins(w, batch)
         if self.normalization is None:
@@ -223,46 +226,19 @@ class GlmObjective:
         return v
 
     # -- static-sparsity fast path --------------------------------------------
-    def _sparse_kernel(self, batch: Batch, dim: Optional[int] = None) -> Optional[str]:
+    def _sparse_kernel(self, batch: Batch, dim: int) -> Optional[str]:
         """Which static-layout gradient kernel applies to this batch:
         ``"fm"`` (pre-sorted segment sum over FeatureMajorAux), ``"pallas"``
         (slab-aligned Mosaic reduce over AlignedLayoutDev), ``"blocked"``
         (both directions in VMEM over BlockTiles), or ``None``
         (autodiff — the unsorted scatter XLA lowers is faster on some
-        platforms).  When the coefficient dim is known, the choice is the
-        measured-on-this-backend selection (ops/sparse_grad_select.py)."""
+        platforms): the measured-on-this-backend selection
+        (ops/sparse_grad_select.py) among the layouts the batch carries."""
         if not (isinstance(batch, SparseBatch) and batch.ids.ndim == 2):
             return None
-        has_fm = batch.fm is not None
-        has_al = batch.al is not None
-        has_benes = batch.benes is not None and has_al
-        # The cumsum-reduce xchg variant (bounds set) never touches the
-        # aligned layout at runtime, so a batch can carry the route alone
-        # — the streaming layout cache relies on this (no layout bytes
-        # cached or shipped per chunk).  The aligned-reduce variant still
-        # requires ``al``.
-        has_xchg = batch.xchg is not None and (
-            has_al or getattr(batch.xchg, "bounds", None) is not None
-        )
-        has_blocked = batch.bt is not None
-        if not (has_fm or has_al or has_xchg or has_blocked):
+        if all(getattr(batch, field) is None for field in LAYOUT_FIELDS):
             return None
-        if dim is None:
-            if has_fm:
-                return "fm"
-            if has_al:
-                return "pallas"
-            if has_xchg:
-                return "xchg"  # bounds-only route (streamed cumsum chunks)
-            return "blocked"
-        from photon_tpu.ops.sparse_grad_select import select_kernel
-
-        n, k = batch.ids.shape
-        choice = select_kernel(
-            n * k, dim, n,
-            has_fm=has_fm, has_aligned=has_al, has_benes=has_benes,
-            has_xchg=has_xchg, has_blocked=has_blocked,
-        )
+        choice = select_kernel(batch, dim)
         return None if choice == "autodiff" else choice
 
     def _segment_grad(self, kernel: str, per_row: Array, batch: Batch, dim: int) -> Array:
@@ -275,18 +251,6 @@ class GlmObjective:
                 return block_tiles_product(
                     per_row, batch.bt, dim, transpose=True
                 )
-        if kernel == "xchg":
-            from photon_tpu.ops.vperm import xchg_segment_grad
-
-            return xchg_segment_grad(
-                per_row, batch.vals, batch.al, batch.xchg, dim
-            )
-        if kernel == "benes":
-            from photon_tpu.ops.benes import benes_segment_grad
-
-            return benes_segment_grad(
-                per_row, batch.vals, batch.al, batch.benes, dim
-            )
         if kernel == "pallas":
             from photon_tpu.ops.pallas_gather import aligned_segment_grad
 
@@ -355,14 +319,12 @@ class GlmObjective:
 
     def _differentiable_grad(self, w: Array, batch: Batch) -> Array:
         """Gradient via a kernel jax.jvp can differentiate THROUGH: the
-        pallas kernel has no JVP rule (``pallas_call`` is not
+        pallas and blocked kernels have no JVP rule (``pallas_call`` is not
         differentiable), so callers that re-differentiate the gradient
         (normalized Hv below) route it to the fm layout — always built
-        alongside the aligned one — or plain autodiff.  The benes
-        and blocked paths contain a pallas_call too and route
-        identically."""
+        alongside theirs — or plain autodiff."""
         kernel = self._sparse_kernel(batch, int(w.shape[0]))
-        if kernel in ("pallas", "benes", "xchg", "blocked"):
+        if kernel is not None and not differentiable(kernel):
             kernel = "fm" if batch.fm is not None else None
         if kernel is not None:
             _, g = self._fast_data_value_and_grad(w, batch, kernel)

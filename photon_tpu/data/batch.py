@@ -109,18 +109,6 @@ class SparseBatch(NamedTuple):
     # the Pallas FORWARD (margins) direction; attach with
     # ``attach_feature_major(..., aligned_dim=d, aligned_forward=True)``.
     al_t: Optional["object"] = None
-    # Optional static Clos routing (ops/benes.BenesAux) for the `benes`
-    # kernel — value/grad/Hv with no random E-element access; built by
-    # ``attach_feature_major(..., aligned_dim=d)`` when
-    # ``PHOTON_SPARSE_GRAD=benes``.  Requires ``al``.
-    benes: Optional["object"] = None
-    # Optional vperm routing (ops/vperm.VpermRoute) for the `xchg` kernel:
-    # row-major products ride a 3-pass static permutation into aligned
-    # slot order instead of the per-step E-element XLA gather.  Built by
-    # ``attach_feature_major(..., aligned_dim=d)`` when
-    # ``PHOTON_SPARSE_GRAD`` is ``xchg`` or ``auto``.  Requires ``al``
-    # (and uses ``al_t`` for margins when present).
-    xchg: Optional["object"] = None
     # Optional row-block x feature-block entry tiles
     # (ops/block_tiles.BlockTiles) for the `blocked` kernel: margins,
     # gradient and Hv with both random accesses inside VMEM.  Built by
@@ -132,6 +120,11 @@ class SparseBatch(NamedTuple):
     def num_examples(self) -> int:
         return self.ids.shape[0]
 
+
+# The optional static layouts a SparseBatch can carry, named once: what
+# pad_batch strips, a sharded placement squeezes and a multi-process
+# assembly rebuilds.  ops/sparse_grad_select says which kernel reads which.
+LAYOUT_FIELDS = ("fm", "al", "al_t", "bt")
 
 Batch = Union[DenseBatch, SparseBatch]
 
@@ -253,10 +246,15 @@ def attach_feature_major(
     the batch on that axis hands each device exactly its block's layout
     (VERDICT r5 item 2 — the fast kernels must run under the sharded
     objective; squeeze + dispatch happen in parallel/distributed.py).
-    The same applies to the xchg exchange routes: every shard's route is
-    built with the SHARED balanced-block geometry (max census across
-    shards) or all shards fall back to the colored route together, so
-    the stacked route pytree has one uniform treedef.
+
+    Callers pass the dimension unconditionally: which of those layouts are
+    built is decided HERE, by ``sparse_grad_select.layouts_wanted`` on the
+    batch's entry count (a kernel that reads the layout is pinned, or could
+    win auto-selection on this backend), so CPU runs never pay for layouts
+    the selector cannot route to.  The exception is a ``geometry_gather``
+    caller (a multi-process assembly): every process must take the same
+    branch around the gather's collectives, so that caller decides on
+    globally agreed inputs and passes ``aligned_dim`` or None.
 
     ``aligned_forward`` additionally builds the transposed (row-dictionary)
     layout so the Pallas path computes MARGINS through the same kernel
@@ -305,94 +303,66 @@ def attach_feature_major(
             "only serves the pallas kernel, which needs the aligned "
             "gradient layout too)"
         )
-    if aligned_dim is not None:
-        from photon_tpu.ops.pallas_gather import (
-            device_layout,
-            load_or_build_aligned_layout,
-        )
+    if aligned_dim is None:
+        return batch
+    from photon_tpu.ops.pallas_gather import (
+        device_layout,
+        layout_content_hash,
+        load_or_build_aligned_layout,
+    )
+    from photon_tpu.ops.sparse_grad_select import (
+        aligned_layout_wanted,
+        layouts_wanted,
+    )
 
-        from photon_tpu.ops.sparse_grad_select import (
-            layouts_wanted,
-            xchg_route_wanted,
+    ids_np = np.asarray(batch.ids)
+    vals_np = np.asarray(batch.vals, np.float32)
+    if aligned_forward is None:
+        aligned_forward = (
+            os.environ.get("PHOTON_SPARSE_MARGIN", "xla") == "pallas"
         )
+    if shards != 1 or geometry_gather is not None:
+        # A geometry gather forces the STACKED form even for one local
+        # shard: a multi-process assembly needs every process's aux to
+        # carry the leading shard axis (and to agree on the
+        # globally-gathered geometry) so the per-process arrays
+        # concatenate into one global sharded pytree.  Tiles are
+        # single-block only: a sharded batch gets the aligned layouts
+        # whenever any layout is wanted (a ``blocked`` pin then runs the
+        # nearest kernel the batch carries).
+        if geometry_gather is None and not aligned_layout_wanted(n * k):
+            return batch
+        return _attach_aligned_sharded(
+            batch, ids_np, vals_np, aligned_dim, shards,
+            bool(aligned_forward), geometry_gather,
+        )
+    want_aligned, want_tiles = layouts_wanted(n * k)
+    if want_tiles:
+        from photon_tpu.ops import block_tiles
+        from photon_tpu.utils.device import record_kernel_refusal
 
-        ids_np = np.asarray(batch.ids)
-        vals_np = np.asarray(batch.vals, np.float32)
-        want_xchg = xchg_route_wanted()
-        if aligned_forward is None:
-            # xchg implies the pallas forward: its whole point is deleting
-            # the E-element gathers, and XLA margins would reintroduce one.
-            aligned_forward = want_xchg or (
-                os.environ.get("PHOTON_SPARSE_MARGIN", "xla") == "pallas"
+        if block_tiles.block_tile_geometry(n, aligned_dim, n * k) is None:
+            record_kernel_refusal(
+                "blocked", ValueError(block_tiles.untileable(n, aligned_dim))
             )
-        if shards != 1 or geometry_gather is not None:
-            # A geometry gather forces the STACKED form even for one
-            # local shard: a multi-process assembly needs every process's
-            # aux to carry the leading shard axis (and to agree on the
-            # globally-gathered geometry) so the per-process arrays
-            # concatenate into one global sharded pytree.
-            if os.environ.get("PHOTON_SPARSE_GRAD", "auto") == "benes":
-                # Before the expensive per-shard build: rejecting after it
-                # would waste the costliest host work in the package.
-                raise ValueError(
-                    "the benes research kernel is single-shard only"
-                )
-            return _attach_aligned_sharded(
-                batch, ids_np, vals_np, aligned_dim, shards,
-                aligned_forward=bool(aligned_forward),
-                want_xchg=want_xchg, order=order,
-                geometry_gather=geometry_gather,
-            )
-        want_aligned, want_tiles = layouts_wanted(n * k)
-        if want_tiles:
-            from photon_tpu.ops import block_tiles
-            from photon_tpu.utils.device import record_kernel_refusal
-
-            if block_tiles.block_tile_geometry(n, aligned_dim, n * k) is None:
-                record_kernel_refusal(
-                    "blocked", ValueError(block_tiles.untileable(n, aligned_dim))
-                )
-            else:
-                batch = batch._replace(bt=block_tiles.attach_block_tiles(
-                    ids_np, vals_np, aligned_dim
-                ))
-            if not want_aligned:
-                return batch  # pinned: no other kernel's layout is read
-        from photon_tpu.ops.pallas_gather import layout_content_hash
-
-        with telemetry.span("layout.cache_key"):
-            base_hash = layout_content_hash(ids_np, vals_np)
-        layout = load_or_build_aligned_layout(
-            ids_np, vals_np, aligned_dim, base_hash=base_hash
-        )
-        batch = batch._replace(al=device_layout(layout))
-        if aligned_forward:
-            batch = batch._replace(al_t=device_layout(
-                load_or_build_aligned_layout(
-                    ids_np, vals_np, aligned_dim, transposed=True,
-                    base_hash=base_hash,
-                )
+        else:
+            batch = batch._replace(bt=block_tiles.attach_block_tiles(
+                ids_np, vals_np, aligned_dim
             ))
-        if want_xchg:
-            from photon_tpu.ops.vperm import build_xchg_aux
-
-            # shards == 1 here, so order[0] is the flat-stream stable
-            # argsort the fm aux already paid for.
-            batch = batch._replace(
-                xchg=build_xchg_aux(
-                    layout, ids_np, aligned_dim, order=order[0],
-                    vals=vals_np,
-                )
+    if not want_aligned:
+        return batch
+    with telemetry.span("layout.cache_key"):
+        base_hash = layout_content_hash(ids_np, vals_np)
+    batch = batch._replace(al=device_layout(load_or_build_aligned_layout(
+        ids_np, vals_np, aligned_dim, base_hash=base_hash
+    )))
+    if aligned_forward:
+        batch = batch._replace(al_t=device_layout(
+            load_or_build_aligned_layout(
+                ids_np, vals_np, aligned_dim, transposed=True,
+                base_hash=base_hash,
             )
-        if os.environ.get("PHOTON_SPARSE_GRAD", "auto") == "benes":
-            # Explicit opt-in only: the routing (host edge-coloring) is the
-            # most expensive layout build in the package; auto mode never
-            # pays it speculatively.
-            from photon_tpu.ops.benes import build_benes_aux
-
-            batch = batch._replace(
-                benes=build_benes_aux(layout, n, k)
-            )
+        ))
     return batch
 
 
@@ -403,38 +373,26 @@ def _attach_aligned_sharded(
     aligned_dim: int,
     shards: int,
     aligned_forward: bool,
-    want_xchg: bool,
-    order: np.ndarray,
     geometry_gather=None,
 ) -> SparseBatch:
-    """Per-shard aligned layouts (+ optional transposed layouts and xchg
-    routes), padded to common geometry and stacked on a leading shard
-    axis (VERDICT r5 item 2).
-
-    Every shard's arrays must stack into ONE pytree with ONE treedef, so:
-
-    - aligned layouts pad to the max (slabs, tiles) across shards
-      (ops/pallas_gather.stack_device_layouts);
-    - xchg balanced routes are built with the SHARED max block census
-      (``blk_override``), or — when any shard's data defeats the
-      balanced form — every shard takes the colored route together
-      (``force_colored``); route meta is asserted uniform before
-      stacking, and on any mismatch the xchg aux is dropped (the batch
-      still carries fm + aligned, so training routes to the next-best
-      kernel instead of failing).
+    """Per-shard aligned layouts (+ optional transposed layouts), padded to
+    the max (slabs, tiles) across shards
+    (ops/pallas_gather.stack_device_layouts) and stacked on a leading shard
+    axis (VERDICT r5 item 2), so every shard's arrays are ONE pytree with
+    ONE treedef.
 
     ``geometry_gather(local [S, 4] int64) -> global [S_total, 4]``
     widens the geometry agreement beyond this call's shards — the
     multi-process assembly (data/streaming.make_global_batch) passes a
     process-allgather so every process pads to ONE global geometry and
     the per-process stacked leaves concatenate into one sharded global
-    array.  Columns: (n_slabs, n_tiles, al_t n_slabs, al_t n_tiles) for
-    the layout phase; (census|-1, 0, 0, 0) for the route phase.
+    array.  Columns: (n_slabs, n_tiles, al_t n_slabs, al_t n_tiles).
     Default: identity (single-process attach).
     """
-    import logging
-
+    from photon_tpu import telemetry
     from photon_tpu.ops.pallas_gather import (
+        common_layout_geometry_arr,
+        layout_content_hash,
         load_or_build_aligned_layout,
         pad_aligned_layout,
         stack_device_layouts,
@@ -446,10 +404,6 @@ def _attach_aligned_sharded(
     ns = n // shards
     ids_blocks = ids_np.reshape(shards, ns, k)
     vals_blocks = vals_np.reshape(shards, ns, k)
-    from photon_tpu.ops.pallas_gather import layout_content_hash
-
-    from photon_tpu import telemetry
-
     with telemetry.span("layout.cache_key"):
         base_hashes = [
             layout_content_hash(ids_blocks[s], vals_blocks[s])
@@ -480,77 +434,17 @@ def _attach_aligned_sharded(
         ]
         for s in range(shards)
     ], np.int64)
-    from photon_tpu.ops.pallas_gather import common_layout_geometry_arr
-
     geo = np.asarray(geometry_gather(geo_local), np.int64)
     s_tgt, t_tgt = common_layout_geometry_arr(geo[:, :2])
-    # Pad FIRST, then build routes against the padded layouts: the
-    # aligned-mode exchange's destination is the slot stream, whose
-    # length must be uniform across shards for the routes to stack.
-    layouts = [pad_aligned_layout(l, s_tgt, t_tgt) for l in layouts]
-    batch = batch._replace(al=stack_device_layouts(layouts))
+    batch = batch._replace(al=stack_device_layouts(
+        [pad_aligned_layout(l, s_tgt, t_tgt) for l in layouts]
+    ))
     if aligned_forward:
         st, tt = common_layout_geometry_arr(geo[:, 2:])
         batch = batch._replace(al_t=stack_device_layouts(
             [pad_aligned_layout(l, st, tt) for l in layouts_t]
         ))
-    if not want_xchg:
-        return batch
-    import jax
-    import os
-
-    from photon_tpu.ops.vperm import balanced_blk_census, build_xchg_aux
-
-    mode = os.environ.get("PHOTON_XCHG_REDUCE", "aligned")
-    e_s = ns * k
-    censuses = []
-    for s in range(shards):
-        if mode == "cumsum":
-            dest_src = order[s]
-        else:
-            dest_src = layouts[s].src.reshape(-1)
-        censuses.append(balanced_blk_census(dest_src, e_s, k))
-    census_local = np.asarray([
-        [-1 if c is None else c, 0, 0, 0] for c in censuses
-    ], np.int64)
-    census_all = np.asarray(geometry_gather(census_local), np.int64)[:, 0]
-    force_colored = bool((census_all < 0).any())
-    blk_override = None if force_colored else int(census_all.max())
-    auxes = [
-        build_xchg_aux(
-            layouts[s], ids_blocks[s], aligned_dim, order=order[s],
-            vals=vals_blocks[s], blk_override=blk_override,
-            force_colored=force_colored,
-        )
-        for s in range(shards)
-    ]
-    defs = {jax.tree.structure(a) for a in auxes}
-    # Route KIND (2=balanced, 1=colored — _aux_to_npz codes) must match
-    # across ALL shards globally, and the drop decision must be agreed
-    # globally too: one process keeping the aux while another drops it
-    # would give the hosts different program pytrees (hang, not
-    # fallback).  Same gather as the geometry negotiation.
-    from photon_tpu.ops.vperm import BalancedRoute
-
-    kind = 2 if isinstance(auxes[0].route, BalancedRoute) else 1
-    verdict_local = np.asarray(
-        [[1 if len(defs) != 1 else 0, kind, 0, 0]], np.int64
-    )
-    verdict = np.asarray(geometry_gather(verdict_local), np.int64)
-    drop = bool(verdict[:, 0].any()) or len(set(
-        verdict[:, 1].tolist()
-    )) != 1
-    if drop:
-        logging.getLogger("photon_tpu.batch").warning(
-            "per-shard xchg routes came out with mismatched geometry "
-            "(locally %d distinct treedefs; global kinds %s); dropping "
-            "the xchg aux everywhere — training will route to the "
-            "pallas/fm kernels instead",
-            len(defs), sorted(set(verdict[:, 1].tolist())),
-        )
-        return batch
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *auxes)
-    return batch._replace(xchg=stacked)
+    return batch
 
 
 def batch_astype(batch: Batch, dtype) -> Batch:
@@ -577,20 +471,6 @@ def batch_astype(batch: Batch, dtype) -> Batch:
             out = out._replace(**{
                 aux: dataclasses.replace(lay, vals=lay.vals.astype(dtype))
             })
-    if out.xchg is not None and getattr(out.xchg, "vals_dest", None) is not None:
-        # The baked destination stream was permuted from the
-        # PRE-conversion values; left untouched, gradients would read
-        # different values than the margins (the objective and its
-        # gradient must see ONE value stream).  Elementwise casts
-        # commute with the static permutation (pads stay zero), so
-        # converting the baked stream in place keeps it exactly equal
-        # to permute(converted vals) — preserving the fused dz-expansion
-        # fast path, working directly on stacked sharded arrays, and
-        # keeping vals_fp valid (its guard's loose rtol exists for this
-        # conversion).
-        out = out._replace(xchg=dataclasses.replace(
-            out.xchg, vals_dest=out.xchg.vals_dest.astype(dtype)
-        ))
     if out.bt is not None:
         from photon_tpu.ops.block_tiles import round_values
 
@@ -617,12 +497,10 @@ def pad_batch(batch: Batch, target_n: int) -> Batch:
         # compiles nothing — the point of the capacity headroom.
         return np.pad(np.asarray(a), widths)
 
-    # The feature-major / aligned / routing auxes are row-count- and
-    # block-structure-dependent; padding per-leaf would corrupt them (the
-    # vperm index planes most destructively).  Strip them (padded rows
-    # carry only zero-value entries, so an aux rebuilt after padding is
+    # The static layouts are row-count- and block-structure-dependent;
+    # padding per-leaf would corrupt them.  Strip them (padded rows carry
+    # only zero-value entries, so a layout rebuilt after padding is
     # equivalent) and let the caller re-attach at the final row count.
-    for aux in ("fm", "al", "al_t", "benes", "xchg", "bt"):
-        if getattr(batch, aux, None) is not None:
-            batch = batch._replace(**{aux: None})
+    if isinstance(batch, SparseBatch):
+        batch = batch._replace(**dict.fromkeys(LAYOUT_FIELDS))
     return jax.tree.map(_pad, batch)

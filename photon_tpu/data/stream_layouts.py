@@ -2,37 +2,25 @@
 
 The streaming tier (data/streaming.py tier 3) re-parses the same part
 files on every objective evaluation, so until now it could only run the
-row-major autodiff kernel: the aligned/xchg layouts cost orders of
-magnitude more host time than a chunk parse, and rebuilding them per
-pass is economically impossible.  But a chunk's layout and exchange
-route are pure functions of its FILE — identical on every pass — so
-they can be built once, persisted beside the route cache, and
-re-attached to each freshly parsed chunk at stat+load cost:
+row-major autodiff kernel: the aligned layout costs orders of magnitude
+more host time than a chunk parse, and rebuilding it per pass is
+economically impossible.  But a chunk's layout is a pure function of its
+FILE — identical on every pass — so it can be built once, persisted
+under the cache root, and re-attached to each freshly parsed chunk at
+stat+load cost:
 
 - **Cache key = file identity (abspath, size, mtime) + parse params**,
   not content: the hit path per pass is one ``stat`` and one ``npz``
   load — no per-pass hashing of multi-MB id streams.
 - **Pow2-bucketed geometry**: per-file natural geometry (aligned
-  slabs/tiles, balanced block census) is padded UP to powers of two, so
-  equal-shaped chunks (every full part file of a dataset) share one
-  stacked treedef and therefore ONE jitted per-chunk program — without
-  any global pre-pass over all files.
-- **No value baking**: a streamed chunk is evaluated once per pass, so
-  pre-permuting the value stream (``vals_dest``) would cost one extra
-  exchange per evaluation instead of amortizing; the route moves the
-  materialized product stream instead.
+  slabs/tiles) is padded UP to powers of two, so equal-shaped chunks
+  (every full part file of a dataset) share one stacked treedef and
+  therefore ONE jitted per-chunk program — without any global pre-pass
+  over all files.
 
-Amortization math (KERNEL_NOTES.md round-5 streaming section): the
-route build is tens of host-seconds per production-size file, paid ONCE
-per dataset; an L-BFGS fit re-streams every file ~50-150 times (one
-pass per value+gradient evaluation), so the build amortizes to well
-under a second per pass while deleting the per-pass E-element gather
-the xchg kernel exists to delete.
-
-Select with ``PHOTON_STREAM_KERNEL=autodiff|fm|pallas|xchg`` (default
-``autodiff`` — the right default while streamed passes are
-host-parse-bound).  ``xchg`` honors ``PHOTON_XCHG_REDUCE`` like the
-resident path (and, like it, does not lower on the v5e — ops/vperm.py).
+Select with ``PHOTON_STREAM_KERNEL=autodiff|fm|pallas`` (default: a
+pinned ``PHOTON_SPARSE_GRAD`` of ``fm`` or ``pallas``, else ``autodiff`` —
+the right default while streamed passes are host-parse-bound).
 """
 
 from __future__ import annotations
@@ -44,14 +32,12 @@ from typing import Optional
 
 import numpy as np
 
-import jax.numpy as jnp
-
 from photon_tpu.data.batch import SparseBatch
 
 _VERSION = 1
 _LOG = logging.getLogger("photon_tpu.stream_layouts")
 
-_KERNELS = ("autodiff", "fm", "pallas", "xchg")
+_KERNELS = ("autodiff", "fm", "pallas")
 
 
 def stream_kernel() -> str:
@@ -65,8 +51,10 @@ def stream_kernel() -> str:
     resident batches."""
     k = os.environ.get("PHOTON_STREAM_KERNEL")
     if k is None:
-        forced = os.environ.get("PHOTON_SPARSE_GRAD", "auto")
-        k = forced if forced in ("fm", "pallas", "xchg") else "autodiff"
+        from photon_tpu.ops.sparse_grad_select import pinned_kernel
+
+        forced = pinned_kernel()
+        k = forced if forced in ("fm", "pallas") else "autodiff"
     if k not in _KERNELS:
         raise ValueError(
             f"PHOTON_STREAM_KERNEL={k!r}; valid: {'|'.join(_KERNELS)}"
@@ -83,9 +71,9 @@ def stream_kernel_why(kernel: str) -> str:
             "per chunk"
         )
     return (
-        f"PHOTON_STREAM_KERNEL={kernel}: per-file layouts/routes built "
-        "once and cached (pow2-bucketed geometry), re-attached per pass "
-        "at stat+load cost (KERNEL_NOTES round-5 streaming section)"
+        f"PHOTON_STREAM_KERNEL={kernel}: per-file layouts built once "
+        "and cached (pow2-bucketed geometry), re-attached per pass at "
+        "stat+load cost"
     )
 
 
@@ -101,8 +89,8 @@ def _cache_root() -> Optional[str]:
     return resolve_cache_dir("PHOTON_STREAM_LAYOUT_CACHE", "stream")
 
 
-def _aux_cache_path(file_path: str, dim: int, kernel: str,
-                    mode: str, capacity: int) -> Optional[str]:
+def _layout_cache_path(file_path: str, dim: int,
+                       capacity: int) -> Optional[str]:
     root = _cache_root()
     if root is None:
         return None
@@ -114,12 +102,8 @@ def _aux_cache_path(file_path: str, dim: int, kernel: str,
         return None
     h = hashlib.sha256()
     h.update(repr(ident).encode())
-    h.update(f"|{dim}|{capacity}|{kernel}|{mode}|v{_VERSION}".encode())
+    h.update(f"|{dim}|{capacity}|pallas|v{_VERSION}".encode())
     return os.path.join(root, "aux_" + h.hexdigest()[:32] + ".npz")
-
-
-def _needs_layout(kernel: str, mode: str) -> bool:
-    return kernel == "pallas" or (kernel == "xchg" and mode == "aligned")
 
 
 def _build_padded_layout(ids_np: np.ndarray, vals_np: np.ndarray,
@@ -137,69 +121,12 @@ def _build_padded_layout(ids_np: np.ndarray, vals_np: np.ndarray,
     return pad_aligned_layout(lay, s2, t2)
 
 
-def _build_aux(ids_np: np.ndarray, vals_np: np.ndarray, dim: int,
-               kernel: str, mode: str):
-    """(layout | None, XchgAux | None) freshly built with pow2-bucketed
-    geometry.  Calls the underlying route builders directly (NOT
-    build_xchg_aux) so routes are not double-cached in the route cache —
-    the stream cache file is the single store — and so no env mutation
-    is needed on the (multi-threaded) chunk-load path."""
-    from photon_tpu.ops.vperm import (
-        XchgAux,
-        balanced_blk_census,
-        build_balanced_aligned_route,
-        build_balanced_sorted_route,
-        build_xchg_route,
-        build_xchg_sorted_route,
-    )
-
-    layout = (
-        _build_padded_layout(ids_np, vals_np, dim)
-        if _needs_layout(kernel, mode) else None
-    )
-    if kernel == "pallas":
-        return layout, None
-    n, k = ids_np.shape
-    e = ids_np.size
-    flat = ids_np.reshape(-1).astype(np.int64)
-    order = np.argsort(flat, kind="stable")
-    if mode == "cumsum":
-        census = balanced_blk_census(order, e, k)
-        built = (
-            build_balanced_sorted_route(
-                ids_np, dim, order, blk_override=_pow2(census)
-            ) if census is not None else None
-        )
-        if built is not None:
-            aux = XchgAux(route=built[0], bounds=built[1])
-        else:
-            aux = build_xchg_sorted_route(ids_np, dim, order=order)
-    else:
-        census = balanced_blk_census(
-            layout.src.reshape(-1), e, k
-        )
-        built = (
-            build_balanced_aligned_route(
-                layout, ids_np, blk_override=_pow2(census)
-            ) if census is not None else None
-        )
-        aux = XchgAux(route=built) if built is not None else XchgAux(
-            route=build_xchg_route(layout, n, k)
-        )
-    return layout, aux
-
-
-def _save_aux(path: str, layout, aux) -> None:
-    from photon_tpu.ops.vperm import _aux_to_npz
-
-    out = {}
-    if layout is not None:
-        for name in ("lo", "vals", "rows", "slab_of_tile", "dup_map"):
-            out["lay_" + name] = np.asarray(getattr(layout, name))
-        out["lay_n_entries"] = np.int64(layout.n_entries)
-    if aux is not None:
-        for key, val in _aux_to_npz(aux).items():
-            out["aux_" + key] = val
+def _save_layout(path: str, layout) -> None:
+    out = {
+        "lay_" + name: np.asarray(getattr(layout, name))
+        for name in ("lo", "vals", "rows", "slab_of_tile", "dup_map")
+    }
+    out["lay_n_entries"] = np.int64(layout.n_entries)
     import tempfile
 
     try:
@@ -221,36 +148,25 @@ def _save_aux(path: str, layout, aux) -> None:
         _LOG.warning("stream layout cache write failed (%s)", exc)
 
 
-def _load_aux(path: str):
-    """(layout | None, XchgAux | None) from a cache file, or None on any
-    read failure (caller rebuilds)."""
+def _load_layout(path: str):
+    """The aligned layout from a cache file, or None on any read failure
+    (caller rebuilds)."""
     from photon_tpu.ops.pallas_gather import AlignedLayout
-    from photon_tpu.ops.vperm import _aux_from_npz
 
     try:
         with np.load(path) as z:
-            layout = None
-            if "lay_lo" in z:
-                lo = z["lay_lo"]
-                layout = AlignedLayout(
-                    lo=lo,
-                    vals=z["lay_vals"],
-                    rows=z["lay_rows"],
-                    slab_of_tile=z["lay_slab_of_tile"],
-                    dup_map=z["lay_dup_map"],
-                    # Host-only routing field; never needed again once
-                    # the route is built (and not cached for size).
-                    src=np.full(lo.shape, -1, np.int64),
-                    n_entries=int(z["lay_n_entries"]),
-                )
-            aux = None
-            if "aux_kind" in z:
-                trimmed = {
-                    key[4:]: z[key] for key in z.files
-                    if key.startswith("aux_")
-                }
-                aux = _aux_from_npz(trimmed)
-            return layout, aux
+            lo = z["lay_lo"]
+            return AlignedLayout(
+                lo=lo,
+                vals=z["lay_vals"],
+                rows=z["lay_rows"],
+                slab_of_tile=z["lay_slab_of_tile"],
+                dup_map=z["lay_dup_map"],
+                # Host-only field the device layout never reads (and not
+                # cached for size).
+                src=np.full(lo.shape, -1, np.int64),
+                n_entries=int(z["lay_n_entries"]),
+            )
     except Exception as exc:  # noqa: BLE001 — corrupt cache = rebuild
         _LOG.warning("stream layout cache read failed (%s); rebuilding",
                      exc)
@@ -273,35 +189,25 @@ def attach_stream_aux(batch: SparseBatch, dim: int,
     if kernel == "fm":
         # Cheap (one argsort) relative to the parse; rebuilt per pass.
         return attach_feature_major(batch)
-    mode = os.environ.get("PHOTON_XCHG_REDUCE", "aligned")
-    path = _aux_cache_path(
-        file_path, dim, kernel, mode, int(batch.ids.shape[1])
-    )
-    layout = aux = None
+    from photon_tpu.ops.pallas_gather import device_layout
+
+    path = _layout_cache_path(file_path, dim, int(batch.ids.shape[1]))
+    layout = None
     if path is not None and os.path.exists(path):
-        loaded = _load_aux(path)
-        if loaded is not None:
-            layout, aux = loaded
-    if layout is None and aux is None:
+        layout = _load_layout(path)
+    if layout is None:
         # Host copies of the chunk arrays happen ONLY on this build
         # branch — the per-pass hit path stays stat + npz load.
         ids_np = np.asarray(batch.ids)
         vals_np = np.asarray(batch.vals, np.float32)
         _LOG.warning(
-            "building the %s stream layout for %s (%d entries, "
-            "mode=%s) — one-time host work, cached for every later "
-            "pass%s",
-            kernel, os.path.basename(file_path), ids_np.size, mode,
+            "building the %s stream layout for %s (%d entries) — one-time "
+            "host work, cached for every later pass%s",
+            kernel, os.path.basename(file_path), ids_np.size,
             "" if path is not None else
             " (caching DISABLED via PHOTON_STREAM_LAYOUT_CACHE=0)",
         )
-        layout, aux = _build_aux(ids_np, vals_np, dim, kernel, mode)
+        layout = _build_padded_layout(ids_np, vals_np, dim)
         if path is not None:
-            _save_aux(path, layout, aux)
-    if layout is not None:
-        from photon_tpu.ops.pallas_gather import device_layout
-
-        batch = batch._replace(al=device_layout(layout))
-    if aux is not None:
-        batch = batch._replace(xchg=aux)
-    return batch
+            _save_layout(path, layout)
+    return batch._replace(al=device_layout(layout))

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 import queue
 import threading
 from typing import Callable, Iterable, Iterator, List, NamedTuple, Optional, Sequence
@@ -44,7 +43,7 @@ from photon_tpu.core.optimizers.base import (
     init_history,
 )
 from photon_tpu.core.optimizers.lbfgs import _two_loop_direction
-from photon_tpu.data.batch import SparseBatch
+from photon_tpu.data.batch import LAYOUT_FIELDS, SparseBatch
 from photon_tpu.fault.injection import fault_point
 
 # Module-level jit: a per-call `jax.jit(...)` wrapper would carry a fresh
@@ -778,13 +777,12 @@ def make_global_batch(local_batch: SparseBatch, mesh, axis: str = "data",
     the multi-host path SURVEY.md §7 names).  Single-process meshes reduce
     to a plain shard placement.
 
-    With ``aligned_dim`` (and the kernel selector wanting them — same
-    gate as ``shard_batch``), each process builds the aligned/xchg aux
-    for ITS local row blocks, with the padded geometry and balanced
-    block census agreed GLOBALLY via a process allgather — so the
-    per-process stacked aux leaves concatenate into one uniformly-shaped
-    global array and the fast kernels run per shard on every host
-    (VERDICT r5 item 2, multi-process leg).
+    With ``aligned_dim`` (and the kernel selector wanting them, asked on
+    the GLOBAL entry count), each process builds the aligned layouts for
+    ITS local row blocks, with the padded geometry agreed GLOBALLY via a
+    process allgather — so the per-process stacked aux leaves concatenate
+    into one uniformly-shaped global array and the fast kernels run per
+    shard on every host (VERDICT r5 item 2, multi-process leg).
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -812,7 +810,10 @@ def make_global_batch(local_batch: SparseBatch, mesh, axis: str = "data",
     wants_aligned = False
     global_entries = None
     if aligned_dim is not None and local_batch.ids.ndim == 2:
-        from photon_tpu.ops.sparse_grad_select import aligned_layout_wanted
+        from photon_tpu.ops.sparse_grad_select import (
+            aligned_layout_wanted,
+            pinned_kernel,
+        )
 
         # Collective-agreement discipline: every decision that gates a
         # collective must itself be computed from GLOBALLY-agreed
@@ -837,49 +838,39 @@ def make_global_batch(local_batch: SparseBatch, mesh, axis: str = "data",
                 "batches first"
             )
         global_entries = int(shapes.prod(axis=1).sum())
-        if (
-            jax.process_count() > 1
-            and os.environ.get("PHOTON_SPARSE_GRAD", "auto") == "auto"
-        ):
-            # Mirror DistributedGlmObjective._sparse_kernel's multi-
-            # process auto pin: the objective will run autodiff, so
-            # building (and shipping to HBM) aux it will never touch is
-            # pure waste — AND this pin is what makes every remaining
-            # gate host-uniform: the forced modes that can still reach
-            # the attach resolve aligned_layout_wanted/xchg_route_wanted
-            # from the env alone (no per-host probes or native-lib
-            # loads), so no host can diverge around the geometry
-            # collectives.  PHOTON_SPARSE_GRAD must be set uniformly
-            # across processes (caller contract, like the mesh).
-            wants_aligned = False
-        else:
-            wants_aligned = aligned_layout_wanted(global_entries)
+        # Mirror DistributedGlmObjective._sparse_kernel's multi-process
+        # auto pin: the objective will run autodiff, so building (and
+        # shipping to HBM) aux it will never touch is pure waste — AND
+        # this pin is what makes the gate host-uniform: a forced mode
+        # resolves aligned_layout_wanted from the env alone (no per-host
+        # probes), so no host can diverge around the geometry
+        # collectives.  PHOTON_SPARSE_GRAD must be set uniformly across
+        # processes (caller contract, like the mesh).
+        wants_aligned = not (
+            jax.process_count() > 1 and pinned_kernel() is None
+        ) and aligned_layout_wanted(global_entries)
     rebuilt = False
     if wants_aligned or (
         local_batch.fm is not None
         and int(local_batch.fm.ids.shape[0]) != local_shards
     ):
         # Rebuild the aux at the right granularity (one block per local
-        # device) — and, when eligible, with the aligned/xchg layouts.
+        # device) — and, when eligible, with the aligned layouts.
         from photon_tpu.data.batch import attach_feature_major
 
         local_batch = attach_feature_major(
-            local_batch._replace(fm=None, al=None, al_t=None, xchg=None),
+            local_batch._replace(**dict.fromkeys(LAYOUT_FIELDS)),
             shards=local_shards,
             aligned_dim=aligned_dim if wants_aligned else None,
             geometry_gather=gather_geometry,
         )
         rebuilt = True
-    if local_batch.fm is not None:
-        core = core._replace(
-            fm=type(local_batch.fm)(*(build(leaf) for leaf in local_batch.fm))
-        )
-    if rebuilt:
-        # Forward ONLY aux this assembly built (stacked, with globally
-        # agreed geometry).  Caller-attached single-block aux cannot be
-        # row-sharded — it is stripped above, exactly as before round 5.
-        for aux_name in ("al", "al_t", "xchg"):
-            aux = getattr(local_batch, aux_name, None)
-            if aux is not None:
-                core = core._replace(**{aux_name: build_tree(aux)})
+    # Beside the per-block fm, forward ONLY aux this assembly built
+    # (stacked, with globally agreed geometry).  Caller-attached
+    # single-block aux cannot be row-sharded — it is dropped here,
+    # exactly as before round 5.
+    for aux_name in LAYOUT_FIELDS if rebuilt else ("fm",):
+        aux = getattr(local_batch, aux_name)
+        if aux is not None:
+            core = core._replace(**{aux_name: build_tree(aux)})
     return core

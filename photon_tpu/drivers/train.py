@@ -445,24 +445,19 @@ def _run_resident(args: argparse.Namespace, logger, session,
     if mesh is not None:
         logger.info("mesh: %d devices on axis 'data'", mesh.devices.size)
         # Attaches the per-shard feature-major layout — and the per-shard
-        # aligned/xchg layouts when the kernel selector could route to
-        # them (gated inside shard_batch), so the fast kernels run under
-        # the sharded objective too.
+        # aligned layouts when the kernel selector could route to them
+        # (decided inside attach_feature_major), so the fast kernels run
+        # under the sharded objective too.
         batch = shard_batch(batch, mesh, aligned_dim=dim)
     else:
         from photon_tpu.data.batch import SparseBatch, attach_feature_major
-        from photon_tpu.ops.sparse_grad_select import aligned_layout_wanted
 
         if isinstance(batch, SparseBatch) and batch.ids.ndim == 2:
             # Single-device: attach the pre-sorted layout so objectives take
-            # the segment-sum gradient path (exact under normalization too).
-            # The slab-aligned layout (Pallas kernel eligibility) is built
-            # only when the selector could actually route to it.
-            batch = attach_feature_major(
-                batch,
-                aligned_dim=dim
-                if aligned_layout_wanted(int(batch.ids.size)) else None,
-            )
+            # the segment-sum gradient path (exact under normalization too);
+            # the attach builds the fast kernels' layouts only when the
+            # selector could actually route to them.
+            batch = attach_feature_major(batch, aligned_dim=dim)
 
     if args.dtype != "float32":
         from photon_tpu.data.batch import batch_astype

@@ -122,12 +122,6 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.ixs_close.restype = None
     lib.ixs_close.argtypes = [c.c_void_p]
 
-    lib.clos_edge_color.restype = c.c_int32
-    lib.clos_edge_color.argtypes = [
-        c.c_int64, c.c_int32, c.c_int32, c.POINTER(c.c_int32),
-        c.POINTER(c.c_int32), c.POINTER(c.c_int32),
-    ]
-
 
 def get_lib() -> Optional[ctypes.CDLL]:
     """The native library, building it if needed; None when unavailable

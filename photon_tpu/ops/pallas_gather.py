@@ -77,9 +77,8 @@ class AlignedLayout:
       position (0 for unused positions — they gather ``w[0]`` but only ever
       multiply pad zeros).
     - ``src``: int64 ORIGINAL flat entry index (row-major ``r * k + j``)
-      each slot was filled from; -1 for pad slots.  Host-only — consumed by
-      the ``benes`` kernel's static-permutation routing (ops/clos.py),
-      never shipped to device.
+      each slot was filled from; -1 for pad slots.  Host-only, never
+      shipped to device.
     - ``n_entries``: real (unpadded) entry count.
     """
 
@@ -709,9 +708,7 @@ def aligned_reduce(
 ) -> Array:
     """Stages 2+3 of :func:`aligned_segment_grad` alone: fold per-slot
     products ``pv`` (``[total_sub, 128]``, zeros in pad slots) into the
-    ``dim`` coefficients.  The ``benes`` kernel (ops/benes.py) computes its
-    products by static permutation instead of the E-gather and enters
-    here."""
+    ``dim`` coefficients."""
     if interpret is None:
         interpret = pallas_interpret()
     with jax.named_scope("pallas/reduce"):

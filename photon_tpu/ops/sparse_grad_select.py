@@ -34,12 +34,16 @@ refuses (or that fails parity) is excluded LOUDLY: a WARNING and a
 ``kernels.refused{kernel=…}`` counter in the run report
 (utils/device.record_kernel_refusal), never a quiet switch of path.
 
-Override with ``PHOTON_SPARSE_GRAD=fm|autodiff|pallas|blocked|xchg|benes|auto``
-(default auto).  ``xchg`` (ops/vperm.py) and ``benes`` (ops/benes.py) are
-explicit opt-ins, never auto candidates: Mosaic on the v5e refuses the
-xchg chunk kernel's wide lane gather ("Not implemented: Multiple source
-vregs along gather dimension" — any chunk height over 128), so it runs in
-interpret mode only; benes was measured slower than every alternative.
+Override with ``PHOTON_SPARSE_GRAD=autodiff|fm|pallas|blocked|auto``
+(default auto); any other value raises at the first read
+(:func:`pinned_kernel`).
+
+This module is the one owner of the decision: it alone knows the kernel
+names and reads ``PHOTON_SPARSE_GRAD``, and :data:`_KERNELS` says for each
+kernel which batch layout it reads, whether it needs compiled Mosaic to be
+an auto candidate, whether it brings its own forward and whether
+``jax.jvp`` can go through it.  core/objective.py dispatches on the name;
+every other module asks here.
 """
 
 from __future__ import annotations
@@ -47,10 +51,71 @@ from __future__ import annotations
 import functools
 import os
 import time
+from typing import NamedTuple, Optional
 
 import numpy as np
 
 _CACHE: dict = {}
+
+
+class _Kernel(NamedTuple):
+    layout: Optional[str]  # the SparseBatch field its gradient reads
+    mosaic: bool  # an auto candidate only where Mosaic compiles (a TPU)
+    forward: Optional[str]  # the field that gives it a forward of its own
+    jvp: bool  # jax.jvp can differentiate through it (no pallas_call)
+
+
+# In order of preference: a pin whose layout the batch does not carry falls
+# to the nearest earlier kernel whose layout it does.
+_KERNELS = {
+    "autodiff": _Kernel(layout=None, mosaic=False, forward=None, jvp=True),
+    "fm": _Kernel(layout="fm", mosaic=False, forward=None, jvp=True),
+    "pallas": _Kernel(layout="al", mosaic=True, forward="al_t", jvp=False),
+    "blocked": _Kernel(layout="bt", mosaic=True, forward="bt", jvp=False),
+}
+KERNELS = tuple(_KERNELS)
+
+
+def pinned_kernel() -> Optional[str]:
+    """The kernel ``PHOTON_SPARSE_GRAD`` forces, or None in auto mode.  A
+    value that names no kernel raises: an operator's pin must never quietly
+    run something else."""
+    mode = os.environ.get("PHOTON_SPARSE_GRAD", "auto")
+    if mode == "auto":
+        return None
+    if mode not in _KERNELS:
+        raise ValueError(
+            f"PHOTON_SPARSE_GRAD={mode!r}; valid: {'|'.join(KERNELS)}|auto"
+        )
+    return mode
+
+
+def pin_for_multiprocess() -> None:
+    """Auto mode becomes ``autodiff`` for this process and its children: the
+    selection is a per-process wall-clock measurement, and ranks that
+    measured different winners would run different reduction orders.  An
+    operator's pin is the same on every rank already and stays."""
+    if pinned_kernel() is None:
+        os.environ["PHOTON_SPARSE_GRAD"] = "autodiff"
+
+
+def has_own_forward(kernel: str, batch) -> bool:
+    """Does ``kernel`` compute ``X u`` over a layout of its own on this
+    batch (else the row-major XLA gather does)?"""
+    field = _KERNELS[kernel].forward
+    return field is not None and getattr(batch, field) is not None
+
+
+def differentiable(kernel: str) -> bool:
+    """Can ``jax.jvp`` go through ``kernel``'s gradient?"""
+    return _KERNELS[kernel].jvp
+
+
+def _nearest(kernel: str, carried: tuple) -> str:
+    """``kernel`` if the batch carries its layout, else the nearest earlier
+    one it carries (``autodiff`` needs none)."""
+    upto = KERNELS[: KERNELS.index(kernel) + 1]
+    return [name for name in upto if name in carried][-1]
 
 # Probe arrays are capped so the one-time measurement stays cheap even for
 # billion-entry datasets; relative kernel cost is stable above this size.
@@ -141,19 +206,14 @@ def _kernel_fn(name: str, p):
 
         fn.forward = lambda w: block_tiles.block_tiles_product(w, bt, p.n)
         return fn
-    from photon_tpu.ops import pallas_gather
-
-    layout = pallas_gather.build_aligned_layout(p.ids, p.vals, d)
-    al = pallas_gather.device_layout(layout)
     if name == "pallas":
+        from photon_tpu.ops import pallas_gather
+
+        al = pallas_gather.device_layout(
+            pallas_gather.build_aligned_layout(p.ids, p.vals, d)
+        )
         # Looked up at call time: tests substitute the kernel.
         return lambda dz: pallas_gather.aligned_segment_grad(dz, al, d)
-    if name == "xchg":
-        from photon_tpu.ops.vperm import build_xchg_aux, xchg_segment_grad
-
-        aux = build_xchg_aux(layout, p.ids, d, vals=p.vals)
-        vals = jnp.asarray(p.vals)
-        return lambda dz: xchg_segment_grad(dz, vals, al, aux, d)
     raise ValueError(f"no probe for kernel {name!r}")
 
 
@@ -188,27 +248,20 @@ def check_kernel(name: str, p) -> tuple:
     return fn, "compiled+parity ok"
 
 
-def kernel_report(e: int, d: int, n: int,
-                  kernels=("autodiff", "fm", "pallas", "blocked",
-                           "xchg")) -> dict:
+def kernel_report(e: int, d: int, n: int, kernels=KERNELS) -> dict:
     """``{kernel: status}`` of :func:`check_kernel` at one probe problem —
     the per-kernel compile/parity table ``chip_smoke.py`` prints."""
     p = _probe_problem(e, d, n)
     return {name: check_kernel(name, p)[1] for name in kernels}
 
 
-def _measure(e: int, d: int, n: int, with_pallas: bool,
-             with_fm: bool = True, with_blocked: bool = False) -> str:
+def _measure(e: int, d: int, n: int, names: tuple) -> str:
+    """The fastest of the candidate kernels ``names`` on an evaluation of a
+    probe problem of this size (those that pass :func:`check_kernel`)."""
     import jax
     import jax.numpy as jnp
 
     p = _probe_problem(e, d, n)
-    # fm only when the batch carries the aux (streamed fast-kernel chunks
-    # attach the aligned layout without it): a winning-but-unavailable fm
-    # verdict would be sanitized to autodiff by select_kernel.
-    names = ["autodiff"] + (["fm"] if with_fm else []) + (
-        ["pallas"] if with_pallas else []
-    ) + (["blocked"] if with_blocked else [])
     dz, w = jnp.asarray(p.dz), jnp.asarray(p.w)
     ids, vals = jnp.asarray(p.ids), jnp.asarray(p.vals)
 
@@ -245,7 +298,7 @@ def _measure(e: int, d: int, n: int, with_pallas: bool,
     if not timings:
         raise RuntimeError(
             f"no sparse-gradient kernel passed its check on this device "
-            f"(tried {names}); see the kernels.refused warnings"
+            f"(tried {list(names)}); see the kernels.refused warnings"
         )
     return min(timings, key=timings.get)
 
@@ -257,72 +310,45 @@ def _pallas_eligible() -> bool:
     return not pallas_interpret()
 
 
-def select_kernel(
-    e_total: int,
-    dim: int,
-    n_rows: int,
-    has_fm: bool = True,
-    has_aligned: bool = False,
-    has_benes: bool = False,
-    has_xchg: bool = False,
-    has_blocked: bool = False,
-) -> str:
-    """Pick the gradient kernel — ``"fm"``, ``"autodiff"``, ``"pallas"``,
-    ``"blocked"``, ``"benes"``, or ``"xchg"`` — for this problem size on the
-    current backend, restricted to the layouts the batch actually carries."""
+def select_kernel(batch, dim: int) -> str:
+    """Pick the gradient kernel — one of :data:`KERNELS` — for this 2-D
+    sparse batch on the current backend, among the kernels whose layout the
+    batch carries."""
     from photon_tpu.utils.device import record_kernel_selected
 
-    choice = _select(
-        e_total, dim, n_rows, has_fm, has_aligned, has_benes, has_xchg,
-        has_blocked,
-    )
+    choice = _select(batch, dim)
     record_kernel_selected(choice)
     return choice
 
 
-def _select(e_total, dim, n_rows, has_fm, has_aligned, has_benes,
-            has_xchg, has_blocked) -> str:
-    mode = os.environ.get("PHOTON_SPARSE_GRAD", "auto")
-    if mode == "autodiff":
-        return "autodiff"
-    if mode == "fm":
-        return "fm" if has_fm else "autodiff"
-    if mode == "pallas":
-        # Forced pallas runs in interpret mode off-TPU (tests / parity
-        # checks); it still needs the aligned layout on the batch.
-        return "pallas" if has_aligned else ("fm" if has_fm else "autodiff")
-    if mode == "blocked":
-        # Same terms as forced pallas: needs the batch's entry tiles.
-        return "blocked" if has_blocked else (
-            "pallas" if has_aligned else ("fm" if has_fm else "autodiff")
-        )
-    if mode == "xchg":
-        # Explicit opt-in only: the chunk kernel does not lower on the v5e
-        # (module docstring), so on a TPU this mode fails at compile —
-        # loudly, by design.
-        return "xchg" if has_xchg else (
-            "pallas" if has_aligned else ("fm" if has_fm else "autodiff")
-        )
-    if mode == "benes":
-        # Explicit opt-in only; kept as a research path.
-        return "benes" if has_benes else (
-            "pallas" if has_aligned else ("fm" if has_fm else "autodiff")
-        )
+def _select(batch, dim: int) -> str:
+    carried = tuple(
+        name for name, kernel in _KERNELS.items()
+        if kernel.layout is None or getattr(batch, kernel.layout) is not None
+    )
+    pin = pinned_kernel()
+    if pin is not None:
+        # A forced Mosaic kernel runs in interpret mode off the TPU (tests,
+        # parity checks); it still needs its layout on the batch.
+        return _nearest(pin, carried)
     import jax
 
+    n_rows, k = batch.ids.shape
+    e_total = n_rows * k
     # Probe floor: below ~1M entries the eager measurement costs more than
     # any kernel difference could repay (GAME runs hit MANY small shape
     # buckets — one probe each).
     if e_total < _probe_floor():
         return "autodiff"
 
-    with_pallas = has_aligned and _pallas_eligible()
-    with_blocked = has_blocked and _pallas_eligible()
-    if not (has_fm or with_pallas or with_blocked):
+    candidates = tuple(
+        name for name in carried
+        if not _KERNELS[name].mosaic or _pallas_eligible()
+    )
+    if candidates == ("autodiff",):
         return "autodiff"  # single-candidate set: nothing to measure
     key = (
-        jax.default_backend(), _bucket(e_total), _bucket(dim),
-        with_pallas, bool(has_fm), with_blocked,
+        jax.default_backend(), _bucket(e_total), _bucket(dim), candidates
     )
     if key not in _CACHE:
         scale = max(1, -(-e_total // _probe_cap()))  # ceil: cap probe size
@@ -341,15 +367,10 @@ def _select(e_total, dim, n_rows, has_fm, has_aligned, has_benes,
         # outright raises: there is no default kernel to fall back to.
         from photon_tpu import telemetry
 
-        candidates = (
-            1 + int(bool(has_fm)) + int(with_pallas) + int(with_blocked)
-        )
-        with telemetry.span("kernels.probe", candidates=candidates, size=e), \
-                jax.core.eval_context():
-            _CACHE[key] = _measure(
-                e, dim, n, with_pallas, with_fm=bool(has_fm),
-                with_blocked=with_blocked,
-            )
+        with telemetry.span(
+            "kernels.probe", candidates=len(candidates), size=e
+        ), jax.core.eval_context():
+            _CACHE[key] = _measure(e, dim, n, candidates)
         import logging
 
         # Logged because auto-selection is a wall-clock measurement: on a
@@ -361,12 +382,7 @@ def _select(e_total, dim, n_rows, has_fm, has_aligned, has_benes,
             "sparse-grad kernel for backend=%s e~2^%d d~2^%d: %s",
             key[0], key[1], key[2], _CACHE[key],
         )
-    choice = _CACHE[key]
-    if choice == "pallas" and not has_aligned:
-        choice = "fm"
-    if choice == "fm" and not has_fm:
-        choice = "autodiff"
-    return choice
+    return _nearest(_CACHE[key], carried)
 
 
 def layouts_wanted(e_total: int | None = None) -> tuple[bool, bool]:
@@ -377,28 +393,23 @@ def layouts_wanted(e_total: int | None = None) -> tuple[bool, bool]:
     never pay for a kernel auto mode will not pick.  Pass the entry count
     when known: below the probe floor auto mode is guaranteed to run
     autodiff, so a build would be pure wasted host time."""
-    mode = os.environ.get("PHOTON_SPARSE_GRAD", "auto")
-    if mode != "auto":
-        return mode in ("pallas", "benes", "xchg"), mode == "blocked"
-    if e_total is not None and e_total < _probe_floor():
-        return False, False
-    return _pallas_eligible(), _pallas_eligible()
+    pin = pinned_kernel()
+    if pin is not None:
+        wanted = (_KERNELS[pin].layout,)
+    elif e_total is not None and e_total < _probe_floor():
+        wanted = ()
+    else:
+        wanted = tuple(
+            kernel.layout for kernel in _KERNELS.values()
+            if kernel.mosaic and _pallas_eligible()
+        )
+    return "al" in wanted, "bt" in wanted
 
 
 def aligned_layout_wanted(e_total: int | None = None) -> bool:
-    """Should a batch builder hand ``attach_feature_major`` the coefficient
-    dimension (``aligned_dim``)?  When :func:`layouts_wanted` wants any
-    layout; the attach builds the ones it names."""
+    """Does :func:`layouts_wanted` want any layout?  The gate
+    ``attach_feature_major(batch, aligned_dim=d)`` applies to itself; a
+    caller that must decide on other grounds (a multi-process assembly, on
+    the global entry count) asks it here and passes ``aligned_dim`` or
+    None."""
     return any(layouts_wanted(e_total))
-
-
-def xchg_route_wanted() -> bool:
-    """Should batch builders pay the vperm route construction (host
-    edge-coloring, the costliest layout build)?  Only when the kernel is
-    forced — it is not an auto candidate (module docstring)."""
-    return os.environ.get("PHOTON_SPARSE_GRAD", "auto") == "xchg"
-
-
-def fm_path_wins(e_total: int, dim: int, n_rows: int) -> bool:
-    """Back-compat boolean view of :func:`select_kernel` (fm vs autodiff)."""
-    return select_kernel(e_total, dim, n_rows, has_fm=True, has_aligned=False) == "fm"

@@ -29,7 +29,8 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from photon_tpu.core.objective import GlmObjective, _static_zero
-from photon_tpu.data.batch import Batch
+from photon_tpu.data.batch import LAYOUT_FIELDS, Batch
+from photon_tpu.ops.sparse_grad_select import differentiable, pinned_kernel
 from photon_tpu.parallel.mesh import DATA_AXIS
 
 Array = jax.Array
@@ -40,19 +41,11 @@ _MP_AUTO_PIN_LOGGED = False
 
 
 def _aux_is_stacked(v) -> bool:
-    """True when a batch aux carries a leading shard axis: the 2-D index
-    planes (aligned ``lo``, route stage planes) read rank 3."""
+    """True when a batch layout carries a leading shard axis: the aligned
+    layout's 2-D index plane ``lo`` reads rank 3."""
     from photon_tpu.ops.pallas_gather import AlignedLayoutDev
 
-    if isinstance(v, AlignedLayoutDev):
-        return v.lo.ndim == 3
-    route = getattr(v, "route", None)
-    if route is not None:
-        plane = getattr(route, "a1", None)
-        if plane is None:
-            plane = route.i1
-        return plane.ndim == 3
-    return False
+    return isinstance(v, AlignedLayoutDev) and v.lo.ndim == 3
 
 
 class DistributedGlmObjective:
@@ -80,15 +73,14 @@ class DistributedGlmObjective:
 
     def _squeeze_local_aux(self, local: Batch) -> Batch:
         """Inside shard_map: drop the leading shard axis from STACKED
-        aligned/xchg aux so each device hands its block's layout to the
-        kernels in their single-block form.  Stacked-ness is a SHAPE
-        property (index-plane rank 3 instead of 2) — not a mesh-size
-        inference: a 1-device-per-process multi-host assembly is stacked
-        at axis length 1, while a 1-device local mesh with a
-        single-block attach is not.  The fm aux keeps its
-        (always-present) block axis — _fm_segment_grad consumes it
-        directly."""
-        for aux in ("al", "al_t", "xchg"):
+        layouts so each device hands its block's layout to the kernels in
+        their single-block form.  Stacked-ness is a SHAPE property
+        (index-plane rank 3 instead of 2) — not a mesh-size inference: a
+        1-device-per-process multi-host assembly is stacked at axis length
+        1, while a 1-device local mesh with a single-block attach is not.
+        The fm aux keeps its (always-present) block axis —
+        _fm_segment_grad consumes it directly."""
+        for aux in LAYOUT_FIELDS:
             v = getattr(local, aux, None)
             if v is not None and _aux_is_stacked(v):
                 local = local._replace(
@@ -108,12 +100,7 @@ class DistributedGlmObjective:
         (README determinism note); pin ``PHOTON_SPARSE_GRAD`` explicitly
         to run a fast kernel on a multi-process mesh — a forced choice
         is identical on every host by construction."""
-        import os
-
-        if (
-            os.environ.get("PHOTON_SPARSE_GRAD", "auto") == "auto"
-            and jax.process_count() > 1
-        ):
+        if pinned_kernel() is None and jax.process_count() > 1:
             global _MP_AUTO_PIN_LOGGED
             if not _MP_AUTO_PIN_LOGGED:
                 _MP_AUTO_PIN_LOGGED = True
@@ -122,8 +109,7 @@ class DistributedGlmObjective:
                 logging.getLogger("photon_tpu.distributed").info(
                     "multi-process auto mode pins the sharded objective "
                     "to autodiff (per-host probes could disagree); set "
-                    "PHOTON_SPARSE_GRAD=fm|pallas|xchg to run a fast "
-                    "kernel"
+                    "PHOTON_SPARSE_GRAD=fm|pallas to run a fast kernel"
                 )
             return None
         return self.obj._sparse_kernel(batch, int(w.shape[0]))
@@ -152,9 +138,9 @@ class DistributedGlmObjective:
         kernel = self._sparse_kernel(w, batch)
         if kernel is not None:
             # Static-sparsity fast path: per-shard explicit value+gradient
-            # over the shard's block-local static layout (fm segment-sum,
-            # pallas aligned reduce, or the xchg exchange — whichever the
-            # measured selection picked), psum-ed — the direct analog of
+            # over the shard's block-local static layout (fm segment-sum or
+            # pallas aligned reduce — whichever the measured selection
+            # picked), psum-ed — the direct analog of
             # treeAggregate(ValueAndGradientAggregator) with the
             # per-evaluation sort deleted (see FeatureMajorAux).
             ax = self.axis_name
@@ -219,11 +205,11 @@ class DistributedGlmObjective:
     def _differentiable_grad(self, w: Array, batch: Batch) -> Array:
         """Gradient via a kernel jvp can differentiate THROUGH (the
         normalized-Hv path re-differentiates the gradient, and
-        ``pallas_call`` has no JVP rule): pallas/xchg/blocked route to the fm
-        layout — always built alongside the aligned one — mirroring
+        ``pallas_call`` has no JVP rule): pallas/blocked route to the fm
+        layout — always built alongside theirs — mirroring
         GlmObjective._differentiable_grad."""
         kernel = self._sparse_kernel(w, batch)
-        if kernel in ("pallas", "xchg", "benes", "blocked"):
+        if kernel is not None and not differentiable(kernel):
             kernel = "fm" if batch.fm is not None else None
         if kernel is None:
             return jax.grad(self.value)(w, batch)

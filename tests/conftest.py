@@ -102,10 +102,9 @@ jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 # logic itself is tested explicitly with env overrides.
 os.environ.setdefault("PHOTON_SPARSE_GRAD", "fm")
 
-# The vperm route disk cache must NOT serve tests: a stale cached route
-# would mask builder regressions (tests would validate deserialization,
-# not construction).  The cache itself is covered by a dedicated test
-# with an explicit tmp-dir override.
+# The host layout caches (under this root) must NOT serve tests: a stale
+# cached layout would mask builder regressions.  The caches themselves are
+# covered by dedicated tests with an explicit tmp-dir override.
 os.environ.setdefault("PHOTON_ROUTE_CACHE", "0")
 
 # Hermetic fixtures: an operator's ambient PHOTON_REAL_DATA_DIR would
@@ -123,29 +122,6 @@ def pytest_configure(config):
         "slow: excluded from the tier-1 run (`-m 'not slow'`); full CLI "
         "subprocess drives and other minute-scale checks",
     )
-
-
-@pytest.fixture(scope="session")
-def native_router():
-    """The native ``_photon_native.so``, building it once per session.
-
-    ``build.get_lib`` caches both on disk (the compiled .so survives across
-    sessions) and in process (a failed build costs one attempt), so this
-    fixture is effectively free after the first use.  Tests whose routes
-    exceed the pure-Python edge-colorer's size cap (ops/clos.py) depend on
-    it; when no working C++ toolchain is present they skip with a reason
-    instead of erroring out of ``route_permutation``.
-    """
-    from photon_tpu.native import build
-
-    lib = build.get_lib()
-    if lib is None:
-        pytest.skip(
-            "native _photon_native.so unavailable (no working C++ toolchain "
-            "to build clos_edge_color; routes over the Python fallback cap "
-            "cannot be colored)"
-        )
-    return lib
 
 
 @pytest.fixture(autouse=True, scope="module")

@@ -372,8 +372,8 @@ def test_a_batch_over_the_tile_table_goes_on_without_the_tiles(monkeypatch, mode
     if mode == "auto":  # as on a TPU: both layouts wanted, the probe stubbed
         monkeypatch.setattr(sel, "_pallas_eligible", lambda: True)
 
-        def measure(e, d, n, with_pallas, with_fm=True, with_blocked=False):
-            seen.append((with_pallas, with_fm, with_blocked))
+        def measure(e, d, n, names):
+            seen.append(names)
             return "fm"
 
         monkeypatch.setattr(sel, "_measure", measure)
@@ -390,7 +390,7 @@ def test_a_batch_over_the_tile_table_goes_on_without_the_tiles(monkeypatch, mode
     finally:
         sel._CACHE.clear()
         sel._CACHE.update(saved)
-    assert seen == ([(True, True, False)] if mode == "auto" else [])
+    assert seen == ([("autodiff", "fm", "pallas")] if mode == "auto" else [])
     assert int(result.iterations) == int(ref.iterations)
     _close(got.means, want.means, rel=1e-4)
 
@@ -444,9 +444,12 @@ def test_storage_dtype_and_row_padding(monkeypatch):
 # -- selection -----------------------------------------------------------------
 
 
-def test_blocked_is_offered_only_when_the_batch_carries_the_layout(monkeypatch):
+def test_blocked_runs_both_directions_and_is_probed_where_mosaic_compiles(
+    monkeypatch,
+):
     """Pinned and spied on, as ``test_sparse_grad_kernel_selection`` does for
-    ``fm``."""
+    ``fm`` (which kernel a pin runs over which layouts:
+    tests/test_sparse_kernel_owner.py)."""
     import photon_tpu.ops.sparse_grad_select as sel
 
     batch = _batch("uniform", "logistic", seed=51)
@@ -463,39 +466,34 @@ def test_blocked_is_offered_only_when_the_batch_carries_the_layout(monkeypatch):
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "blocked")
     with_fm = attach_feature_major(batch)
     assert obj._sparse_kernel(with_fm, D) == "fm"  # no tiles: next best
-    assert obj._sparse_kernel(batch, D) is None
     obj.value_and_grad(w, with_fm)
     assert not calls
     fast = _with_tiles(batch)
-    assert obj._sparse_kernel(fast, D) == "blocked"
-    assert obj._sparse_kernel(fast) == "blocked"
     obj.value_and_grad(w, fast)
     assert calls == [(N, False), (D, True)]
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "autodiff")
-    assert obj._sparse_kernel(fast, D) is None
     # auto: a candidate under pallas's conditions (compiled Mosaic, entries
     # above the floor), and the probe is told so.
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
     monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "0")
     seen = []
 
-    def measure(e, d, n, with_pallas, with_fm=True, with_blocked=False):
-        seen.append((with_pallas, with_fm, with_blocked))
-        return "blocked" if with_blocked else "autodiff"
+    def measure(e, d, n, names):
+        seen.append(names)
+        return "blocked" if "blocked" in names else "autodiff"
 
     monkeypatch.setattr(sel, "_measure", measure)
     saved = dict(sel._CACHE)
     sel._CACHE.clear()
     try:
-        pick = lambda **kw: sel.select_kernel(N * K, D, N, **kw)  # noqa: E731
-        assert pick(has_fm=False, has_blocked=True) == "autodiff"  # CPU
+        pick = lambda b: sel.select_kernel(b, D)  # noqa: E731
+        assert pick(fast) == "autodiff"  # CPU
         assert not seen
         monkeypatch.setattr(sel, "_pallas_eligible", lambda: True)
-        assert pick(has_fm=True, has_blocked=True) == "blocked"
-        assert pick(has_fm=True, has_blocked=False) == "autodiff"
-        assert seen == [(False, True, True), (False, True, False)]
+        assert pick(fast._replace(fm=with_fm.fm)) == "blocked"
+        assert pick(with_fm) == "autodiff"
+        assert seen == [("autodiff", "fm", "blocked"), ("autodiff", "fm")]
         monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", str(1 << 20))
-        assert pick(has_fm=True, has_blocked=True) == "autodiff"  # the floor
+        assert pick(fast._replace(fm=with_fm.fm)) == "autodiff"  # the floor
     finally:
         sel._CACHE.clear()
         sel._CACHE.update(saved)
@@ -519,9 +517,7 @@ def test_probe_refuses_a_wrong_blocked_kernel_loudly(monkeypatch):
     for wrong in (garbage, garbage_forward):
         process_registry().clear()
         monkeypatch.setattr(bt_mod, "block_tiles_product", wrong)
-        choice = sel._measure(
-            1 << 12, 256, 256, with_pallas=False, with_blocked=True
-        )
+        choice = sel._measure(1 << 12, 256, 256, ("autodiff", "fm", "blocked"))
         assert choice in ("fm", "autodiff")
         assert refused() == {"blocked": 1.0}
     process_registry().clear()
@@ -552,7 +548,7 @@ def test_probe_times_an_evaluation_margins_and_gradient(monkeypatch):
 
     monkeypatch.setattr(bt_mod, "block_tiles_product", spy)
     monkeypatch.setattr(jax, "jit", jit_spy)
-    sel._measure(1 << 12, 256, 256, with_pallas=False, with_blocked=True)
+    sel._measure(1 << 12, 256, 256, ("autodiff", "fm", "blocked"))
     monkeypatch.setattr(jax, "jit", real_jit)
     assert sorted(traced) == [False, True]
     p = sel._probe_problem(1 << 12, 256, 256)

@@ -161,13 +161,24 @@ def test_sparse_grad_kernel_selection(monkeypatch):
 
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
     sel._CACHE.clear()
-    decision = sel.fm_path_wins(n * k, d, n)
-    assert isinstance(decision, bool)
+    # On the CPU auto never measures a Mosaic kernel, whatever the batch
+    # carries (the eligibility gate): the aligned layout is no candidate.
+    with_al = batch._replace(al=object())
+    assert sel.select_kernel(with_al, d) in ("fm", "autodiff")
     assert sel._CACHE, "auto mode must cache the measurement"
+    (key,) = sel._CACHE
+    assert key[3] == ("autodiff", "fm")
     # Same bucket -> no re-measure (cache key count stable).
     before = dict(sel._CACHE)
-    sel.fm_path_wins(n * k, d, n)
+    sel.select_kernel(batch, d)
     assert sel._CACHE == before
+    # Which layouts a builder should pay for: a pinned kernel's; on the
+    # CPU, none in auto.
+    assert not sel.aligned_layout_wanted()
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
+    assert sel.aligned_layout_wanted()
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "fm")
+    assert not sel.aligned_layout_wanted()
 
 
 def test_fast_path_under_normalization_matches_autodiff():
@@ -235,13 +246,13 @@ def test_pallas_kernel_matches_autodiff(monkeypatch, loss, zipf):
     autodiff reference like the fm path does (VERDICT r3 item 2)."""
     n, k, d = 256, 6, 48
     batch = _random_batch(n, k, d, seed=50, zipf=zipf)
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     fast = attach_feature_major(batch, aligned_dim=d)
     assert fast.al is not None
     obj = GlmObjective.create(loss, RegularizationContext("l2", 0.6))
     rng = np.random.default_rng(51)
     w = jnp.asarray(rng.standard_normal(d), jnp.float32) * 0.1
 
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     assert obj._sparse_kernel(fast, d) == "pallas"
     v_ref, g_ref = jax.value_and_grad(obj.value)(w, batch)
     v_p, g_p = obj.value_and_grad(w, fast)
@@ -265,6 +276,7 @@ def test_pallas_kernel_under_normalization(monkeypatch):
 
     n, k, d = 192, 5, 40
     batch = _random_batch(n, k, d, seed=60)
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     fast = attach_feature_major(batch, aligned_dim=d)
     summary = BasicStatisticalSummary.from_batch(batch, d)
     norm = NormalizationContext.build("standardization", summary, intercept_id=0)
@@ -272,7 +284,6 @@ def test_pallas_kernel_under_normalization(monkeypatch):
         "logistic", RegularizationContext("l2", 0.4), normalization=norm
     )
     w = jnp.asarray(np.random.default_rng(61).standard_normal(d), jnp.float32) * 0.1
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     v_ref, g_ref = jax.value_and_grad(obj.value)(w, batch)
     v_p, g_p = obj.value_and_grad(w, fast)
     np.testing.assert_allclose(v_p, v_ref, rtol=1e-5)
@@ -291,6 +302,7 @@ def test_pallas_forward_margins_via_transposed_layout(monkeypatch, zipf):
 
     n, k, d = 320, 7, 56
     batch = _random_batch(n, k, d, seed=80, zipf=zipf)
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     fast = attach_feature_major(batch, aligned_dim=d, aligned_forward=True)
     assert fast.al is not None and fast.al_t is not None
     rng = np.random.default_rng(81)
@@ -305,7 +317,6 @@ def test_pallas_forward_margins_via_transposed_layout(monkeypatch, zipf):
         rtol=2e-4, atol=1e-5,
     )
 
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     obj = GlmObjective.create("logistic", RegularizationContext("l2", 0.5))
     v_ref, g_ref = jax.value_and_grad(obj.value)(w, batch)
     v_p, g_p = obj.value_and_grad(w, fast)
@@ -338,6 +349,7 @@ def test_pallas_kernel_normalized_hessian_vector(monkeypatch):
 
     n, k, d = 128, 4, 24
     batch = _random_batch(n, k, d, seed=65)
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     fast = attach_feature_major(batch, aligned_dim=d)
     summary = BasicStatisticalSummary.from_batch(batch, d)
     norm = NormalizationContext.build("standardization", summary, intercept_id=0)
@@ -347,36 +359,9 @@ def test_pallas_kernel_normalized_hessian_vector(monkeypatch):
     rng = np.random.default_rng(66)
     w = jnp.asarray(rng.standard_normal(d), jnp.float32) * 0.1
     vec = jnp.asarray(rng.standard_normal(d), jnp.float32)
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     hv = obj.hessian_vector(w, vec, fast)
     hv_ref = jax.jvp(lambda u: jax.grad(obj.value)(u, batch), (w,), (vec,))[1]
     np.testing.assert_allclose(hv, hv_ref, rtol=2e-4, atol=1e-5)
-
-
-def test_select_kernel_availability_fallbacks(monkeypatch):
-    """select_kernel honors layout availability: pallas needs the aligned
-    layout, fm needs the feature-major aux; on CPU auto never picks pallas
-    (Mosaic eligibility gate)."""
-    import photon_tpu.ops.sparse_grad_select as sel
-
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
-    assert sel.select_kernel(1024, 64, 256, has_fm=True, has_aligned=False) == "fm"
-    assert sel.select_kernel(1024, 64, 256, has_fm=False, has_aligned=False) == "autodiff"
-    assert sel.select_kernel(1024, 64, 256, has_fm=False, has_aligned=True) == "pallas"
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
-    # Drop the floor so the 1024-entry call reaches the MEASURED path —
-    # the pallas-exclusion assertion is about the probe, not the floor.
-    monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "0")
-    sel._CACHE.clear()
-    choice = sel.select_kernel(1024, 64, 256, has_fm=True, has_aligned=True)
-    assert choice in ("fm", "autodiff"), "CPU auto must exclude pallas"
-    # aligned_layout_wanted: forced pallas -> build; auto on CPU -> don't.
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
-    assert sel.aligned_layout_wanted()
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
-    assert not sel.aligned_layout_wanted()
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "fm")
-    assert not sel.aligned_layout_wanted()
 
 
 def _refused() -> dict:
@@ -423,7 +408,7 @@ def test_measure_correctness_gate_excludes_bad_pallas(monkeypatch, caplog):
     process_registry().clear()
     monkeypatch.setattr(pg, "aligned_segment_grad", garbage)
     with caplog.at_level("WARNING", logger="photon_tpu.kernels"):
-        choice = sel._measure(1 << 12, 256, 256, with_pallas=True)
+        choice = sel._measure(1 << 12, 256, 256, ("autodiff", "fm", "pallas"))
     assert choice in ("fm", "autodiff"), "garbage pallas must be excluded"
     assert _refused() == {"pallas": 1.0}
     assert "kernel pallas refused on this device: parity failed" in caplog.text
@@ -432,7 +417,7 @@ def test_measure_correctness_gate_excludes_bad_pallas(monkeypatch, caplog):
     caplog.clear()
     monkeypatch.setattr(pg, "aligned_segment_grad", refused)
     with caplog.at_level("WARNING", logger="photon_tpu.kernels"):
-        choice = sel._measure(1 << 12, 256, 256, with_pallas=True)
+        choice = sel._measure(1 << 12, 256, 256, ("autodiff", "fm", "pallas"))
     assert choice in ("fm", "autodiff"), "a refused pallas must be excluded"
     assert _refused() == {"pallas": 1.0}
     assert caplog.text.rstrip().endswith(
@@ -453,7 +438,7 @@ def test_measure_correctness_gate_excludes_bad_pallas(monkeypatch, caplog):
 
     process_registry().clear()
     monkeypatch.setattr(pg, "aligned_segment_grad", correct)
-    choice2 = sel._measure(1 << 12, 256, 256, with_pallas=True)
+    choice2 = sel._measure(1 << 12, 256, 256, ("autodiff", "fm", "pallas"))
     assert choice2 in ("fm", "autodiff", "pallas")  # gate passed; timing decides
     assert not _refused()
     klog.removeHandler(caplog.handler)
@@ -491,13 +476,14 @@ def test_probe_floor_skips_measurement_for_small_problems(monkeypatch):
 
     monkeypatch.setattr(sel, "_measure", boom)
     sel._CACHE.clear()
-    assert sel.select_kernel(1 << 10, 64, 256, has_fm=True) == "autodiff"
+    batch = attach_feature_major(_random_batch(256, 4, 64, seed=72))
+    assert sel.select_kernel(batch, 64) == "autodiff"
     assert not sel._CACHE, "below the floor the probe path must not engage"
     # At/above the floor the measurement DOES run — and a probe that fails
     # outright propagates: there is no quiet pin to a default kernel.
     monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "512")
     with pytest.raises(AssertionError, match="probe must not run"):
-        sel.select_kernel(1 << 10, 64, 256, has_fm=True)
+        sel.select_kernel(batch, 64)
     assert not sel._CACHE, "a failed probe must not cache a verdict"
 
 
@@ -507,12 +493,12 @@ def test_aligned_layout_survives_astype_and_pad_strip(monkeypatch):
     from photon_tpu.data.batch import batch_astype, pad_batch
 
     batch = _random_batch(64, 4, 32, seed=70)
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     fast = attach_feature_major(batch, aligned_dim=32)
     bf16 = batch_astype(fast, jnp.bfloat16)
     assert bf16.al is not None and bf16.al.vals.dtype == jnp.bfloat16
     obj = GlmObjective.create("logistic")
     w = jnp.asarray(np.random.default_rng(71).standard_normal(32), jnp.float32) * 0.1
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     _, g_ref = jax.value_and_grad(obj.value)(w, batch)
     _, g_bf = obj.value_and_grad(w, bf16)
     np.testing.assert_allclose(g_bf, g_ref, rtol=0.02, atol=0.02)
@@ -586,11 +572,14 @@ def test_selection_probe_measures_under_enclosing_trace(monkeypatch):
     monkeypatch.setattr(sg, "_measure", spy)
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
     monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "1")
+    # What the batch carries names the candidates; the probe measures them
+    # on a problem of its own.
+    batch = attach_feature_major(
+        _random_batch(256, 16, 512, seed=74)
+    )._replace(al=object())
     try:
         def f(x):
-            choice = sg.select_kernel(
-                4096, 512, 256, has_fm=True, has_aligned=True
-            )
+            choice = sg.select_kernel(batch, 512)
             assert choice in ("fm", "autodiff", "pallas")
             return x * 2.0
 

@@ -73,16 +73,14 @@ hv = dist.hessian_vector(
 )
 
 # Part 1b (round 5): multi-process SHARDED FAST KERNELS — each process
-# builds the xchg aux for its local block with globally-agreed geometry
+# builds the aligned layout for its local block with globally-agreed geometry
 # (the allgather inside make_global_batch), and the sharded objective
 # must produce the same numbers the fm path above did.
 _prev_env = {
     k: os.environ.get(k)
-    for k in ("PHOTON_SPARSE_GRAD", "PHOTON_XCHG_REDUCE",
-              "PHOTON_ROUTE_CACHE")
+    for k in ("PHOTON_SPARSE_GRAD", "PHOTON_ROUTE_CACHE")
 }
-os.environ["PHOTON_SPARSE_GRAD"] = "xchg"
-os.environ["PHOTON_XCHG_REDUCE"] = "cumsum"
+os.environ["PHOTON_SPARSE_GRAD"] = "pallas"
 os.environ["PHOTON_ROUTE_CACHE"] = "0"
 local_x = SparseBatch(
     jnp.asarray(ids[lo:hi]), jnp.asarray(vals[lo:hi]),
@@ -90,10 +88,11 @@ local_x = SparseBatch(
     jnp.asarray(weight[lo:hi]),
 )
 batch_x = make_global_batch(local_x, mesh, aligned_dim=d)
-assert batch_x.xchg is not None, "multi-process xchg aux missing"
+assert batch_x.al is not None, "multi-process aligned layout missing"
+assert dist._sparse_kernel(w, batch_x) == "pallas"
 v_x, g_x = dist.value_and_grad(w, batch_x)
 # Restore the pre-part-1b environment so part 2 exercises the same
-# (auto, default-reduce, cached-routes) dispatch it did before round 5.
+# (auto, cached-layouts) dispatch it did before round 5.
 for _k, _v in _prev_env.items():
     if _v is None:
         os.environ.pop(_k, None)
@@ -137,8 +136,8 @@ with open(out_path, "w") as f:
         "value": float(v),
         "grad": np.asarray(g).tolist(),
         "hv": np.asarray(hv).tolist(),
-        "xchg_value": float(v_x),
-        "xchg_grad": np.asarray(g_x).tolist(),
+        "pallas_value": float(v_x),
+        "pallas_grad": np.asarray(g_x).tolist(),
         "rs_means": to_host(coeffs.means).tolist(),
         "rs_value": to_host(res.value).tolist(),
     }, f)
@@ -272,13 +271,13 @@ def test_two_process_objective_matches_single(merged_worker_results):
                                rtol=2e-4, atol=1e-5)
     np.testing.assert_allclose(results[0]["hv"], np.asarray(hv_ref),
                                rtol=2e-4, atol=1e-5)
-    # Round 5: the multi-process SHARDED XCHG path (per-process aux with
+    # Round 5: the multi-process SHARDED PALLAS path (per-process aux with
     # globally-agreed geometry) must match the same reference.
-    assert results[0]["xchg_value"] == pytest.approx(float(v_ref), rel=1e-5)
-    np.testing.assert_allclose(results[0]["xchg_grad"], np.asarray(g_ref),
+    assert results[0]["pallas_value"] == pytest.approx(float(v_ref), rel=1e-5)
+    np.testing.assert_allclose(results[0]["pallas_grad"], np.asarray(g_ref),
                                rtol=2e-4, atol=1e-4)
-    assert results[0]["xchg_value"] == pytest.approx(
-        results[1]["xchg_value"], rel=1e-6
+    assert results[0]["pallas_value"] == pytest.approx(
+        results[1]["pallas_value"], rel=1e-6
     )
 
 

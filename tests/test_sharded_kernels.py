@@ -1,5 +1,5 @@
-"""Sharded fast-kernel equivalence (VERDICT r5 item 2): the pallas and
-xchg gradient kernels must produce the SAME numbers under the sharded
+"""Sharded fast-kernel equivalence (VERDICT r5 item 2): the pallas
+gradient kernel must produce the SAME numbers under the sharded
 objective (8-virtual-device mesh, per-shard layouts + psum) as plain
 single-device autodiff.
 
@@ -43,11 +43,9 @@ def _autodiff_reference(obj, w, batch, monkeypatch):
     return np.asarray(v), np.asarray(g)
 
 
-def _check_sharded(monkeypatch, kernel, reduce_mode=None, loss="logistic",
-                   reg=None, n=N, check_hv=True):
+def _check_sharded(monkeypatch, kernel, loss="logistic", reg=None, n=N,
+                   check_hv=True):
     monkeypatch.setenv("PHOTON_ROUTE_CACHE", "0")
-    if reduce_mode is not None:
-        monkeypatch.setenv("PHOTON_XCHG_REDUCE", reduce_mode)
     batch = _batch(n=n)
     obj = GlmObjective.create(
         loss, reg or RegularizationContext("l2", 0.3)
@@ -84,24 +82,12 @@ def test_sharded_pallas_grad_matches_autodiff(monkeypatch):
     _check_sharded(monkeypatch, "pallas")
 
 
-def test_sharded_xchg_cumsum_matches_autodiff(monkeypatch):
-    _check_sharded(monkeypatch, "xchg", reduce_mode="cumsum")
-
-
-def test_sharded_xchg_aligned_matches_autodiff(monkeypatch):
-    # Hv covered by the cumsum variant (same exchange machinery); skipped
-    # here to keep the suite under its wall-clock bar.
-    _check_sharded(monkeypatch, "xchg", reduce_mode="aligned",
-                   check_hv=False)
-
-
-def test_sharded_xchg_poisson_unpadded_rows(monkeypatch):
+def test_sharded_pallas_poisson_unpadded_rows(monkeypatch):
     """Different loss + a row count that needs zero-weight padding (101
     rows over 8 shards): the pad rows must contribute exactly nothing
-    through the exchange.  (Hv covered by the logistic cumsum test.)"""
+    through the per-shard layouts.  (Hv covered by the logistic test.)"""
     _check_sharded(
-        monkeypatch, "xchg", reduce_mode="cumsum", loss="poisson", n=101,
-        check_hv=False,
+        monkeypatch, "pallas", loss="poisson", n=101, check_hv=False
     )
 
 
@@ -144,13 +130,12 @@ def test_sharded_pallas_normalized_grad(monkeypatch):
 
 
 def test_sharded_attach_stacks_uniform_geometry(monkeypatch):
-    """The per-shard aux must stack: aligned layouts share one padded
-    geometry; xchg routes share one treedef (shared blk census or a
-    collective colored fallback)."""
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
-    monkeypatch.setenv("PHOTON_XCHG_REDUCE", "cumsum")
+    """The per-shard aux must stack: the aligned layouts (and, asked for,
+    the transposed ones) share one padded geometry, so every leaf carries
+    the shard axis and the batch is ONE pytree."""
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     monkeypatch.setenv("PHOTON_ROUTE_CACHE", "0")
-    # Skewed ids so per-shard block censuses genuinely differ.
+    # Skewed ids so per-shard slab and tile counts genuinely differ.
     rng = np.random.default_rng(3)
     n = 8 * 24
     ids = (1 + (rng.zipf(1.5, size=(n, K)) - 1) % (D - 1)).astype(np.int32)
@@ -161,25 +146,29 @@ def test_sharded_attach_stacks_uniform_geometry(monkeypatch):
         offset=jnp.zeros(n, jnp.float32),
         weight=jnp.ones(n, jnp.float32),
     )
-    out = attach_feature_major(batch, shards=8, aligned_dim=D)
-    assert out.al is not None and out.xchg is not None
+    out = attach_feature_major(
+        batch, shards=8, aligned_dim=D, aligned_forward=True
+    )
+    assert out.al is not None and out.al_t is not None and out.bt is None
     assert int(out.al.lo.shape[0]) == 8
     assert int(out.al.dup_map.shape[0]) == 8
-    # One treedef means uniform meta (n_in/n_out/nc/ch/... are static).
-    leaves = jax.tree.leaves(out.xchg)
-    assert all(int(leaf.shape[0]) == 8 for leaf in leaves)
+    for aux in (out.al, out.al_t):
+        assert all(int(leaf.shape[0]) == 8 for leaf in jax.tree.leaves(aux))
+    # Under a pin that reads no layout the same call builds fm alone.
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "fm")
+    plain = attach_feature_major(batch, shards=8, aligned_dim=D)
+    assert plain.fm is not None and plain.al is None
 
 
-def test_sharded_lbfgs_convergence_xchg(monkeypatch):
-    """A full sharded L-BFGS fit with the xchg kernel forced converges to
-    the same optimum as single-device autodiff.  Iteration cap keeps the
-    interpret-mode run inside the suite's wall-clock bar (converges in
+def test_sharded_lbfgs_convergence_pallas(monkeypatch):
+    """A full sharded L-BFGS fit with the pallas kernel forced converges
+    to the same optimum as single-device autodiff.  Iteration cap keeps
+    the interpret-mode run inside the suite's wall-clock bar (converges in
     ~15 iterations at this shape)."""
     from photon_tpu.core.optimizers import OptimizerConfig, lbfgs
 
     cfg = OptimizerConfig(max_iterations=30)
     monkeypatch.setenv("PHOTON_ROUTE_CACHE", "0")
-    monkeypatch.setenv("PHOTON_XCHG_REDUCE", "cumsum")
     batch = _batch(seed=11)
     obj = GlmObjective.create("logistic", RegularizationContext("l2", 1.0))
     w0 = jnp.zeros(D, jnp.float32)
@@ -187,7 +176,7 @@ def test_sharded_lbfgs_convergence_xchg(monkeypatch):
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "autodiff")
     res_ref = lbfgs(lambda w: obj.value_and_grad(w, batch), w0, cfg)
 
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     mesh = create_mesh()
     sharded = shard_batch(batch, mesh, aligned_dim=D)
     dist = DistributedGlmObjective(obj, mesh)
@@ -201,30 +190,26 @@ def test_sharded_lbfgs_convergence_xchg(monkeypatch):
     )
 
 
-def test_bf16_storage_keeps_xchg_grad_consistent(monkeypatch):
-    """batch_astype(bf16) after an xchg attach must keep the gradient
-    consistent with the (converted) values the margins read: the baked
-    vals_dest converts IN PLACE (elementwise casts commute with the
-    static permutation), so both directions see one value stream and
-    the fused path survives.  Checked sharded AND single-device against
+def test_bf16_storage_keeps_pallas_grad_consistent(monkeypatch):
+    """batch_astype(bf16) after a pallas attach must keep the gradient
+    consistent with the (converted) values the margins read: the layout's
+    own value stream converts with the row-major one, so both directions
+    see one value stream.  Checked sharded AND single-device against
     autodiff on the SAME converted batch (tight tolerance — same
     values, different reduction order)."""
     from photon_tpu.data.batch import batch_astype
 
     monkeypatch.setenv("PHOTON_ROUTE_CACHE", "0")
-    monkeypatch.setenv("PHOTON_XCHG_REDUCE", "cumsum")
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     batch = _batch(seed=17)
     obj = GlmObjective.create("logistic", RegularizationContext("l2", 0.3))
     rng = np.random.default_rng(18)
     w = jnp.asarray(rng.standard_normal(D).astype(np.float32) * 0.1)
 
-    fast = attach_feature_major(batch, aligned_dim=D)
-    assert fast.xchg is not None and fast.xchg.vals_dest is not None
-    fast16 = batch_astype(fast, jnp.bfloat16)
-    # The baked stream converts IN PLACE (elementwise casts commute with
-    # the static permutation), so the fused path survives bf16 storage.
-    assert fast16.xchg.vals_dest.dtype == jnp.bfloat16
+    fast16 = batch_astype(
+        attach_feature_major(batch, aligned_dim=D), jnp.bfloat16
+    )
+    assert fast16.al.vals.dtype == jnp.bfloat16
     v_x, g_x = obj.value_and_grad(w, fast16)
 
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "autodiff")
@@ -236,19 +221,16 @@ def test_bf16_storage_keeps_xchg_grad_consistent(monkeypatch):
         np.asarray(g_x), np.asarray(g_a), rtol=2e-4, atol=2e-4 * scale
     )
 
-    # Sharded: the STACKED baked stream converts in place the same way —
-    # assert the aux actually survived (shard_batch can drop it on route
-    # mismatch, which would let fallback kernels pass this vacuously)
-    # and that xchg is what dispatches.
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
+    # Sharded: the STACKED value stream converts the same way, and pallas
+    # is what dispatches.
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     mesh = create_mesh()
     sharded16 = batch_astype(
         shard_batch(batch, mesh, aligned_dim=D), jnp.bfloat16
     )
-    assert sharded16.xchg is not None
-    assert sharded16.xchg.vals_dest.dtype == jnp.bfloat16
+    assert sharded16.al.vals.dtype == jnp.bfloat16
     dist = DistributedGlmObjective(obj, mesh)
-    assert dist._sparse_kernel(w, sharded16) == "xchg"
+    assert dist._sparse_kernel(w, sharded16) == "pallas"
     v_d, g_d = dist.value_and_grad(w, sharded16)
     np.testing.assert_allclose(float(v_d), float(v_a), rtol=2e-5)
     np.testing.assert_allclose(

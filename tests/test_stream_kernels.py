@@ -1,5 +1,5 @@
 """Streamed fast-kernel layouts (VERDICT r5 item 3): chunks re-parsed
-per pass carry cached aligned/xchg aux, route to the fast kernels, and
+per pass carry cached aligned layouts, route to the fast kernels, and
 produce the same numbers as the plain autodiff streamed pass."""
 
 import os
@@ -46,14 +46,8 @@ def _streamed_vg(files, w):
     return float(v), np.asarray(g), source.dim
 
 
-@pytest.mark.parametrize("kernel,reduce_mode", [
-    ("fm", None),
-    ("pallas", None),
-    ("xchg", "cumsum"),
-    ("xchg", "aligned"),
-])
-def test_streamed_kernel_matches_autodiff(tmp_path, monkeypatch, kernel,
-                                          reduce_mode):
+@pytest.mark.parametrize("kernel", ["fm", "pallas"])
+def test_streamed_kernel_matches_autodiff(tmp_path, monkeypatch, kernel):
     files = _write_files(tmp_path)
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "autodiff")
     dim_probe = LibsvmFileSource(files, intercept=True).dim
@@ -64,8 +58,6 @@ def test_streamed_kernel_matches_autodiff(tmp_path, monkeypatch, kernel,
     v_ref, g_ref, _ = _streamed_vg(files, w)
 
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", kernel)
-    if reduce_mode is not None:
-        monkeypatch.setenv("PHOTON_XCHG_REDUCE", reduce_mode)
     monkeypatch.setenv(
         "PHOTON_STREAM_LAYOUT_CACHE", str(tmp_path / "cache")
     )
@@ -81,21 +73,20 @@ def test_stream_layout_cache_hit_skips_build(tmp_path, monkeypatch):
     import photon_tpu.data.stream_layouts as sl
 
     files = _write_files(tmp_path)
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
-    monkeypatch.setenv("PHOTON_XCHG_REDUCE", "cumsum")
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     monkeypatch.setenv(
         "PHOTON_STREAM_LAYOUT_CACHE", str(tmp_path / "cache")
     )
     dim_probe = LibsvmFileSource(files, intercept=True).dim
     w = jnp.zeros(dim_probe, jnp.float32)
     builds = []
-    real_build = sl._build_aux
+    real_build = sl._build_padded_layout
 
     def counting_build(*args, **kw):
         builds.append(1)
         return real_build(*args, **kw)
 
-    monkeypatch.setattr(sl, "_build_aux", counting_build)
+    monkeypatch.setattr(sl, "_build_padded_layout", counting_build)
     v1, g1, _ = _streamed_vg(files, w)
     assert len(builds) == len(files)  # one build per file, first pass
     v2, g2, _ = _streamed_vg(files, w)  # fresh source = restart
@@ -108,12 +99,14 @@ def test_stream_kernel_follows_forced_sparse_grad(monkeypatch):
     from photon_tpu.data.stream_layouts import stream_kernel
 
     monkeypatch.delenv("PHOTON_STREAM_KERNEL", raising=False)
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
-    assert stream_kernel() == "xchg"
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
+    assert stream_kernel() == "pallas"
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
     assert stream_kernel() == "autodiff"
-    monkeypatch.setenv("PHOTON_STREAM_KERNEL", "pallas")
-    assert stream_kernel() == "pallas"
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "blocked")  # no stream layout
+    assert stream_kernel() == "autodiff"
+    monkeypatch.setenv("PHOTON_STREAM_KERNEL", "fm")
+    assert stream_kernel() == "fm"
 
 
 def test_stream_cache_invalidated_by_file_change(tmp_path, monkeypatch):
@@ -122,28 +115,27 @@ def test_stream_cache_invalidated_by_file_change(tmp_path, monkeypatch):
     import photon_tpu.data.stream_layouts as sl
 
     files = _write_files(tmp_path, n_files=1, rows=32)
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
-    monkeypatch.setenv("PHOTON_XCHG_REDUCE", "cumsum")
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     monkeypatch.setenv(
         "PHOTON_STREAM_LAYOUT_CACHE", str(tmp_path / "cache")
     )
     dim_probe = LibsvmFileSource(files, intercept=True).dim
     w = jnp.zeros(dim_probe, jnp.float32)
     builds = []
-    real_build = sl._build_aux
+    real_build = sl._build_padded_layout
 
     def counting_build(*args, **kw):
         builds.append(1)
         return real_build(*args, **kw)
 
-    monkeypatch.setattr(sl, "_build_aux", counting_build)
+    monkeypatch.setattr(sl, "_build_padded_layout", counting_build)
     _streamed_vg(files, w)
     assert len(builds) == 1
     # Rewrite with different content (more rows -> different size).
     _write_files(tmp_path, n_files=1, rows=48, seed=9)
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "autodiff")
     dim2 = LibsvmFileSource(files, intercept=True).dim
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     v_new, g_new, _ = _streamed_vg(files, jnp.zeros(dim2, jnp.float32))
     assert len(builds) == 2  # rebuilt for the new file identity
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "autodiff")

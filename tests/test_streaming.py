@@ -446,27 +446,26 @@ def test_make_global_batch_single_process():
 
 def test_make_global_batch_aligned_single_process(monkeypatch):
     """make_global_batch(aligned_dim=...) attaches per-local-shard
-    aligned/xchg aux (8 local devices here) and the sharded objective
+    aligned layouts (8 local devices here) and the sharded objective
     matches single-device autodiff — the single-process degenerate of
     the multi-process leg (tests/test_multiprocess.py part 1b)."""
     from photon_tpu.parallel import DistributedGlmObjective, create_mesh
 
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
-    monkeypatch.setenv("PHOTON_XCHG_REDUCE", "cumsum")
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     monkeypatch.setenv("PHOTON_ROUTE_CACHE", "0")
     batch = _sparse_data(n=64)
     mesh = create_mesh()
     global_batch = make_global_batch(batch, mesh, aligned_dim=64)
-    assert global_batch.xchg is not None
+    assert global_batch.al is not None
     obj = GlmObjective.create("logistic", RegularizationContext("l2", 0.4))
     w = jnp.asarray(
         np.random.default_rng(3).standard_normal(64).astype(np.float32) * 0.1
     )
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "autodiff")
     v_ref, g_ref = obj.value_and_grad(w, batch)
-    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "xchg")
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
     dist = DistributedGlmObjective(obj, mesh)
-    assert dist._sparse_kernel(w, global_batch) == "xchg"
+    assert dist._sparse_kernel(w, global_batch) == "pallas"
     v, g = dist.value_and_grad(w, global_batch)
     np.testing.assert_allclose(float(v), float(v_ref), rtol=2e-5)
     np.testing.assert_allclose(
