@@ -321,8 +321,12 @@ class GlmObjective:
         """Gradient via a kernel jax.jvp can differentiate THROUGH: the
         pallas and blocked kernels have no JVP rule (``pallas_call`` is not
         differentiable), so callers that re-differentiate the gradient
-        (normalized Hv below) route it to the fm layout — always built
-        alongside theirs — or plain autodiff."""
+        (normalized Hv below) route it to the fm layout where the batch
+        carries one (a pinned or sharded attach builds it beside theirs),
+        else to plain autodiff over the row-major entries: what a batch
+        attached after the probe's verdict runs, since it carries the
+        winner's layout alone — exact either way, and on the v5e the faster
+        of the two (ops/KERNEL_NOTES.md "Selection defaults")."""
         kernel = self._sparse_kernel(batch, int(w.shape[0]))
         if kernel is not None and not differentiable(kernel):
             kernel = "fm" if batch.fm is not None else None

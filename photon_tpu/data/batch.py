@@ -89,9 +89,12 @@ class SparseBatch(NamedTuple):
 
     ``fm`` optionally carries the static feature-major entry layout
     (:class:`FeatureMajorAux`, built by :func:`attach_feature_major`); when
-    present, objectives compute gradients via a pre-sorted segment sum
+    present, objectives can compute gradients via a pre-sorted segment sum
     instead of an unsorted scatter — see
-    :meth:`photon_tpu.core.objective.GlmObjective.value_and_grad`.
+    :meth:`photon_tpu.core.objective.GlmObjective.value_and_grad`.  Which
+    of the four optional layouts an attach builds is
+    :func:`attach_feature_major`'s decision (where a probe picks the kernel:
+    the winner's alone).
     """
 
     ids: Array  # [n, k] int32
@@ -113,7 +116,8 @@ class SparseBatch(NamedTuple):
     # (ops/block_tiles.BlockTiles) for the `blocked` kernel: margins,
     # gradient and Hv with both random accesses inside VMEM.  Built by
     # ``attach_feature_major(..., aligned_dim=d)`` on single-block batches
-    # when ``PHOTON_SPARSE_GRAD`` is ``auto`` or ``blocked``.
+    # when ``PHOTON_SPARSE_GRAD`` is ``blocked``, or ``auto`` and the probe
+    # picks that kernel.
     bt: Optional["object"] = None
 
     @property
@@ -227,49 +231,59 @@ def attach_feature_major(
     aligned_forward: bool | None = None,
     geometry_gather=None,
 ) -> SparseBatch:
-    """Attach the static feature-major layout (:class:`FeatureMajorAux`).
+    """Attach the static layouts the sparse-gradient kernels read.
 
-    Host-side: one stable argsort of the flat entries per row block — run
-    once per dataset, amortized over every optimizer iteration (the runtime
-    win is deleting the per-evaluation device sort inside XLA's scatter
-    lowering; see FeatureMajorAux).  ``shards`` must match the mesh data-axis
-    size the batch will be sharded over (1 for single-device use); rows are
-    split into ``shards`` contiguous blocks, mirroring
+    ``attach_feature_major(batch)`` attaches the feature-major layout
+    (:class:`FeatureMajorAux`, ``batch.fm``).  Host-side: one stable argsort
+    of the flat entries per row block — run once per dataset, amortized over
+    every optimizer iteration (the runtime win is deleting the
+    per-evaluation device sort inside XLA's scatter lowering; see
+    FeatureMajorAux).  ``shards`` must match the mesh data-axis size the
+    batch will be sharded over (1 for single-device use); rows are split
+    into ``shards`` contiguous blocks, mirroring
     :func:`photon_tpu.parallel.mesh.shard_batch` placement.
 
-    With ``aligned_dim`` (the coefficient dimension) the slab-aligned layout
-    for the Pallas gradient kernel is ALSO built and attached (``batch.al``),
-    making the batch eligible for the third kernel of
-    ops/sparse_grad_select.  With ``shards > 1`` every row block gets its
-    OWN layout (block-local rows) and the per-block layouts are padded to
-    a common geometry and stacked on a leading shard axis, so sharding
-    the batch on that axis hands each device exactly its block's layout
-    (VERDICT r5 item 2 — the fast kernels must run under the sharded
-    objective; squeeze + dispatch happen in parallel/distributed.py).
+    With ``aligned_dim`` (the coefficient dimension; callers pass it
+    unconditionally) every kernel of ops/sparse_grad_select is on the
+    table, and which layouts are built is decided HERE:
 
-    Callers pass the dimension unconditionally: which of those layouts are
-    built is decided HERE, by ``sparse_grad_select.layouts_wanted`` on the
-    batch's entry count (a kernel that reads the layout is pinned, or could
-    win auto-selection on this backend), so CPU runs never pay for layouts
-    the selector cannot route to.  The exception is a ``geometry_gather``
-    caller (a multi-process assembly): every process must take the same
-    branch around the gather's collectives, so that caller decides on
-    globally agreed inputs and passes ``aligned_dim`` or None.
+    - A single-block batch (``shards == 1``, no ``geometry_gather``) in auto
+      mode at or above the probe floor takes the selector's VERDICT FIRST
+      (``sparse_grad_select.kernel_for_shape``: the probe measures every
+      kernel that could be built for this shape, on a problem of its own)
+      and builds the layout the winner reads, and nothing else:
+      ``blocked`` -> ``bt`` (the row-block x feature-block tiles of
+      ops/block_tiles.py), ``pallas`` -> ``al`` (the slab-aligned layout,
+      through its disk cache; and ``al_t`` under ``aligned_forward``),
+      ``fm`` -> ``fm``, ``autodiff`` -> no layout at all.  Each build so
+      spared counts ``layout.skipped{layout}``.  On a TPU that is the tiles
+      alone: no sort, no bin-packing.  The trace-time selection finds the
+      same verdict cached and does not measure again.
+    - Under a pin, or under the probe floor, no measurement decides: ``fm``
+      is built, and beside it what ``sparse_grad_select.layouts_wanted``
+      names on the batch's entry count (the pinned kernel's layout; nothing
+      under the floor), so CPU runs never pay for layouts the selector
+      cannot route to.  A batch whose grid of blocks outgrows the
+      ``blocked`` kernel's tile table goes without the tiles in either case,
+      loudly (``kernels.refused{kernel=blocked}``), and selection goes on
+      among the other kernels.
+    - With ``shards > 1`` ``fm`` is built, and every row block gets its OWN
+      aligned layout (block-local rows) whenever any layout is wanted; the
+      per-block layouts are padded to a common geometry and stacked on a
+      leading shard axis, so sharding the batch on that axis hands each
+      device exactly its block's layout (VERDICT r5 item 2 — the fast
+      kernels must run under the sharded objective; squeeze + dispatch
+      happen in parallel/distributed.py).  Tiles are single-block only.
+    - A ``geometry_gather`` caller (a multi-process assembly) gets the same
+      stacked form: every process must take the same branch around the
+      gather's collectives, so that caller decides on globally agreed inputs
+      and passes ``aligned_dim`` or None.
 
     ``aligned_forward`` additionally builds the transposed (row-dictionary)
     layout so the Pallas path computes MARGINS through the same kernel
     (``batch.al_t``) — costs a second layout's host build and device
     memory, so it defaults to the ``PHOTON_SPARSE_MARGIN=pallas`` env
     opt-in.
-
-    A single-block batch (``shards == 1``, no ``geometry_gather``) given
-    ``aligned_dim`` also gets the row-block x feature-block entry tiles of
-    the ``blocked`` kernel (``batch.bt``, ops/block_tiles.py) when
-    ``sparse_grad_select.layouts_wanted`` says that kernel can be selected
-    (and, when it is the only one that can, no aligned layout).  A batch
-    whose grid of blocks outgrows the kernel's tile table goes without the
-    tiles, loudly (``kernels.refused{kernel=blocked}``), and selection goes
-    on among the other kernels.
     """
     if not isinstance(batch, SparseBatch) or batch.ids.ndim != 2:
         raise ValueError("feature-major layout requires a 2-D SparseBatch")
@@ -279,49 +293,47 @@ def attach_feature_major(
     n, k = batch.ids.shape
     if n % shards:
         raise ValueError(f"rows ({n}) not divisible by shards ({shards}); pad first")
-    ns = n // shards
-    # The host argsort and the reorder gathers of the flat entries (plus
-    # the fetch of ids/vals when the batch is already on the device).
-    with telemetry.span("layout.feature_major", entries=n * k, shards=shards):
-        ids = np.asarray(batch.ids).reshape(shards, ns * k)
-        vals = np.asarray(batch.vals).reshape(shards, ns * k)
-        rows = np.broadcast_to(
-            np.repeat(np.arange(ns, dtype=np.int32), k), (shards, ns * k)
-        )
-        order = np.argsort(ids, axis=1, kind="stable")
-        take = np.take_along_axis
-        fm = FeatureMajorAux(
-            ids=jnp.asarray(take(ids, order, axis=1)),
-            rows=jnp.asarray(take(rows, order, axis=1)),
-            vals=jnp.asarray(take(vals, order, axis=1)),
-        )
-    count_h2d("feature_major", fm)
-    batch = batch._replace(fm=fm)
     if aligned_forward and aligned_dim is None:
         raise ValueError(
             "aligned_forward requires aligned_dim (the transposed layout "
             "only serves the pallas kernel, which needs the aligned "
             "gradient layout too)"
         )
-    if aligned_dim is None:
-        return batch
-    from photon_tpu.ops.pallas_gather import (
-        device_layout,
-        layout_content_hash,
-        load_or_build_aligned_layout,
-    )
-    from photon_tpu.ops.sparse_grad_select import (
-        aligned_layout_wanted,
-        layouts_wanted,
-    )
-
-    ids_np = np.asarray(batch.ids)
-    vals_np = np.asarray(batch.vals, np.float32)
     if aligned_forward is None:
         aligned_forward = (
             os.environ.get("PHOTON_SPARSE_MARGIN", "xla") == "pallas"
         )
-    if shards != 1 or geometry_gather is not None:
+    single_block = shards == 1 and geometry_gather is None
+    build = {"fm"}
+    if aligned_dim is not None and single_block:
+        build = _single_block_layouts(n, k, aligned_dim, aligned_forward)
+    ns = n // shards
+    if "fm" in build:
+        # The host argsort and the reorder gathers of the flat entries
+        # (plus the fetch of ids/vals when the batch is already on the
+        # device).
+        with telemetry.span(
+            "layout.feature_major", entries=n * k, shards=shards
+        ):
+            ids = np.asarray(batch.ids).reshape(shards, ns * k)
+            vals = np.asarray(batch.vals).reshape(shards, ns * k)
+            rows = np.broadcast_to(
+                np.repeat(np.arange(ns, dtype=np.int32), k), (shards, ns * k)
+            )
+            order = np.argsort(ids, axis=1, kind="stable")
+            take = np.take_along_axis
+            fm = FeatureMajorAux(
+                ids=jnp.asarray(take(ids, order, axis=1)),
+                rows=jnp.asarray(take(rows, order, axis=1)),
+                vals=jnp.asarray(take(vals, order, axis=1)),
+            )
+        count_h2d("feature_major", fm)
+        batch = batch._replace(fm=fm)
+    if aligned_dim is None or (single_block and build <= {"fm"}):
+        return batch
+    ids_np = np.asarray(batch.ids)
+    vals_np = np.asarray(batch.vals, np.float32)
+    if not single_block:
         # A geometry gather forces the STACKED form even for one local
         # shard: a multi-process assembly needs every process's aux to
         # carry the leading shard axis (and to agree on the
@@ -330,33 +342,34 @@ def attach_feature_major(
         # single-block only: a sharded batch gets the aligned layouts
         # whenever any layout is wanted (a ``blocked`` pin then runs the
         # nearest kernel the batch carries).
+        from photon_tpu.ops.sparse_grad_select import aligned_layout_wanted
+
         if geometry_gather is None and not aligned_layout_wanted(n * k):
             return batch
         return _attach_aligned_sharded(
             batch, ids_np, vals_np, aligned_dim, shards,
             bool(aligned_forward), geometry_gather,
         )
-    want_aligned, want_tiles = layouts_wanted(n * k)
-    if want_tiles:
-        from photon_tpu.ops import block_tiles
-        from photon_tpu.utils.device import record_kernel_refusal
+    if "bt" in build:
+        from photon_tpu.ops.block_tiles import attach_block_tiles
 
-        if block_tiles.block_tile_geometry(n, aligned_dim, n * k) is None:
-            record_kernel_refusal(
-                "blocked", ValueError(block_tiles.untileable(n, aligned_dim))
-            )
-        else:
-            batch = batch._replace(bt=block_tiles.attach_block_tiles(
-                ids_np, vals_np, aligned_dim
-            ))
-    if not want_aligned:
+        batch = batch._replace(
+            bt=attach_block_tiles(ids_np, vals_np, aligned_dim)
+        )
+    if "al" not in build:
         return batch
+    from photon_tpu.ops.pallas_gather import (
+        device_layout,
+        layout_content_hash,
+        load_or_build_aligned_layout,
+    )
+
     with telemetry.span("layout.cache_key"):
         base_hash = layout_content_hash(ids_np, vals_np)
     batch = batch._replace(al=device_layout(load_or_build_aligned_layout(
         ids_np, vals_np, aligned_dim, base_hash=base_hash
     )))
-    if aligned_forward:
+    if "al_t" in build:
         batch = batch._replace(al_t=device_layout(
             load_or_build_aligned_layout(
                 ids_np, vals_np, aligned_dim, transposed=True,
@@ -364,6 +377,45 @@ def attach_feature_major(
             )
         ))
     return batch
+
+
+def _single_block_layouts(
+    n: int, k: int, dim: int, aligned_forward: bool
+) -> set:
+    """The fields of :data:`LAYOUT_FIELDS` a single-block ``[n, k]`` attach
+    builds.  Without a measurement (a pin, the probe floor): ``fm`` and what
+    ``layouts_wanted`` names.  When the probe decides, its verdict is taken
+    now and only the layout the winner reads is kept; every other build is
+    counted as spared (``layout.skipped{layout}``)."""
+    from photon_tpu.ops import block_tiles
+    from photon_tpu.ops.sparse_grad_select import (
+        kernel_for_shape,
+        layouts_wanted,
+    )
+    from photon_tpu.utils.device import (
+        count_layout_skipped,
+        record_kernel_refusal,
+    )
+
+    want_aligned, want_tiles = layouts_wanted(n * k)
+    if want_tiles and block_tiles.block_tile_geometry(n, dim, n * k) is None:
+        record_kernel_refusal(
+            "blocked", ValueError(block_tiles.untileable(n, dim))
+        )
+        want_tiles = False
+    build = {"fm"}
+    if want_aligned:
+        build |= {"al", "al_t"} if aligned_forward else {"al"}
+    if want_tiles:
+        build.add("bt")
+    verdict = kernel_for_shape(n, k, dim)
+    if verdict.probed:
+        # (``{None}`` for autodiff: no layout is kept.)
+        keep = {"al", "al_t"} if verdict.layout == "al" else {verdict.layout}
+        for field in sorted(build - keep):
+            count_layout_skipped(field)
+        build &= keep
+    return build
 
 
 def _attach_aligned_sharded(

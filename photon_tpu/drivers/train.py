@@ -453,10 +453,11 @@ def _run_resident(args: argparse.Namespace, logger, session,
         from photon_tpu.data.batch import SparseBatch, attach_feature_major
 
         if isinstance(batch, SparseBatch) and batch.ids.ndim == 2:
-            # Single-device: attach the pre-sorted layout so objectives take
-            # the segment-sum gradient path (exact under normalization too);
-            # the attach builds the fast kernels' layouts only when the
-            # selector could actually route to them.
+            # Single-device: the attach takes the kernel selector's verdict
+            # (the pin, the probe floor, else the probe, run there and
+            # cached for the trace) and builds the layout the winning
+            # kernel reads; without a measurement it builds the pre-sorted
+            # layout, and a pinned kernel's beside it.
             batch = attach_feature_major(batch, aligned_dim=dim)
 
     if args.dtype != "float32":

@@ -678,7 +678,8 @@ class FixedEffectDeviceData:
 
             # Single-device: the GAME fixed effect is the framework's big
             # sparse solve, so it gets the same fast-kernel eligibility as
-            # the legacy driver.
+            # the legacy driver (the selector's verdict first, then the
+            # winner's layout alone).
             self.batch = attach_feature_major(self.batch, aligned_dim=self.dim)
 
     def offsets_to_device(self, offsets) -> Array:

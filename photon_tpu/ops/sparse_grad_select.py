@@ -27,12 +27,18 @@ The production gradient has four lowerings auto mode chooses between
 
 Which wins is a hardware property — so, like the reference's BLAS
 dispatch, the choice is made by a one-time EAGER measurement on the live
-backend, cached per (platform, size bucket, candidate set).  Every
-candidate first passes :func:`check_kernel` — compiled on this device and
-compared against the NumPy reference — and a candidate the compiler
-refuses (or that fails parity) is excluded LOUDLY: a WARNING and a
-``kernels.refused{kernel=…}`` counter in the run report
-(utils/device.record_kernel_refusal), never a quiet switch of path.
+backend, cached per (platform, size bucket, candidate set).  The probe
+times a problem of its own, so it needs none of the batch's layouts:
+**verdict, then build**.  A single-block attach asks
+:func:`kernel_for_shape` BEFORE it builds (every kernel that could be
+built for the shape is a candidate) and builds the winner's layout alone;
+the trace-time :func:`select_kernel`, which asks among what the batch
+carries, finds that verdict cached.  Every candidate first passes
+:func:`check_kernel` — compiled on this device and compared against the
+NumPy reference — and a candidate the compiler refuses (or that fails
+parity) is excluded LOUDLY: a WARNING and a ``kernels.refused{kernel=…}``
+counter in the run report (utils/device.record_kernel_refusal), never a
+quiet switch of path.
 
 Override with ``PHOTON_SPARSE_GRAD=autodiff|fm|pallas|blocked|auto``
 (default auto); any other value raises at the first read
@@ -331,14 +337,11 @@ def _select(batch, dim: int) -> str:
         # A forced Mosaic kernel runs in interpret mode off the TPU (tests,
         # parity checks); it still needs its layout on the batch.
         return _nearest(pin, carried)
-    import jax
-
     n_rows, k = batch.ids.shape
-    e_total = n_rows * k
     # Probe floor: below ~1M entries the eager measurement costs more than
     # any kernel difference could repay (GAME runs hit MANY small shape
     # buckets — one probe each).
-    if e_total < _probe_floor():
+    if n_rows * k < _probe_floor():
         return "autodiff"
 
     candidates = tuple(
@@ -347,52 +350,112 @@ def _select(batch, dim: int) -> str:
     )
     if candidates == ("autodiff",):
         return "autodiff"  # single-candidate set: nothing to measure
-    key = (
-        jax.default_backend(), _bucket(e_total), _bucket(dim), candidates
-    )
-    if key not in _CACHE:
-        scale = max(1, -(-e_total // _probe_cap()))  # ceil: cap probe size
-        e = max(e_total // scale, 1 << 10)
-        n = max(n_rows // scale, 64)
-        # eval_context: this selection usually runs while an ENCLOSING jit
-        # (the optimizer's while_loop, a streamed chunk program) is being
-        # traced, and under omnistaging even jit calls on concrete inputs
-        # inline into the outer trace — the probe's host synchronizations
-        # would raise.  Stepping out to the eval trace executes the probe
-        # eagerly, so the cache holds a real measurement wherever the
-        # first call happens.  (NOT ensure_compile_time_eval: on jax 0.9
-        # that also constant-folds inside the Pallas kernel-body trace,
-        # where ``program_id`` has no evaluation rule — every pallas probe
-        # was refused that way on the chip, PR 21.)  A probe that fails
-        # outright raises: there is no default kernel to fall back to.
-        from photon_tpu import telemetry
+    return _probed(n_rows, k, dim, candidates)
 
-        with telemetry.span(
-            "kernels.probe", candidates=len(candidates), size=e
-        ), jax.core.eval_context():
-            _CACHE[key] = _measure(e, dim, n, candidates)
-        import logging
 
-        # Logged because auto-selection is a wall-clock measurement: on a
-        # machine near the kernel crossover two runs can pick different
-        # kernels, whose different reduction orders give slightly different
-        # float results.  Pin PHOTON_SPARSE_GRAD=fm|autodiff|pallas|blocked for
-        # bitwise same-seed reproducibility (SURVEY.md §5 determinism note).
-        logging.getLogger("photon_tpu.sparse_grad").info(
-            "sparse-grad kernel for backend=%s e~2^%d d~2^%d: %s",
-            key[0], key[1], key[2], _CACHE[key],
+def _probed(n_rows: int, k: int, dim: int, candidates: tuple) -> str:
+    """The fastest of ``candidates`` at this size on the live backend:
+    measured once a process per (backend, size bucket, dim bucket,
+    candidates) and cached.  A cached verdict over MORE candidates answers
+    too when its winner is among these: the attach asks about every kernel
+    it could build (:func:`kernel_for_shape`) and builds the winner's layout
+    alone, so the trace-time question over what the batch then carries is
+    already answered."""
+    import jax
+
+    e_total = n_rows * k
+    where = (jax.default_backend(), _bucket(e_total), _bucket(dim))
+    for key, winner in _CACHE.items():
+        if (
+            key[:3] == where and winner in candidates
+            and set(candidates) <= set(key[3])
+        ):
+            return winner
+    scale = max(1, -(-e_total // _probe_cap()))  # ceil: cap probe size
+    e = max(e_total // scale, 1 << 10)
+    n = max(n_rows // scale, 64)
+    # eval_context: at trace time this runs while an ENCLOSING jit (the
+    # optimizer's while_loop, a streamed chunk program) is being traced,
+    # and under omnistaging even jit calls on concrete inputs inline into
+    # the outer trace — the probe's host synchronizations would raise.
+    # Stepping out to the eval trace executes the probe eagerly, so the
+    # cache holds a real measurement wherever the first call happens.
+    # (NOT ensure_compile_time_eval: on jax 0.9 that also constant-folds
+    # inside the Pallas kernel-body trace, where ``program_id`` has no
+    # evaluation rule — every pallas probe was refused that way on the
+    # chip, PR 21.)  A probe that fails outright raises: there is no
+    # default kernel to fall back to.
+    from photon_tpu import telemetry
+
+    with telemetry.span(
+        "kernels.probe", candidates=len(candidates), size=e
+    ), jax.core.eval_context():
+        winner = _CACHE[where + (candidates,)] = _measure(
+            e, dim, n, candidates
         )
-    return _nearest(_CACHE[key], carried)
+    import logging
+
+    # Logged because auto-selection is a wall-clock measurement: on a
+    # machine near the kernel crossover two runs can pick different
+    # kernels, whose different reduction orders give slightly different
+    # float results.  Pin PHOTON_SPARSE_GRAD=fm|autodiff|pallas|blocked for
+    # bitwise same-seed reproducibility (SURVEY.md §5 determinism note).
+    logging.getLogger("photon_tpu.sparse_grad").info(
+        "sparse-grad kernel for backend=%s e~2^%d d~2^%d: %s",
+        *where, winner,
+    )
+    return winner
+
+
+class Verdict(NamedTuple):
+    kernel: str
+    probed: bool  # a measurement decided it, not a pin or the floor
+
+    @property
+    def layout(self) -> Optional[str]:
+        """The ``SparseBatch`` field the kernel's gradient reads (None:
+        ``autodiff`` reads the row-major entries)."""
+        return _KERNELS[self.kernel].layout
+
+
+def kernel_for_shape(n_rows: int, k: int, dim: int) -> Verdict:
+    """Which kernel will a single-block ``[n_rows, k]`` batch of dimension
+    ``dim`` run on this backend?  Asked by the attach BEFORE it builds
+    anything, so that it builds the winner's layout alone: the pin; under
+    the probe floor ``autodiff``; else the probe's verdict
+    (:func:`_probed`: the same measurement and the same cache as at trace
+    time) among the kernels whose layout could be built here — ``autodiff``
+    and ``fm`` anywhere, the Mosaic kernels where Mosaic compiles,
+    ``blocked`` only for a batch its tile table can hold."""
+    pin = pinned_kernel()
+    if pin is not None:
+        return Verdict(pin, False)
+    if n_rows * k < _probe_floor():
+        return Verdict("autodiff", False)
+    from photon_tpu.ops.block_tiles import block_tile_geometry
+
+    mosaic = _pallas_eligible()
+    candidates = tuple(
+        name for name, kernel in _KERNELS.items()
+        if (mosaic or not kernel.mosaic) and (
+            kernel.layout != "bt"
+            or block_tile_geometry(n_rows, dim, n_rows * k) is not None
+        )
+    )
+    return Verdict(_probed(n_rows, k, dim, candidates), True)
 
 
 def layouts_wanted(e_total: int | None = None) -> tuple[bool, bool]:
-    """``(aligned, block_tiles)``: which static layouts a batch builder
-    should pay the host-side construction of, besides the feature-major aux.
-    A layout is wanted when a kernel that reads it is forced, or could win
-    auto-selection on this backend (compiled Mosaic: a TPU), so CPU runs
-    never pay for a kernel auto mode will not pick.  Pass the entry count
-    when known: below the probe floor auto mode is guaranteed to run
-    autodiff, so a build would be pure wasted host time."""
+    """``(aligned, block_tiles)``: which static layouts COULD be wanted
+    here, besides the feature-major aux.  A layout is wanted when a kernel
+    that reads it is forced, or could win auto-selection on this backend
+    (compiled Mosaic: a TPU), so CPU runs never pay for a kernel auto mode
+    will not pick.  Pass the entry count when known: below the probe floor
+    auto mode is guaranteed to run autodiff, so a build would be pure
+    wasted host time.  Where no measurement decides (a pin, a sharded
+    attach) these are the layouts built; where the probe decides, the
+    single-block attach narrows them to the winner's
+    (:func:`kernel_for_shape`)."""
     pin = pinned_kernel()
     if pin is not None:
         wanted = (_KERNELS[pin].layout,)

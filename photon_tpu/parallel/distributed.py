@@ -206,7 +206,9 @@ class DistributedGlmObjective:
         """Gradient via a kernel jvp can differentiate THROUGH (the
         normalized-Hv path re-differentiates the gradient, and
         ``pallas_call`` has no JVP rule): pallas/blocked route to the fm
-        layout — always built alongside theirs — mirroring
+        layout where the batch carries one (every sharded attach builds
+        it; a one-device mesh's single-block attach only under a pin or
+        when fm wins the probe), else to plain autodiff — mirroring
         GlmObjective._differentiable_grad."""
         kernel = self._sparse_kernel(w, batch)
         if kernel is not None and not differentiable(kernel):

@@ -105,8 +105,10 @@ def _render_program_work_section(report: dict) -> list:
     total of the fits run through ``GlmOptimizationProblem.run``).  Layout
     bytes: what the layout build handed to the
     device (``layout.h2d_bytes{what}``) and moved through the layout cache
-    (``layout.cache_bytes{op}``).  Each table is absent when nothing was
-    recorded under its names."""
+    (``layout.cache_bytes{op}``), and the layouts it did not build because
+    the kernel verdict came first and another kernel won
+    (``layout.skipped{layout}``: a count, no bytes).  Each table is absent
+    when nothing was recorded under its names."""
     lines: list = []
     seconds = _counter_totals(report, "span.seconds", "span")
     counts = _counter_totals(report, "span.count", "span")
@@ -136,12 +138,18 @@ def _render_program_work_section(report: dict) -> list:
             )
     uploads = _counter_totals(report, "layout.h2d_bytes", "what")
     cache = _counter_totals(report, "layout.cache_bytes", "op")
-    if uploads or cache:
+    skipped = _counter_totals(report, "layout.skipped", "layout")
+    if uploads or cache or skipped:
         lines += ["", "## Layout bytes", "", "| what | MiB |", "|---|---|"]
         for (what,), b in sorted(uploads.items()):
             lines.append(f"| to device: {what} | {b / 2**20:.1f} |")
         for (op,), b in sorted(cache.items()):
             lines.append(f"| layout cache {op} | {b / 2**20:.1f} |")
+        for (layout,), n in sorted(skipped.items()):
+            lines.append(
+                f"| not built, another kernel won the probe: {layout} "
+                f"| none (x{int(n)}) |"
+            )
     return lines
 
 

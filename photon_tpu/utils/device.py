@@ -103,6 +103,15 @@ def count_h2d(what: str, tree) -> None:
     )
 
 
+def count_layout_skipped(layout: str) -> None:
+    """One build of ``layout`` (a ``SparseBatch`` field) spared because the
+    kernel verdict came first and another kernel won:
+    ``layout.skipped{layout}`` in the process registry."""
+    from photon_tpu.telemetry import process_registry
+
+    process_registry().counter("layout.skipped", layout=layout).inc()
+
+
 def record_kernel_refusal(kernel: str, exc: BaseException) -> str:
     """The compiler (or an on-device parity gate) refused ``kernel``: log
     it once per occurrence at WARNING and count it for the run report.

@@ -268,7 +268,9 @@ def test_tron_hessian_vector_matches_autodiff(monkeypatch, pattern, loss):
 
 def test_normalized_hessian_vector_differentiates_around_the_kernel(monkeypatch):
     """``pallas_call`` has no JVP rule: the normalized Hv re-differentiates
-    the gradient through autodiff (no fm aux here), not through the tiles."""
+    the gradient through autodiff, not through the tiles.  The batch carries
+    ``bt`` and no ``fm``: what an attach builds when the probe picks
+    ``blocked`` (the verdict comes first, no other layout is built)."""
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "blocked")
     batch = _batch("uniform", "logistic", seed=31)
     obj = _objective("logistic", True, batch)
@@ -276,7 +278,42 @@ def test_normalized_hessian_vector_differentiates_around_the_kernel(monkeypatch)
     w = jnp.asarray(rng.standard_normal(D).astype(np.float32) * 0.1)
     v = jnp.asarray(rng.standard_normal(D).astype(np.float32))
     hv_ref = jax.jvp(lambda u: jax.grad(obj.value)(u, batch), (w,), (v,))[1]
-    _close(obj.hessian_vector(w, v, _with_tiles(batch)), hv_ref, rel=1e-5)
+    fast = _with_tiles(batch)
+    assert fast.fm is None and obj._sparse_kernel(fast, D) == "blocked"
+    _close(obj.hessian_vector(w, v, fast), hv_ref, rel=1e-5)
+    _close(obj.hvp_operator(w, fast)(v), hv_ref, rel=1e-5)
+
+
+def test_normalized_tron_fit_on_a_tiles_only_batch(monkeypatch):
+    """TRON under a normalization on a batch that carries the tiles and no
+    ``fm``: value and gradient run ``blocked``, every Hv differentiates the
+    row-major objective, and the fit is the plain row-major fit.  Run to
+    convergence: where a truncated CG stops moves with the last bits of the
+    gradient (the ``fm`` route stands as far from ``autodiff`` after 2 or 3
+    iterations), the optimum does not."""
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
+
+    batch = _batch("uniform", "logistic", seed=33)
+    context = RegularizationContext("l2", 0.5)
+    problem = GlmOptimizationProblem(
+        _objective("logistic", True, batch),
+        ProblemConfig(
+            optimizer="tron", regularization=context,
+            optimizer_config=OptimizerConfig(max_iterations=15),
+        ),
+    )
+    w0 = jnp.zeros(D, jnp.float32)
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "autodiff")
+    want, ref = problem.run(batch, w0)
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "blocked")
+    jax.clear_caches()  # the pin is read when the loop is traced
+    fast = _with_tiles(batch)
+    assert fast.fm is None
+    got, result = problem.run(fast, w0)
+    assert int(result.iterations) == int(ref.iterations) < 15
+    np.testing.assert_allclose(float(result.value), float(ref.value), rtol=1e-5)
+    _close(got.means, want.means, rel=1e-4)
 
 
 # -- attach, storage dtype, padding -------------------------------------------
@@ -317,25 +354,46 @@ def test_attach_builds_the_tiles_only_when_the_kernel_can_be_selected(monkeypatc
     assert attach_feature_major(
         pad_batch(batch, 2504), shards=2, aligned_dim=D
     ).bt is None
-    # With auto selection eligible (a TPU) both layouts are built, above
-    # the probe floor: under it auto mode runs autodiff whatever is built.
+    # With auto selection eligible (a TPU) either layout could be wanted,
+    # above the probe floor (under it auto mode runs autodiff whatever is
+    # built): the attach takes the probe's verdict first and builds the
+    # winner's layout alone.
     monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
     monkeypatch.setattr(sel, "_pallas_eligible", lambda: True)
     assert sel.layouts_wanted(N * K) == (False, False)
     monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "0")
     assert sel.layouts_wanted(N * K) == (True, True)
-    both = attach_feature_major(batch, aligned_dim=D)
-    assert both.bt is not None and both.al is not None
+    monkeypatch.setattr(sel, "_measure", lambda e, d, n, names: "blocked")
+    monkeypatch.setattr(sel, "_CACHE", {})
+    telemetry.process_registry().clear()
+    only = attach_feature_major(batch, aligned_dim=D)
+    assert only.bt is not None
+    assert only.fm is None and only.al is None and only.al_t is None
+    assert _skipped() == {"fm": 1.0, "al": 1.0}
+    spans = {
+        row["labels"]["span"]
+        for row in telemetry.process_registry().snapshot()["counters"]
+        if row["name"] == "span.count"
+    }
+    assert spans == {"kernels.probe", "layout.block_tiles"}
 
 
-def _refusals():
+def _counts(name: str, label: str) -> dict:
     from photon_tpu.telemetry import process_registry
 
     return {
-        row["labels"]["kernel"]: row["value"]
+        row["labels"][label]: row["value"]
         for row in process_registry().snapshot()["counters"]
-        if row["name"] == "kernels.refused"
+        if row["name"] == name
     }
+
+
+def _refusals():
+    return _counts("kernels.refused", "kernel")
+
+
+def _skipped():
+    return _counts("layout.skipped", "layout")
 
 
 @pytest.mark.parametrize("mode", ["blocked", "auto"])
@@ -377,22 +435,124 @@ def test_a_batch_over_the_tile_table_goes_on_without_the_tiles(monkeypatch, mode
             return "fm"
 
         monkeypatch.setattr(sel, "_measure", measure)
-    saved = dict(sel._CACHE)
-    sel._CACHE.clear()
+    monkeypatch.setattr(sel, "_CACHE", {})
     telemetry.process_registry().clear()
-    try:
-        fast = attach_feature_major(batch, aligned_dim=D)
-        assert fast.bt is None and fast.fm is not None
-        assert (fast.al is not None) == (mode == "auto")
-        assert _refusals() == {"blocked": 1.0}
-        jax.clear_caches()  # the pin is read when the loop is traced
-        got, result = problem.run(fast, w0)
-    finally:
-        sel._CACHE.clear()
-        sel._CACHE.update(saved)
+    fast = attach_feature_major(batch, aligned_dim=D)
+    assert fast.bt is None and fast.fm is not None and fast.al is None
+    assert _refusals() == {"blocked": 1.0}
+    # auto: the probe ran in the attach, over the kernels that could still
+    # be built, and its verdict (fm) spared the aligned layout; the tiles
+    # were refused, not spared.
+    assert _skipped() == ({"al": 1.0} if mode == "auto" else {})
+    jax.clear_caches()  # the pin is read when the loop is traced
+    got, result = problem.run(fast, w0)
+    # ... and the traced fit found that verdict: one measurement in all.
     assert seen == ([("autodiff", "fm", "pallas")] if mode == "auto" else [])
     assert int(result.iterations) == int(ref.iterations)
     _close(got.means, want.means, rel=1e-4)
+
+
+VERDICT_LAYOUT = {"blocked": "bt", "pallas": "al", "fm": "fm", "autodiff": None}
+
+
+@pytest.mark.parametrize("verdict", list(VERDICT_LAYOUT))
+def test_attach_takes_the_verdict_first_and_builds_only_its_layout(
+    monkeypatch, verdict
+):
+    """As on a TPU in auto mode (Mosaic made eligible, the floor at 0, the
+    probe's timing stubbed): the attach asks for the verdict before it
+    builds, the batch carries exactly the layout the winner reads, every
+    other build is counted as spared, the traced fit asks again and is
+    answered from the cache (one measurement in all), and it is the
+    ``autodiff`` fit."""
+    import photon_tpu.ops.sparse_grad_select as sel
+    from photon_tpu import telemetry
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
+    from photon_tpu.data.batch import LAYOUT_FIELDS
+    from photon_tpu.utils.device import kernel_metrics
+
+    batch = _batch("uniform", "logistic", seed=101)
+    context = RegularizationContext("l2", 1.0)
+    problem = GlmOptimizationProblem(
+        GlmObjective.create("logistic_regression", context),
+        ProblemConfig(
+            optimizer="lbfgs", regularization=context,
+            optimizer_config=OptimizerConfig(max_iterations=3),
+        ),
+    )
+    w0 = jnp.zeros(D, jnp.float32)
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "autodiff")
+    want, ref = problem.run(batch, w0)
+
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
+    monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "0")
+    monkeypatch.setattr(sel, "_pallas_eligible", lambda: True)
+    seen = []
+
+    def measure(e, d, n, names):
+        seen.append(names)
+        return verdict
+
+    monkeypatch.setattr(sel, "_measure", measure)
+    monkeypatch.setattr(sel, "_CACHE", {})
+    telemetry.process_registry().clear()
+    fast = attach_feature_major(batch, aligned_dim=D)
+    assert seen == [sel.KERNELS]  # every kernel that could be built
+    carried = {f for f in LAYOUT_FIELDS if getattr(fast, f) is not None}
+    kept = VERDICT_LAYOUT[verdict]
+    assert carried == ({kept} if kept else set())
+    assert _skipped() == {f: 1.0 for f in ("fm", "al", "bt") if f != kept}
+    jax.clear_caches()  # the selection is made when the loop is traced
+    got, result = problem.run(fast, w0)
+    assert seen == [sel.KERNELS]  # the verdict was found, not measured again
+    assert set(_counts("kernels.selected", "kernel")) == (
+        {verdict} if kept else set()
+    )
+    assert any(row["name"] == "span.count"
+               and row["labels"] == {"span": "kernels.probe"}
+               and row["value"] == 1 for row in kernel_metrics())
+    assert int(result.iterations) == int(ref.iterations)
+    np.testing.assert_allclose(float(result.value), float(ref.value), rtol=1e-5)
+    _close(got.means, want.means, rel=1e-4)
+
+
+@pytest.mark.parametrize("case", ["pin", "floor", "shards", "no_dim"])
+def test_attach_without_a_measurement_builds_what_it_built(monkeypatch, case):
+    """No probe decides under a pin, under the floor, for a sharded attach or
+    for ``attach_feature_major(batch)`` (an explicit request for ``fm``):
+    nothing is measured, nothing is spared, and the batch carries ``fm`` and
+    what ``layouts_wanted`` names."""
+    import photon_tpu.ops.sparse_grad_select as sel
+    from photon_tpu import telemetry
+    from photon_tpu.data.batch import LAYOUT_FIELDS
+
+    monkeypatch.setattr(sel, "_pallas_eligible", lambda: True)  # as on a TPU
+    monkeypatch.setattr(
+        sel, "_measure", lambda *a, **kw: pytest.fail("nothing to measure")
+    )
+    monkeypatch.setattr(sel, "_CACHE", {})
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
+    monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "0")
+    batch = _batch("uniform", "logistic", seed=111)
+    kwargs, want = {"aligned_dim": D}, {"fm"}
+    if case == "pin":
+        monkeypatch.setenv("PHOTON_SPARSE_GRAD", "pallas")
+        monkeypatch.setenv("PHOTON_SPARSE_MARGIN", "pallas")
+        want = {"fm", "al", "al_t"}
+    elif case == "floor":
+        monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", str(N * K + 1))
+    elif case == "shards":
+        batch, kwargs = pad_batch(batch, 2504), {"aligned_dim": D, "shards": 2}
+        want = {"fm", "al"}
+    else:
+        kwargs = {}
+    telemetry.process_registry().clear()
+    fast = attach_feature_major(batch, **kwargs)
+    assert {f for f in LAYOUT_FIELDS if getattr(fast, f) is not None} == want
+    shards = kwargs.get("shards", 1)
+    assert fast.fm.ids.shape == (shards, batch.ids.size // shards)
+    assert not _skipped() and not sel._CACHE
 
 
 def test_a_mesh_of_one_device_runs_the_tiles(monkeypatch):
@@ -482,21 +642,30 @@ def test_blocked_runs_both_directions_and_is_probed_where_mosaic_compiles(
         return "blocked" if "blocked" in names else "autodiff"
 
     monkeypatch.setattr(sel, "_measure", measure)
-    saved = dict(sel._CACHE)
-    sel._CACHE.clear()
-    try:
-        pick = lambda b: sel.select_kernel(b, D)  # noqa: E731
-        assert pick(fast) == "autodiff"  # CPU
-        assert not seen
-        monkeypatch.setattr(sel, "_pallas_eligible", lambda: True)
-        assert pick(fast._replace(fm=with_fm.fm)) == "blocked"
-        assert pick(with_fm) == "autodiff"
-        assert seen == [("autodiff", "fm", "blocked"), ("autodiff", "fm")]
-        monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", str(1 << 20))
-        assert pick(fast._replace(fm=with_fm.fm)) == "autodiff"  # the floor
-    finally:
-        sel._CACHE.clear()
-        sel._CACHE.update(saved)
+    monkeypatch.setattr(sel, "_CACHE", {})
+    pick = lambda b: sel.select_kernel(b, D)  # noqa: E731
+    assert pick(fast) == "autodiff"  # CPU
+    assert not seen
+    monkeypatch.setattr(sel, "_pallas_eligible", lambda: True)
+    # The attach's question, before anything is built: every kernel whose
+    # layout could be built for this shape, measured once.
+    assert sel.kernel_for_shape(N, K, D) == ("blocked", True)
+    assert seen == [sel.KERNELS]
+    # The trace-time question is over what the batch carries: that verdict
+    # answers it when its winner is carried ...
+    assert pick(fast) == "blocked"
+    assert pick(fast._replace(fm=with_fm.fm)) == "blocked"
+    assert seen == [sel.KERNELS]
+    # ... and a batch that does not carry the winner is measured among what
+    # it does carry, as before.
+    assert pick(with_fm) == "autodiff"
+    assert seen == [sel.KERNELS, ("autodiff", "fm")]
+    monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", str(1 << 20))
+    assert pick(fast._replace(fm=with_fm.fm)) == "autodiff"  # the floor
+    assert sel.kernel_for_shape(N, K, D) == ("autodiff", False)
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "fm")
+    assert sel.kernel_for_shape(N, K, D) == ("fm", False)  # the pin
+    assert len(seen) == 2
 
 
 def test_probe_refuses_a_wrong_blocked_kernel_loudly(monkeypatch):

@@ -487,6 +487,54 @@ def test_probe_floor_skips_measurement_for_small_problems(monkeypatch):
     assert not sel._CACHE, "a failed probe must not cache a verdict"
 
 
+@pytest.mark.parametrize("winner", ["autodiff", "fm"])
+def test_cpu_attach_above_the_floor_probes_before_it_sorts(monkeypatch, winner):
+    """Off the TPU the candidates are autodiff and fm.  Above the probe floor
+    a single-block ``attach_feature_major(batch, aligned_dim=d)`` takes the
+    probe's verdict before the sort: the batch carries ``fm`` only if ``fm``
+    won, the spared sort is counted, and the selection at trace time finds
+    the verdict (one measurement, under its span)."""
+    import photon_tpu.ops.sparse_grad_select as sel
+    from photon_tpu.telemetry import process_registry
+
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "auto")
+    monkeypatch.setenv("PHOTON_SPARSE_PROBE_FLOOR", "512")
+    seen = []
+
+    def measure(e, d, n, names):
+        seen.append(names)
+        return winner
+
+    monkeypatch.setattr(sel, "_measure", measure)
+    monkeypatch.setattr(sel, "_CACHE", {})
+    process_registry().clear()
+    n, k, d = 256, 4, 64
+    batch = _random_batch(n, k, d, seed=76)
+    fast = attach_feature_major(batch, aligned_dim=d)
+    assert seen == [("autodiff", "fm")]
+    assert (fast.fm is not None) == (winner == "fm")
+    assert fast.al is None and fast.bt is None
+    rows = {
+        (r["name"], tuple(r["labels"].values())): r["value"]
+        for r in process_registry().snapshot()["counters"]
+    }
+    assert rows.get(("layout.skipped", ("fm",))) == (
+        None if winner == "fm" else 1
+    )
+    assert rows[("span.count", ("kernels.probe",))] == 1
+    assert (("span.count", ("layout.feature_major",)) in rows) == (
+        winner == "fm"
+    )
+    obj = GlmObjective.create("logistic")
+    assert obj._sparse_kernel(fast, d) == (None if winner == "autodiff" else "fm")
+    assert seen == [("autodiff", "fm")]
+    w = jnp.asarray(np.random.default_rng(77).standard_normal(d), jnp.float32)
+    v_ref, g_ref = jax.value_and_grad(obj.value)(w, batch)
+    v, g = obj.value_and_grad(w, fast)
+    np.testing.assert_allclose(float(v), float(v_ref), rtol=2e-5)
+    np.testing.assert_allclose(g, g_ref, rtol=2e-4, atol=2e-5)
+
+
 def test_aligned_layout_survives_astype_and_pad_strip(monkeypatch):
     """batch_astype converts al.vals in place; pad_batch strips al (it is
     row-structure-dependent) so shard_batch rebuilds per-shard fm only."""

@@ -701,6 +701,7 @@ def test_report_program_work_sections_from_counters():
     session.counter("optimizer.line_search_steps", coordinate="fixed").inc(13)
     session.counter("layout.h2d_bytes", what="aligned").inc(3 * 2**20)
     session.counter("layout.cache_bytes", op="write").inc(2**19)
+    session.counter("layout.skipped", layout="fm").inc()
     report = session.build_report()
     text = render_markdown(report)
     (row,) = [
@@ -713,6 +714,7 @@ def test_report_program_work_sections_from_counters():
     assert "| fixed | 2 | 13 | 19 | 13 |" in text
     assert "| to device: aligned | 3.0 |" in text
     assert "| layout cache write | 0.5 |" in text
+    assert "| not built, another kernel won the probe: fm | none (x1) |" in text
 
     plain = render_markdown({"driver": "t", "metrics": {"counters": []}})
     for heading in ("Spans by name", "Optimizer work", "Layout bytes"):
