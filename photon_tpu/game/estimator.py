@@ -297,15 +297,42 @@ class GameEstimator:
         fixed effect's rows (or the random effect's entity blocks) and how
         many DISTINCT slices they hold — equal to the mesh size when the
         data is really sharded, 1 slice when it sits replicated or on one
-        device whatever the mesh says."""
+        device whatever the mesh says.
+
+        ``placement.live_rows{coordinate, device}``: the live (weight > 0)
+        training rows each device's slice holds, from the host's copies:
+        the fixed effect's rows a shard less the mesh padding, each bin's
+        ``row_weight > 0`` over the device's slice of entities.  A pass
+        over host arrays, and this runs at every fit's start: taken once a
+        layout (again when its row count grows), never inside later fits."""
         from photon_tpu.game.coordinate import FixedEffectDeviceData
 
-        if isinstance(device_data, FixedEffectDeviceData):
+        fixed = isinstance(device_data, FixedEffectDeviceData)
+        if fixed:
             arrays = [device_data.batch.label]
         else:
             arrays = [b["label"] for b in device_data.device_buckets]
         if not arrays:
             return
+        layout = (id(device_data), device_data.unpadded_n if fixed
+                  else len(device_data.dataset.entity_idx_per_row))
+        counted = vars(self).setdefault("_live_rows_counted", {})
+        if counted.get(name) != layout:
+            counted[name] = layout
+            live: Dict[int, int] = {}
+            for i, arr in enumerate(arrays):
+                for s in arr.addressable_shards:
+                    if fixed:
+                        start, stop, _ = s.index[0].indices(arr.shape[0])
+                        rows = min(stop, device_data.unpadded_n) - start
+                    else:
+                        weight = device_data.buckets[i].row_weight
+                        rows = int((weight[s.index] > 0).sum())
+                    live[s.device.id] = live.get(s.device.id, 0) + max(rows, 0)
+            for device, rows in live.items():
+                self.telemetry.gauge(
+                    "placement.live_rows", coordinate=name, device=device
+                ).set(rows)
         devices = set()
         slices = []
         for arr in arrays:
