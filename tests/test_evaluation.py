@@ -13,7 +13,8 @@ from photon_tpu.evaluation.metrics import (
 
 
 def _auc_bruteforce(scores, labels, weights=None):
-    w = np.ones_like(scores) if weights is None else weights
+    """Pairwise count, float64 sums (the scores compare as given)."""
+    w = np.ones(len(scores)) if weights is None else np.float64(weights)
     num = den = 0.0
     for i in range(len(scores)):
         for j in range(len(scores)):
@@ -34,6 +35,67 @@ def test_auc_matches_bruteforce():
     labels = (rng.random(60) < 0.4).astype(np.float32)
     got = float(area_under_roc_curve(scores, labels))
     np.testing.assert_allclose(got, _auc_bruteforce(scores, labels), rtol=1e-5)
+
+
+def _auc_case(name):
+    """``(scores, labels, weights, want, tolerance)`` of one tie / padding /
+    size shape the sort-and-scan AUC has to get right."""
+    rng = np.random.default_rng(7)
+    f32 = np.float32
+    if name == "all_tied":
+        labels = (rng.random(50) < 0.4).astype(f32)
+        return np.full(50, 0.25, f32), labels, np.ones(50, f32), 0.5, 1e-6
+    if name == "single_class":
+        scores = rng.normal(size=33).astype(f32)
+        return scores, np.ones(33, f32), np.ones(33, f32), 0.5, 0.0
+    if name == "n1":
+        return np.zeros(1, f32), np.ones(1, f32), np.ones(1, f32), 0.5, 0.0
+    if name == "n2":
+        scores, labels = np.array([0.5, -0.5], f32), np.array([1, 0], f32)
+        return scores, labels, np.ones(2, f32), 1.0, 0.0
+    if name == "n2_tied":
+        scores, labels = np.array([0.5, 0.5], f32), np.array([1, 0], f32)
+        return scores, labels, np.ones(2, f32), 0.5, 0.0
+    if name == "signed_zeros":
+        # -0.0 == 0.0: one tie group, whatever order the sort leaves them in.
+        scores = np.array([-0.0, 0.0, 0.0, -0.0, 1.0, -1.0, 0.0, -0.0], f32)
+        labels = np.array([1, 0, 1, 0, 1, 0, 0, 1], f32)
+        weights = rng.uniform(0.5, 2.0, 8).astype(f32)
+    elif name == "three_levels":
+        scores = rng.integers(0, 3, 80).astype(f32) - 1.0
+        labels = (rng.random(80) < 0.5).astype(f32)
+        weights = rng.uniform(0.1, 3.0, 80).astype(f32)
+    elif name.startswith("padded_"):
+        # sharded_metric's padding: weight-0 rows that carry score 0 and
+        # label 0, among live rows whose scores straddle 0 (some exactly 0).
+        n, share = 96, int(name.split("_")[1]) / 100.0
+        scores = np.round(rng.normal(size=n), 1).astype(f32)
+        labels = (rng.random(n) < 0.5).astype(f32)
+        weights = rng.uniform(0.5, 2.0, n).astype(f32)
+        pad = rng.permutation(n)[: int(n * share)]
+        scores[pad], labels[pad], weights[pad] = 0.0, 0.0, 0.0
+    elif name == "continuous_2p20":
+        n = 2**20
+        scores = rng.normal(size=n).astype(f32)
+        labels = (rng.random(n) < 1 / (1 + np.exp(-scores))).astype(f32)
+        # No pairwise loop reaches this size: the float64 rank AUC that the
+        # benchmark's `correct` is decided on (unweighted).
+        from benchmarks.reference import game as reference
+
+        return scores, labels, np.ones(n, f32), reference.auc(scores, labels), 1e-6
+    else:
+        raise KeyError(name)
+    return scores, labels, weights, _auc_bruteforce(scores, labels, weights), 1e-6
+
+
+@pytest.mark.parametrize("case", [
+    "all_tied", "three_levels", "padded_10", "padded_30", "padded_50",
+    "single_class", "n1", "n2", "n2_tied", "signed_zeros", "continuous_2p20",
+])
+def test_auc_ties_padding_and_sizes(case):
+    scores, labels, weights, want, tol = _auc_case(case)
+    got = float(area_under_roc_curve(scores, labels, weights))
+    assert abs(got - want) <= tol, (case, got, want)
 
 
 def test_auc_weighted_and_padded():

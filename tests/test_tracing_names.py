@@ -592,6 +592,22 @@ def test_published_program_names():
     assert "residuals/update" in _scopes(ops, "score_table_update")
 
 
+def test_metric_auc_is_one_sort_and_no_gather_or_loop():
+    """The validation AUC sorts once, weights riding with their score, and
+    carries tie-group sums by scans: a binary search (a ``while`` around a
+    gather, 44 passes over the scores a call, 0.49 s of a 1.9 s GAME fit on
+    the v5e before PR 35) cannot come back unseen."""
+    from photon_tpu.evaluation import metrics
+
+    x = jnp.linspace(-1.0, 1.0, 4096)
+    lowered = metrics.area_under_roc_curve.lower(x, (x > 0) * 1.0, x * 0 + 1)
+    for text in (lowered.as_text(dialect="hlo"), lowered.compile().as_text()):
+        # The opcode of every instruction: "%x = <type, maybe a tuple> op(".
+        ops = re.findall(r"(?m)^\s*(?:ROOT )?\S+ = .*? ([a-z][\w\-]*)\(", text)
+        assert ops.count("sort") == 1, sorted(set(ops))
+        assert not {"gather", "while", "dynamic-slice", "scatter"} & set(ops)
+
+
 def test_descent_loop_gained_no_sanctioned_host_sync():
     sys.path.insert(0, os.path.join(REPO, "tools"))
     from check_host_sync import main
