@@ -60,6 +60,7 @@ from photon_tpu.game.model import (
     FixedEffectModel,
     RandomEffectModel,
     _shard_feats,
+    count_sparse_entries,
     shard_to_batch,
 )
 from photon_tpu.models.glm import Coefficients, model_for_task
@@ -1529,6 +1530,10 @@ class FixedEffectCoordinate:
         if model.shard_name != self.config.shard_name:
             return model.score(self.data)
         feats, dense = _scoring_feats(self)
+        count_sparse_entries(
+            getattr(self, "telemetry", NULL_SESSION),
+            getattr(self, "fault_name", self.config.shard_name), feats, dense,
+        )
         return model.margins_device(feats, dense)
 
 

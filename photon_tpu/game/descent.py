@@ -371,7 +371,7 @@ class CoordinateDescent:
                 # the same deterministic kernel an uninterrupted run
                 # used to fill these rows.
                 for name, model in models.items():
-                    val_engine.update(name, val_cache.score(model))
+                    val_engine.update(name, val_cache.score(model, name))
             best_model = GameModel(
                 dict(resume_state.best_models), self.task_type
             )
@@ -395,7 +395,9 @@ class CoordinateDescent:
                     # Seed the validation score table: locked coordinates
                     # are never re-scored again (their rows are reused every
                     # iteration — validation.score_reuse counts them).
-                    val_engine.update(name, val_cache.score(coord_model))
+                    val_engine.update(
+                        name, val_cache.score(coord_model, name)
+                    )
             # Kick the foreign-vocabulary warm-start key joins onto the io
             # pool NOW: the fixed effect usually trains first, and by the
             # time a random coordinate's train() needs its aligned table
@@ -517,7 +519,7 @@ class CoordinateDescent:
                     if val_engine is not None:
                         # Incremental re-score: ONLY the coordinate that
                         # just trained touches its validation score row.
-                        val_engine.update(name, val_cache.score(model))
+                        val_engine.update(name, val_cache.score(model, name))
                     trained += 1
                     if isinstance(info, DeferredSolveStats):
                         deferred[name] = info
@@ -635,7 +637,7 @@ class CoordinateDescent:
                             name, self._score(self.coordinates[name], prev)
                         )
                         if val_engine is not None:
-                            val_engine.update(name, val_cache.score(prev))
+                            val_engine.update(name, val_cache.score(prev, name))
                     else:
                         # No previous iterate: the coordinate leaves the
                         # composite entirely this iteration (zero rows ==

@@ -304,7 +304,8 @@ class GameEstimator:
         the fixed effect's rows a shard less the mesh padding, each bin's
         ``row_weight > 0`` over the device's slice of entities.  A pass
         over host arrays, and this runs at every fit's start: taken once a
-        layout (again when its row count grows), never inside later fits."""
+        layout (again when its row count grows), never inside later fits;
+        with it, for the fixed effect, ``fixed_effect.layout``."""
         from photon_tpu.game.coordinate import FixedEffectDeviceData
 
         fixed = isinstance(device_data, FixedEffectDeviceData)
@@ -319,6 +320,8 @@ class GameEstimator:
         counted = vars(self).setdefault("_live_rows_counted", {})
         if counted.get(name) != layout:
             counted[name] = layout
+            if fixed:
+                self._count_fixed_layout(name, device_data)
             live: Dict[int, int] = {}
             for i, arr in enumerate(arrays):
                 for s in arr.addressable_shards:
@@ -345,6 +348,28 @@ class GameEstimator:
         self.telemetry.gauge("placement.slices", coordinate=name).set(
             min(slices)
         )
+
+    def _count_fixed_layout(self, name: str, device_data) -> None:
+        """``fixed_effect.layout{coordinate, kind, kernel}``, once a layout:
+        whether the fixed effect's training batch is ``dense`` or ``sparse``
+        and, for a sparse one, the value+gradient kernel its fits will be
+        answered (``sparse_grad_select.carried_kernel``: the attach's
+        verdict where it asked first; told without a measurement).  On a
+        mesh the selection is each shard's own at trace time
+        (``per_shard``); a dense batch has none."""
+        from photon_tpu.data.batch import SparseBatch
+        from photon_tpu.ops.sparse_grad_select import carried_kernel
+
+        batch = device_data.batch
+        if not isinstance(batch, SparseBatch):
+            kind, kernel = "dense", "none"
+        elif self.mesh is not None:
+            kind, kernel = "sparse", "per_shard"
+        else:
+            kind, kernel = "sparse", carried_kernel(batch, device_data.dim)
+        self.telemetry.counter(
+            "fixed_effect.layout", coordinate=name, kind=kind, kernel=kernel
+        ).inc()
 
     # -- streamed (out-of-core) mode -----------------------------------------
     def _stream_plan(self):

@@ -68,7 +68,21 @@ def _fixed_margins(w: Array, feats, dense: bool) -> Array:
     if dense:
         return feats @ w
     ids, vals = feats
-    return jnp.sum(jnp.take(w, ids, axis=0) * vals, axis=-1)
+    with jax.named_scope("score_fixed/gather"):
+        gathered = jnp.take(w, ids, axis=0)
+    with jax.named_scope("score_fixed/reduce"):
+        return jnp.sum(gathered * vals, axis=-1)
+
+
+def count_sparse_entries(telemetry, coordinate: str, feats, dense: bool) -> None:
+    """``score.sparse_entries{coordinate}``: the padded-COO entries a sparse
+    fixed-effect score is about to read, added where the score is
+    dispatched, from the static shape of the ids (no device value is
+    touched).  A dense shard counts nothing."""
+    if not dense:
+        telemetry.counter(
+            "score.sparse_entries", coordinate=coordinate
+        ).inc(feats[0].size)
 
 
 @partial(jax.jit, static_argnames=("dense",))
@@ -442,11 +456,14 @@ class DeviceScoringCache:
             )
         return self._entity_codes[column]
 
-    def score(self, model) -> Array:
+    def score(self, model, coordinate: str = "") -> Array:
         """Device-resident margins of one coordinate model over the cached
-        (validation) rows — ``[n_pad]``, sharded, no host round-trip."""
+        (validation) rows — ``[n_pad]``, sharded, no host round-trip.
+        ``coordinate`` is the update-sequence name the model is scored
+        under (the label of ``score.sparse_entries``)."""
         if isinstance(model, FixedEffectModel):
             feats, dense = self.feats(model.shard_name)
+            count_sparse_entries(self.telemetry, coordinate, feats, dense)
             return model.margins_device(feats, dense)
         if isinstance(model, RandomEffectModel):
             entity_idx = self.entity_index(model.entity_column, model.keys)

@@ -327,7 +327,16 @@ def select_kernel(batch, dim: int) -> str:
     return choice
 
 
-def _select(batch, dim: int) -> str:
+def carried_kernel(batch, dim: int) -> str:
+    """The kernel :func:`select_kernel` will answer for ``batch``, told
+    without measuring (a pin, the probe floor, one candidate, a verdict the
+    probe has cached: the attach's own, when it asked first); ``unprobed``
+    where only a probe not yet run can say.  Nothing is recorded: this is
+    for a layout's label, not a selection."""
+    return _select(batch, dim, measure=False) or "unprobed"
+
+
+def _select(batch, dim: int, measure: bool = True) -> Optional[str]:
     carried = tuple(
         name for name, kernel in _KERNELS.items()
         if kernel.layout is None or getattr(batch, kernel.layout) is not None
@@ -350,17 +359,19 @@ def _select(batch, dim: int) -> str:
     )
     if candidates == ("autodiff",):
         return "autodiff"  # single-candidate set: nothing to measure
-    return _probed(n_rows, k, dim, candidates)
+    return _probed(n_rows, k, dim, candidates, measure)
 
 
-def _probed(n_rows: int, k: int, dim: int, candidates: tuple) -> str:
+def _probed(n_rows: int, k: int, dim: int, candidates: tuple,
+            measure: bool = True) -> Optional[str]:
     """The fastest of ``candidates`` at this size on the live backend:
     measured once a process per (backend, size bucket, dim bucket,
     candidates) and cached.  A cached verdict over MORE candidates answers
     too when its winner is among these: the attach asks about every kernel
     it could build (:func:`kernel_for_shape`) and builds the winner's layout
     alone, so the trace-time question over what the batch then carries is
-    already answered."""
+    already answered.  With ``measure`` off a question the cache cannot
+    answer gets None."""
     import jax
 
     e_total = n_rows * k
@@ -371,6 +382,8 @@ def _probed(n_rows: int, k: int, dim: int, candidates: tuple) -> str:
             and set(candidates) <= set(key[3])
         ):
             return winner
+    if not measure:
+        return None
     scale = max(1, -(-e_total // _probe_cap()))  # ceil: cap probe size
     e = max(e_total // scale, 1 << 10)
     n = max(n_rows // scale, 64)

@@ -423,6 +423,12 @@ def test_newton_iterations_count_every_descent_iteration():
     assert counters[
         ("optimizer.evaluations", (("coordinate", "fixed"),))
     ] >= counters[("optimizer.iterations", (("coordinate", "fixed"),))] + 2
+    # One layout, one count, whatever the fits: a dense fixed effect has no
+    # sparse kernel and scores no sparse entries.
+    assert counters[("fixed_effect.layout", (
+        ("coordinate", "fixed"), ("kernel", "none"), ("kind", "dense"),
+    ))] == 1
+    assert not [k for k in counters if k[0] == "score.sparse_entries"]
     # Closed span names, nested: estimator.fit > descent.iteration >
     # descent.coordinate; the iteration and the coordinate are attributes.
     spans = {sp.span_id: sp for sp in session.tracer.finished}
@@ -465,8 +471,8 @@ def _scopes(op_names: set, program: str) -> set:
         assert "jit(_unknown)" not in name and "<lambda>" not in name, name
         if f"jit({program})" in name:
             found |= set(re.findall(
-                r"((?:valuegrad|lbfgs|newton|fm|pallas|residuals|validation)"
-                r"/[a-z_]+)", name,
+                r"((?:valuegrad|lbfgs|newton|fm|pallas|residuals|validation"
+                r"|score_fixed)/[a-z_]+)", name,
             ))
     return found
 
@@ -590,6 +596,35 @@ def test_published_program_names():
     ))
     assert module == "HloModule jit_score_table_update"
     assert "residuals/update" in _scopes(ops, "score_table_update")
+
+
+def test_score_fixed_sparse_branch_carries_its_scopes_and_counts():
+    """A sparse fixed effect's score (PR 36): the gather of ``w`` at the ids
+    and the row sums carry scope names (the dense branch, one matrix
+    product, has none and keeps its program), and every dispatch adds the
+    entries it reads to ``score.sparse_entries{coordinate}`` from the ids'
+    shape."""
+    from photon_tpu.game import model
+
+    w = jnp.ones(32, jnp.float32)
+    ids = jnp.zeros((8, 4), jnp.int32)
+    vals = jnp.ones((8, 4), jnp.float32)
+    module, ops = _hlo(model._fixed_margins.lower(w, (ids, vals), dense=False))
+    assert module == "HloModule jit_score_fixed"
+    assert _scopes(ops, "score_fixed") == {
+        "score_fixed/gather", "score_fixed/reduce"}
+    assert any("score_fixed/gather" in n and "gather" in n.rsplit("/", 1)[-1]
+               for n in ops)
+    module, ops = _hlo(model._fixed_margins.lower(
+        w, jnp.ones((8, 32), jnp.float32), dense=True))
+    assert module == "HloModule jit_score_fixed"
+    assert _scopes(ops, "score_fixed") == set()
+    session = TelemetrySession("t")
+    model.count_sparse_entries(session, "fixed", (ids, vals), dense=False)
+    model.count_sparse_entries(session, "fixed", (ids, vals), dense=False)
+    model.count_sparse_entries(session, "fixed", vals, dense=True)
+    assert _counters(session.registry) == {
+        ("score.sparse_entries", (("coordinate", "fixed"),)): 64.0}
 
 
 def test_metric_auc_is_one_sort_and_no_gather_or_loop():
