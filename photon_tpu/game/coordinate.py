@@ -362,8 +362,12 @@ def _scoring_feats(coord) -> tuple:
     This cache is a SECOND device copy of the shard's features (the training
     copies live row-selected/bucketed in the batch structures and cannot
     serve full-row-order scoring) — a deliberate memory-for-transfers
-    trade.  Sharding it over the data axis (rows zero-padded to the mesh
-    multiple) keeps that trade to ONE extra copy across the whole mesh
+    trade.  One coordinate never asks for it: a sparse fixed effect whose
+    training batch is the whole shard in row order on one device and
+    carries block tiles scores from those tiles
+    (:meth:`FixedEffectCoordinate.score_device`), so its entries are
+    resident once.  Sharding it over the data axis (rows zero-padded to the
+    mesh multiple) keeps that trade to ONE extra copy across the whole mesh
     rather than the one-per-device the replicated cache used to cost.
     ``_score_cache_bytes`` makes the residency visible (the descent loop
     exports it as the ``residuals.scoring_cache_bytes`` gauge — global
@@ -656,7 +660,8 @@ class FixedEffectDeviceData:
             # (unchanged) padded shape, every program compiled against it
             # stays hot, and nothing recompiles.  Pad rows are inert in the
             # solve (the loss is weight-summed) and invisible to scoring
-            # (score paths read the shard, not the training batch).
+            # (score paths read the shard, or the batch's tiles cut to the
+            # true row count: a pad row holds no entry).
             shard, label, offset, weight = _pad_fixed_rows(
                 shard, label, offset, weight, row_capacity
             )
@@ -1523,18 +1528,34 @@ class FixedEffectCoordinate:
 
     def score_device(self, model: FixedEffectModel) -> Array:
         """Training-data margins as a device array (the residual engine's
-        scoring path); shard features upload once and stay cached.  A model
+        scoring path), from the training batch's block tiles where it
+        carries them and is the shard itself, else from shard features
+        uploaded once and cached (:func:`_scoring_feats`).  A model
         trained on a different feature shard (foreign warm start) scores
-        through its own host path — the cache holds this coordinate's
+        through its own host path — the device holds this coordinate's
         shard."""
         if model.shard_name != self.config.shard_name:
             return model.score(self.data)
-        feats, dense = _scoring_feats(self)
+        held = self.device_data
+        bt = getattr(held.batch, "bt", None)
+        rows = entries = None
+        if bt is not None and held.train_rows is None and self.mesh is None:
+            # The training batch is the whole shard in row order and carries
+            # the tiles its fits read: score from them, and never build the
+            # second copy of the entries.  Row-capacity pad rows hold no
+            # entry (value 0.0: dropped by the tile builder) and lie past
+            # the rows scored.
+            rows = _score_pad(self)
+            feats, dense = bt, False
+            entries = rows * held.batch.ids.shape[1]
+        else:
+            feats, dense = _scoring_feats(self)
         count_sparse_entries(
             getattr(self, "telemetry", NULL_SESSION),
             getattr(self, "fault_name", self.config.shard_name), feats, dense,
+            entries,
         )
-        return model.margins_device(feats, dense)
+        return model.margins_device(feats, dense, out_len=rows)
 
 
 class RandomEffectCoordinate:

@@ -539,10 +539,11 @@ class CoordinateDescent:
                     )
                     if cache_bytes:
                         # The device scoring path's cached feature/index
-                        # residency (a second, replicated copy of the shard
-                        # — see coordinate._scoring_feats): the memory side
-                        # of the transfer trade, next to the engine's
-                        # residuals.device_bytes.
+                        # residency (a second copy of the shard, sharded
+                        # over the mesh — see coordinate._scoring_feats;
+                        # none for a fixed effect scored from its batch's
+                        # tiles): the memory side of the transfer trade,
+                        # next to the engine's residuals.device_bytes.
                         telemetry.gauge(
                             "residuals.scoring_cache_bytes", coordinate=name
                         ).set(cache_bytes)

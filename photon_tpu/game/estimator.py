@@ -289,6 +289,16 @@ class GameEstimator:
             # warm-start transfer counters) lands in the run's session.
             coord.telemetry = self.telemetry
             self._record_placement(name, coord.device_data)
+        cache = self._validation_scoring_cache()
+        if cache is not None:
+            for coord in coords.values():
+                # A fixed effect whose training batch carries block tiles
+                # (the selector's verdict for its shard) scores its
+                # validation rows through tiles too: this estimator owns
+                # both layouts, so it tells the cache.
+                batch = getattr(coord.device_data, "batch", None)
+                if getattr(batch, "bt", None) is not None:
+                    cache.score_fixed_through_tiles(coord.config.shard_name)
         return coords
 
     def _record_placement(self, name: str, device_data) -> None:

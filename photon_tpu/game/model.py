@@ -63,10 +63,25 @@ def shard_to_batch(
     )
 
 
-@partial(named_jit, "score_fixed", static_argnames=("dense",))
-def _fixed_margins(w: Array, feats, dense: bool) -> Array:
+@partial(named_jit, "score_fixed", static_argnames=("dense", "out_len"))
+def _fixed_margins(
+    w: Array, feats, dense: bool, out_len: Optional[int] = None
+) -> Array:
+    """``X w`` a row, by the form of ``feats``: a dense ``[n, d]`` matrix, a
+    padded-COO ``(ids, vals)`` pair (an XLA gather of ``w`` at the ids), or
+    the shard's :class:`~photon_tpu.ops.block_tiles.BlockTiles` (the
+    ``blocked`` kernel's ``Xw``, both accesses in VMEM: the same float32
+    products, summed in another order), cut to ``out_len`` rows (the tiles
+    may hold row-capacity pad rows past them)."""
     if dense:
         return feats @ w
+    if not isinstance(feats, tuple):
+        from photon_tpu.ops.block_tiles import block_tiles_product
+
+        with jax.named_scope("score_fixed/blocked_xw"):
+            return block_tiles_product(
+                w, feats, feats.n_rows if out_len is None else out_len
+            )
     ids, vals = feats
     with jax.named_scope("score_fixed/gather"):
         gathered = jnp.take(w, ids, axis=0)
@@ -74,15 +89,29 @@ def _fixed_margins(w: Array, feats, dense: bool) -> Array:
         return jnp.sum(gathered * vals, axis=-1)
 
 
-def count_sparse_entries(telemetry, coordinate: str, feats, dense: bool) -> None:
-    """``score.sparse_entries{coordinate}``: the padded-COO entries a sparse
-    fixed-effect score is about to read, added where the score is
-    dispatched, from the static shape of the ids (no device value is
-    touched).  A dense shard counts nothing."""
-    if not dense:
-        telemetry.counter(
-            "score.sparse_entries", coordinate=coordinate
-        ).inc(feats[0].size)
+def count_sparse_entries(
+    telemetry, coordinate: str, feats, dense: bool,
+    entries: Optional[int] = None,
+) -> None:
+    """What a sparse fixed-effect score is about to do, added where the
+    score is dispatched, from static shapes (no device value is touched):
+    ``score.sparse_entries{coordinate}``, the padded-COO entries of the rows
+    scored, and ``score.fixed_dispatches{coordinate, kernel}``, one count by
+    the form of ``feats`` (``gather``: an ``(ids, vals)`` pair; ``blocked``:
+    tiles).  Tiles do not know the shard's entries a row (their slots carry
+    the layout's padding), so their holder gives ``entries`` (rows scored x
+    the shard's ``k``): the same number whichever kernel runs.  A dense
+    shard counts nothing."""
+    if dense:
+        return
+    tiled = not isinstance(feats, tuple)
+    telemetry.counter("score.sparse_entries", coordinate=coordinate).inc(
+        entries if tiled else feats[0].size
+    )
+    telemetry.counter(
+        "score.fixed_dispatches", coordinate=coordinate,
+        kernel="blocked" if tiled else "gather",
+    ).inc()
 
 
 @partial(jax.jit, static_argnames=("dense",))
@@ -173,10 +202,16 @@ class FixedEffectModel:
         feats, dense = _shard_feats(data.shard(self.shard_name))
         return to_host(_fixed_margins(self.coefficients.means, feats, dense))
 
-    def margins_device(self, feats, dense: bool) -> Array:
+    def margins_device(
+        self, feats, dense: bool, out_len: Optional[int] = None
+    ) -> Array:
         """Device-resident margins against pre-uploaded shard features —
-        the residual engine's scoring path (no host round-trip)."""
-        return _fixed_margins(jnp.asarray(self.coefficients.means), feats, dense)
+        the residual engine's scoring path (no host round-trip).  ``feats``
+        in any form :func:`_fixed_margins` takes; ``out_len`` goes with
+        tiles."""
+        return _fixed_margins(
+            jnp.asarray(self.coefficients.means), feats, dense, out_len=out_len
+        )
 
     def serving_weights(self, mesh=None) -> Array:
         """Device-resident coefficient vector for the online scoring
@@ -380,6 +415,10 @@ class DeviceScoringCache:
         self.n_pad = pad_to_multiple(self.n, mesh_shards(mesh))
         self.device_bytes = 0
         self._feats: Dict[str, tuple] = {}
+        # Sparse shards a fixed effect scores through block tiles: named by
+        # the estimator (score_fixed_through_tiles), built on first use.
+        self._tiled_shards: set = set()
+        self._fixed_tiles: Dict[str, object] = {}
         self._entity_codes: Dict[str, Array] = {}
         self._entity_idx: Dict[str, tuple] = {}
         self.label = self._put(np.asarray(data.label, np.float32))
@@ -395,11 +434,14 @@ class DeviceScoringCache:
         from photon_tpu.parallel.mesh import reshard_to_mesh
 
         dev = reshard_to_mesh(host, self.mesh, pad_value=pad_value)
+        self._count_upload(dev.nbytes)
+        return dev
+
+    def _count_upload(self, nbytes: int) -> None:
         self.telemetry.counter(
             "descent.host_transfer_bytes", direction="h2d", path="validation"
-        ).inc(dev.nbytes)
-        self.device_bytes += dev.nbytes
-        return dev
+        ).inc(nbytes)
+        self.device_bytes += nbytes
 
     def feats(self, shard_name: str) -> tuple:
         """Shard ``shard_name``'s features as padded, sharded device leaves
@@ -414,6 +456,53 @@ class DeviceScoringCache:
                 dev = (self._put(leaves[0]), self._put(leaves[1]))
             self._feats[shard_name] = (dev, dense)
         return self._feats[shard_name]
+
+    def score_fixed_through_tiles(self, shard_name: str) -> None:
+        """Told by the cache's owner (the estimator, which owns the
+        coordinates' training layouts too) that the fixed effect on
+        ``shard_name`` trains on a batch that carries block tiles: the
+        selector judged ``blocked`` for this shard, so the validation rows
+        are scored through tiles of their own.  The cache asks no selector
+        and runs no probe."""
+        if shard_name not in self._fixed_tiles:
+            self._tiled_shards.add(shard_name)
+
+    def _fixed_feats(self, shard_name: str) -> tuple:
+        """``(feats, dense, entries)`` a fixed effect scores ``shard_name``
+        from: the shard's block tiles, built and uploaded once IN PLACE of
+        its ``(ids, vals)``, where the owner named the shard, it is sparse,
+        its rows are one block on one device and their grid can be tiled;
+        else :meth:`feats`.  ``entries`` is the shard's padded-COO entry
+        count either way (``score.sparse_entries``)."""
+        tiles = self._fixed_tiles.get(shard_name)
+        if tiles is None and shard_name in self._tiled_shards:
+            # Decided once a naming: built, or the name is dropped.
+            self._tiled_shards.discard(shard_name)
+            tiles = self._build_fixed_tiles(shard_name)
+            if tiles is not None:
+                self._fixed_tiles[shard_name] = tiles
+        if tiles is None:
+            return self.feats(shard_name) + (None,)
+        return tiles, False, self.n * self.data.shard(shard_name).ids.shape[1]
+
+    def _build_fixed_tiles(self, shard_name: str):
+        """The shard's rows as block tiles on the device, or ``None`` where
+        :meth:`_fixed_feats` says they are not to be."""
+        from photon_tpu.ops.block_tiles import (
+            attach_block_tiles,
+            block_tile_geometry,
+        )
+
+        shard = self.data.shard(shard_name)
+        if (self.mesh is not None or not isinstance(shard, SparseShard)
+                or block_tile_geometry(
+                    self.n, shard.dim, shard.ids.size) is None):
+            return None
+        tiles = attach_block_tiles(shard.ids, shard.vals, shard.dim)
+        self._count_upload(
+            sum(leaf.nbytes for leaf in jax.tree.leaves(tiles))
+        )
+        return tiles
 
     def entity_index(self, column: str, keys: np.ndarray) -> Array:
         """Per-row entity index of ``column`` against ``keys`` (``[n_pad]``
@@ -462,8 +551,10 @@ class DeviceScoringCache:
         ``coordinate`` is the update-sequence name the model is scored
         under (the label of ``score.sparse_entries``)."""
         if isinstance(model, FixedEffectModel):
-            feats, dense = self.feats(model.shard_name)
-            count_sparse_entries(self.telemetry, coordinate, feats, dense)
+            feats, dense, entries = self._fixed_feats(model.shard_name)
+            count_sparse_entries(
+                self.telemetry, coordinate, feats, dense, entries
+            )
             return model.margins_device(feats, dense)
         if isinstance(model, RandomEffectModel):
             entity_idx = self.entity_index(model.entity_column, model.keys)

@@ -107,8 +107,13 @@ def _render_program_work_section(report: dict) -> list:
     device (``layout.h2d_bytes{what}``) and moved through the layout cache
     (``layout.cache_bytes{op}``), and the layouts it did not build because
     the kernel verdict came first and another kernel won
-    (``layout.skipped{layout}``: a count, no bytes).  Each table is absent
-    when nothing was recorded under its names."""
+    (``layout.skipped{layout}``: a count, no bytes).  Fixed effect: each
+    fixed coordinate's training layout (``fixed_effect.layout``: kind and
+    the value+gradient kernel told for it), and for a sparse one the scores
+    dispatched by the form that ran them (``score.fixed_dispatches{kernel}``:
+    ``blocked`` from the shard's tiles, ``gather`` from its ``(ids, vals)``)
+    beside the padded-COO entries they read (``score.sparse_entries``).
+    Each table is absent when nothing was recorded under its names."""
     lines: list = []
     seconds = _counter_totals(report, "span.seconds", "span")
     counts = _counter_totals(report, "span.count", "span")
@@ -135,6 +140,25 @@ def _render_program_work_section(report: dict) -> list:
                 f"| {key[0]} | " + " | ".join(
                     _fmt(work[column].get(key)) for column in work
                 ) + " |"
+            )
+    layouts = _counter_totals(
+        report, "fixed_effect.layout", "coordinate", "kind", "kernel")
+    dispatches = _counter_totals(
+        report, "score.fixed_dispatches", "coordinate", "kernel")
+    entries = _counter_totals(report, "score.sparse_entries", "coordinate")
+    if layouts or dispatches:
+        lines += ["", "## Fixed effect", "",
+                  "| coordinate | what | count |", "|---|---|---|"]
+        for (coord, kind, kernel), n in sorted(layouts.items()):
+            lines.append(
+                f"| {coord} | layout: {kind}, value+gradient kernel {kernel} "
+                f"| {int(n)} |"
+            )
+        for (coord, kernel), n in sorted(dispatches.items()):
+            lines.append(f"| {coord} | scores by {kernel} | {int(n)} |")
+        for (coord,), n in sorted(entries.items()):
+            lines.append(
+                f"| {coord} | sparse entries those scores read | {int(n)} |"
             )
     uploads = _counter_totals(report, "layout.h2d_bytes", "what")
     cache = _counter_totals(report, "layout.cache_bytes", "op")

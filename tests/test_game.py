@@ -562,3 +562,177 @@ def test_fixed_effect_pallas_kernel_on_sparse_shard(monkeypatch):
     np.testing.assert_allclose(
         results["pallas"][1], results["fm"][1], rtol=1e-3, atol=1e-4
     )
+
+
+# ---------------------------------------------------------------------------
+# A sparse fixed effect scored through its block tiles (PR 37)
+# ---------------------------------------------------------------------------
+
+
+def _sparse_fixed_game(seed, n_entities, d=300, k=6):
+    """A GAME data set whose ``global`` shard is sparse, with what a tiled
+    score has to get right: an id twice in a row, explicit zeros."""
+    from photon_tpu.game.data import SparseShard
+
+    raw = make_game_data(
+        n_entities=n_entities, rows_per_entity_mean=6, fixed_dim=5,
+        random_dim=3, seed=seed,
+    )
+    n = len(raw["label"])
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, d, size=(n, k)).astype(np.int32)
+    ids[:, 1] = ids[:, 0]
+    vals = rng.standard_normal((n, k)).astype(np.float32)
+    vals[:, 2] = 0.0
+    return GameDataset.create(
+        label=raw["label"],
+        shards={
+            "global": SparseShard(ids, vals, d),
+            "per_entity": DenseShard(raw["x_random"]["re0"]),
+        },
+        id_columns={"userId": raw["entity_ids"]["re0"]},
+        weight=raw["weight"],
+    )
+
+
+def _gather_margins(data, model):
+    from photon_tpu.game.model import _fixed_margins
+
+    shard = data.shard("global")
+    return np.asarray(_fixed_margins(
+        jnp.asarray(model.coefficients.means),
+        (jnp.asarray(shard.ids), jnp.asarray(shard.vals)), dense=False,
+    ))
+
+
+def _score_counts(session):
+    return {
+        (c["name"], c["labels"].get("kernel")): c["value"]
+        for c in session.registry.snapshot()["counters"]
+        if c["name"].startswith("score.")
+    }
+
+
+def test_game_fit_scores_a_tiled_sparse_fixed_effect_through_its_tiles(
+    monkeypatch,
+):
+    """Under kernel ``blocked`` the fixed effect's training batch carries
+    block tiles: both its scores (training rows, validation rows) run
+    ``blocked/xw`` and equal the gather's to float32 summation order, the
+    second copy of the entries (``_scoring_feats``) is never built, the
+    validation cache holds tiles in place of ``(ids, vals)``, and the entry
+    counter reads what it read before."""
+    from photon_tpu.ops.block_tiles import BlockTiles
+    from photon_tpu.telemetry import TelemetrySession
+
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "blocked")
+    train, val = _sparse_fixed_game(0, 30), _sparse_fixed_game(1, 11)
+    problem = ProblemConfig(
+        regularization=RegularizationContext("l2", 1.0),
+        optimizer_config=OptimizerConfig(max_iterations=2),
+    )
+    config = GameOptimizationConfiguration(
+        coordinates={
+            "fixed": FixedEffectCoordinateConfig("global", problem),
+            "re0": RandomEffectCoordinateConfig(
+                "per_entity", "userId", problem),
+        },
+        descent_iterations=2,
+    )
+    session = TelemetrySession("t")
+    estimator = GameEstimator(
+        "logistic_regression", train, validation_data=val,
+        evaluators=MultiEvaluator([get_evaluator("auc")]), telemetry=session,
+    )
+    result = estimator.fit([config])[0]
+    model = result.model.coordinates["fixed"]
+    held = estimator.device_layout(config.coordinates["fixed"])
+    assert held.batch.bt is not None
+    assert held._score_feats is None and held._score_cache_bytes == 0
+    k = train.shard("global").ids.shape[1]
+    assert _score_counts(session) == {
+        ("score.fixed_dispatches", "blocked"): 4.0,
+        ("score.sparse_entries", None):
+            2.0 * (train.num_examples + val.num_examples) * k,
+    }
+    coord = FixedEffectCoordinate(
+        train, config.coordinates["fixed"], "logistic_regression",
+        device_data=held,
+    )
+    np.testing.assert_allclose(
+        np.asarray(coord.score_device(model)), _gather_margins(train, model),
+        rtol=1e-5, atol=1e-6,
+    )
+    cache = estimator._validation_scoring_cache()
+    assert "global" not in cache._feats
+    assert isinstance(cache._fixed_tiles["global"], BlockTiles)
+    scored = np.asarray(cache.score(model, "fixed"))
+    assert scored.shape == (val.num_examples,)
+    np.testing.assert_allclose(
+        scored, _gather_margins(val, model), rtol=1e-5, atol=1e-6
+    )
+    assert np.isfinite(result.metrics["AUC"])
+
+
+@pytest.mark.parametrize("case", [
+    "row_capacity", "down_sampled", "one_device_mesh", "no_tiles",
+    "untileable_validation",
+])
+def test_sparse_fixed_effect_score_takes_tiles_only_where_they_hold_the_rows(
+    monkeypatch, case,
+):
+    """Tiles score the training rows only where the batch IS the shard in
+    row order on one device (row-capacity pad rows are cut; a down-sampled
+    batch, a mesh and a batch without tiles take the gather and keep their
+    ``_scoring_feats``); validation rows whose grid cannot be tiled keep
+    their ``(ids, vals)``.  Every form gives the gather's margins."""
+    from photon_tpu.game.coordinate import FixedEffectDeviceData
+    from photon_tpu.game.model import DeviceScoringCache, FixedEffectModel
+    from photon_tpu.models.glm import Coefficients, model_for_task
+    from photon_tpu.ops import block_tiles
+    from photon_tpu.telemetry import TelemetrySession
+
+    monkeypatch.setenv(
+        "PHOTON_SPARSE_GRAD", "fm" if case == "no_tiles" else "blocked"
+    )
+    data = _sparse_fixed_game(2, 12)
+    n, k = data.shard("global").ids.shape
+    config = FixedEffectCoordinateConfig(
+        "global",
+        ProblemConfig(regularization=RegularizationContext("l2", 1.0)),
+        downsampling_rate=0.5 if case == "down_sampled" else 1.0,
+    )
+    mesh = create_mesh(1) if case == "one_device_mesh" else None
+    held = FixedEffectDeviceData(
+        data, config, mesh,
+        row_capacity=n + 37 if case == "row_capacity" else None,
+    )
+    assert (held.batch.bt is None) == (case == "no_tiles")
+    w = np.random.default_rng(3).standard_normal(300).astype(np.float32)
+    model = FixedEffectModel(
+        model_for_task("logistic_regression", Coefficients(jnp.asarray(w))),
+        "global",
+    )
+    session = TelemetrySession("t")
+    if case == "untileable_validation":
+        monkeypatch.setattr(block_tiles, "MAX_CELLS", 1)
+        cache = DeviceScoringCache(data, telemetry=session)
+        cache.score_fixed_through_tiles("global")
+        scored, tiled = cache.score(model, "fixed"), False
+        assert "global" in cache._feats and not cache._fixed_tiles
+    else:
+        coord = FixedEffectCoordinate(
+            data, config, "logistic_regression", mesh=mesh, device_data=held
+        )
+        coord.telemetry = session
+        scored = coord.score_device(model)
+        tiled = case == "row_capacity"
+        assert (held._score_cache_bytes == 0) == tiled
+    assert scored.shape == (n,)
+    np.testing.assert_allclose(
+        np.asarray(scored), _gather_margins(data, model), rtol=1e-5, atol=1e-6
+    )
+    assert _score_counts(session) == {
+        ("score.fixed_dispatches", "blocked" if tiled else "gather"): 1.0,
+        ("score.sparse_entries", None): float(n * k),
+    }

@@ -702,6 +702,12 @@ def test_report_program_work_sections_from_counters():
     session.counter("layout.h2d_bytes", what="aligned").inc(3 * 2**20)
     session.counter("layout.cache_bytes", op="write").inc(2**19)
     session.counter("layout.skipped", layout="fm").inc()
+    session.counter(
+        "fixed_effect.layout", coordinate="fixed", kind="sparse",
+        kernel="blocked").inc()
+    session.counter(
+        "score.fixed_dispatches", coordinate="fixed", kernel="blocked").inc(4)
+    session.counter("score.sparse_entries", coordinate="fixed").inc(1024)
     report = session.build_report()
     text = render_markdown(report)
     (row,) = [
@@ -715,7 +721,12 @@ def test_report_program_work_sections_from_counters():
     assert "| to device: aligned | 3.0 |" in text
     assert "| layout cache write | 0.5 |" in text
     assert "| not built, another kernel won the probe: fm | none (x1) |" in text
+    assert ("| fixed | layout: sparse, value+gradient kernel blocked | 1 |"
+            in text)
+    assert "| fixed | scores by blocked | 4 |" in text
+    assert "| fixed | sparse entries those scores read | 1024 |" in text
 
     plain = render_markdown({"driver": "t", "metrics": {"counters": []}})
-    for heading in ("Spans by name", "Optimizer work", "Layout bytes"):
+    for heading in ("Spans by name", "Optimizer work", "Layout bytes",
+                    "Fixed effect"):
         assert heading not in plain

@@ -603,8 +603,12 @@ def test_score_fixed_sparse_branch_carries_its_scopes_and_counts():
     and the row sums carry scope names (the dense branch, one matrix
     product, has none and keeps its program), and every dispatch adds the
     entries it reads to ``score.sparse_entries{coordinate}`` from the ids'
-    shape."""
+    shape.  From the shard's block tiles (PR 37) the same program name
+    carries one scope, ``score_fixed/blocked_xw``, and no gather; the
+    entries counted are the same shard's whichever form scores it, and
+    ``score.fixed_dispatches{kernel}`` says which did."""
     from photon_tpu.game import model
+    from photon_tpu.ops.block_tiles import build_block_tiles
 
     w = jnp.ones(32, jnp.float32)
     ids = jnp.zeros((8, 4), jnp.int32)
@@ -619,12 +623,32 @@ def test_score_fixed_sparse_branch_carries_its_scopes_and_counts():
         w, jnp.ones((8, 32), jnp.float32), dense=True))
     assert module == "HloModule jit_score_fixed"
     assert _scopes(ops, "score_fixed") == set()
+    tiles = jax.tree.map(
+        jnp.asarray, build_block_tiles(np.asarray(ids), np.asarray(vals), 32)
+    )
+    module, ops = _hlo(
+        model._fixed_margins.lower(w, tiles, dense=False, out_len=8))
+    assert module == "HloModule jit_score_fixed"
+    assert _scopes(ops, "score_fixed") == {"score_fixed/blocked_xw"}
+    # No gather of ``w`` from HBM: the one gather left is the kernel's own
+    # lane gather inside a VMEM window, which interpret mode (the host)
+    # spells as an XLA op and Mosaic (the chip) as part of the custom call.
+    assert all("jit(_cell_products)" in n for n in ops
+               if n.rsplit("/", 1)[-1] == "gather")
     session = TelemetrySession("t")
     model.count_sparse_entries(session, "fixed", (ids, vals), dense=False)
     model.count_sparse_entries(session, "fixed", (ids, vals), dense=False)
     model.count_sparse_entries(session, "fixed", vals, dense=True)
+    model.count_sparse_entries(
+        session, "fixed", tiles, dense=False, entries=tiles.n_rows * 4
+    )
     assert _counters(session.registry) == {
-        ("score.sparse_entries", (("coordinate", "fixed"),)): 64.0}
+        ("score.sparse_entries", (("coordinate", "fixed"),)): 96.0,
+        ("score.fixed_dispatches",
+         (("coordinate", "fixed"), ("kernel", "gather"))): 2.0,
+        ("score.fixed_dispatches",
+         (("coordinate", "fixed"), ("kernel", "blocked"))): 1.0,
+    }
 
 
 def test_metric_auc_is_one_sort_and_no_gather_or_loop():
