@@ -95,36 +95,23 @@ def check(cond, message: str) -> None:
 # -- compile accounting -----------------------------------------------------
 
 
-class CompileCounter:
-    """Counts XLA compile requests that went to the compiler (persistent
-    cache misses) and those the persistent cache served."""
+def run_leg(name, fn, totals):
+    """One leg, with the compile requests it made: the program's own count
+    (``compile.requests{outcome}`` in the process registry), those that went
+    to the compiler (persistent cache misses) and those the cache served."""
+    from photon_tpu.utils.compilation_cache import request_counts
 
-    def __init__(self):
-        import jax.monitoring
-
-        self.compiled = self.cache_hits = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_misses":
-            self.compiled += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self):
-        return self.compiled, self.cache_hits
-
-
-def run_leg(name, fn, counter, totals):
-    c0, h0 = counter.snapshot()
+    before = request_counts()
     t0 = time.monotonic()
     say(f"[{name}] start")
     fn()
     wall = time.monotonic() - t0
-    c1, h1 = counter.snapshot()
-    totals.append((name, wall, c1 - c0, h1 - h0))
-    say(f"[{name}] ok wall={wall:.1f}s compiled={c1 - c0} "
-        f"cache_hits={h1 - h0}")
+    after = request_counts()
+    compiled = after["miss"] - before["miss"]
+    cache_hits = after["hit"] - before["hit"]
+    totals.append((name, wall, compiled, cache_hits))
+    say(f"[{name}] ok wall={wall:.1f}s compiled={compiled} "
+        f"cache_hits={cache_hits}")
 
 
 # -- run-report helpers -----------------------------------------------------
@@ -453,6 +440,7 @@ def main() -> int:
     import jax
 
     from photon_tpu.native import build as native_build
+    from photon_tpu.utils import compilation_cache
     from photon_tpu.utils.device import device_facts
 
     device = device_facts()
@@ -479,19 +467,18 @@ def main() -> int:
           "the native library is unavailable: the readers would silently "
           "run in Python")
 
-    counter = CompileCounter()
+    compilation_cache.enable()  # and its listeners, before the first leg
     totals: list = []
     t0 = time.monotonic()
     work = tempfile.mkdtemp(prefix="chip_smoke-")
     try:
         train_out = os.path.join(work, "game")
         run_leg("game-train", lambda: leg_game_train(train_out, device),
-                counter, totals)
+                totals)
         run_leg("serve", lambda: leg_serve(
             os.path.join(train_out, "best_model"),
-            os.path.join(work, "served"), device), counter, totals)
-        run_leg("sparse-train", lambda: leg_sparse(work, device),
-                counter, totals)
+            os.path.join(work, "served"), device), totals)
+        run_leg("sparse-train", lambda: leg_sparse(work, device), totals)
     finally:
         shutil.rmtree(work, ignore_errors=True)
     signal.alarm(0)

@@ -177,6 +177,53 @@ def _render_program_work_section(report: dict) -> list:
     return lines
 
 
+_COMPILE_PHASES = ("trace", "lower", "cache_load", "xla_compile")
+_COMPILE_ROWS = 20
+
+
+def _render_compile_section(report: dict) -> list:
+    """What the programs cost before they first ran (README "Telemetry";
+    published by ``utils/compilation_cache.py``): one row a program name —
+    backend compile requests (one a compiled shape), those the persistent
+    cache answered and those it missed (the rest it did not take), and the
+    host seconds of ``compile.seconds{program, phase}`` by phase.  The
+    phases do not overlap, so a row's total and the last line's are sums.
+    The twenty dearest programs by total seconds, the rest in one row."""
+    seconds = _counter_totals(report, "compile.seconds", "program", "phase")
+    requests = _counter_totals(
+        report, "compile.requests", "program", "outcome")
+    # program -> {phase: seconds, outcome: requests}: the two share no key.
+    table: dict = {}
+    for (program, key), value in [*seconds.items(), *requests.items()]:
+        table.setdefault(program, {})[key] = value
+    if not table:
+        return []
+
+    def row(label: str, programs) -> str:
+        def total(*keys):
+            return sum(table[p].get(k, 0) for p in programs for k in keys)
+
+        by_phase = [total(phase) for phase in _COMPILE_PHASES]
+        asked = (total("hit", "miss", "uncached"), total("hit"),
+                 total("miss"))
+        return (f"| {label} | " + " | ".join(str(int(n)) for n in asked)
+                + " | " + " | ".join(f"{t:.3f}" for t in by_phase)
+                + f" | {sum(by_phase):.3f} |")
+
+    dearest = sorted(table, key=lambda p: (
+        -sum(table[p].get(phase, 0.0) for phase in _COMPILE_PHASES), p))
+    lines = ["", "## Compile", "",
+             "| program | requests | hits | misses | "
+             + " (s) | ".join(_COMPILE_PHASES) + " (s) | total (s) |",
+             "|---|---|---|---|---|---|---|---|---|"]
+    lines += [row(p, [p]) for p in dearest[:_COMPILE_ROWS]]
+    rest = dearest[_COMPILE_ROWS:]
+    if rest:
+        lines.append(row(f"{len(rest)} more programs", rest))
+    lines.append(row(f"**all {len(dearest)} programs**", dearest))
+    return lines
+
+
 def _render_pipeline_section(report: dict) -> list:
     """The checkpoint-publisher / io-pool pipeline at a glance: how long
     the training loop actually blocked on checkpoint IO vs how long the
@@ -733,6 +780,7 @@ def render_markdown(report: dict) -> str:
             lines.append(f"| {name} | {secs:.3f} |")
 
     lines += _render_program_work_section(report)
+    lines += _render_compile_section(report)
     lines += _render_pipeline_section(report)
     lines += _render_streaming_section(report)
     lines += _render_entity_solves_section(report)

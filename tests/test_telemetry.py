@@ -730,3 +730,141 @@ def test_report_program_work_sections_from_counters():
     for heading in ("Spans by name", "Optimizer work", "Layout bytes",
                     "Fixed effect"):
         assert heading not in plain
+
+
+# ------------------------------------------------------- compile accounting
+
+
+def _compile_report(programs: int) -> dict:
+    """A report whose counters hold ``programs`` programs' compile rows:
+    program ``i`` asked ``i + 1`` times (one miss, the rest hits), its
+    seconds growing with ``i``."""
+    registry = MetricsRegistry()  # not a session: its report would append
+    # the process registry's own compile rows, which earlier tests left
+    for i in range(programs):
+        name = f"jit_program_{i:02d}"
+        registry.counter(
+            "compile.requests", program=name, outcome="hit").inc(i)
+        registry.counter(
+            "compile.requests", program=name, outcome="miss").inc()
+        for phase, seconds in (("trace", 1.0), ("lower", 0.5),
+                               ("cache_load", 0.25), ("xla_compile", 2.0)):
+            registry.counter(
+                "compile.seconds", program=name, phase=phase
+            ).inc(seconds * (i + 1))
+    # Traced inside another program: seconds and no request.
+    registry.counter(
+        "compile.seconds", program="jit__where", phase="trace").inc(0.125)
+    return {"driver": "compile", "metrics": registry.snapshot()}
+
+
+@pytest.mark.parametrize("programs", [2, 30])
+def test_report_compile_section_from_counters(programs):
+    """One row a program, dearest first: requests, hits, misses, seconds by
+    phase and their sum; past twenty the rest in one row; a last line with
+    the totals."""
+    text = render_markdown(_compile_report(programs))
+    section = text.split("## Compile\n", 1)[1].split("\n## ", 1)[0]
+    rows = [line for line in section.splitlines() if line.startswith("| ")]
+    assert rows[0].startswith(
+        "| program | requests | hits | misses | trace (s) | lower (s) "
+        "| cache_load (s) | xla_compile (s) | total (s) |")
+    last = programs - 1
+    assert rows[1] == (
+        f"| jit_program_{last:02d} | {last + 1} | {last} | 1 "
+        f"| {last + 1:.3f} | {(last + 1) * 0.5:.3f} "
+        f"| {(last + 1) * 0.25:.3f} | {(last + 1) * 2.0:.3f} "
+        f"| {(last + 1) * 3.75:.3f} |")
+    named = [r for r in rows[1:] if r.startswith("| jit_")]
+    n = programs * (programs + 1) // 2  # 1 + 2 + ... + programs
+    totals = (f"| **all {programs + 1} programs** | {n} | {n - programs} "
+              f"| {programs} | {n + 0.125:.3f} | {n * 0.5:.3f} "
+              f"| {n * 0.25:.3f} | {n * 2.0:.3f} | {n * 3.75 + 0.125:.3f} |")
+    assert rows[-1] == totals
+    if programs <= 20:
+        assert len(named) == programs + 1
+        assert named[-1] == ("| jit__where | 0 | 0 | 0 | 0.125 | 0.000 "
+                             "| 0.000 | 0.000 | 0.125 |")
+    else:
+        assert len(named) == 20
+        m = 10  # programs 00..09: 1 + ... + 10 requests = 55
+        assert rows[-2] == (
+            f"| {m + 1} more programs | 55 | {55 - m} | {m} "
+            f"| {55 + 0.125:.3f} | {55 * 0.5:.3f} | {55 * 0.25:.3f} "
+            f"| {55 * 2.0:.3f} | {55 * 3.75 + 0.125:.3f} |")
+
+
+def test_report_has_no_compile_section_without_its_counters():
+    assert "## Compile" not in render_markdown(
+        {"driver": "t", "metrics": {"counters": []}})
+
+
+_COMPILE_RUN = {"counters": {"counters": [
+    {"name": "compile.seconds",
+     "labels": {"program": "jit_glm_fit_lbfgs", "phase": "trace"},
+     "value": 2.0},
+    {"name": "compile.seconds",
+     "labels": {"program": "jit__where", "phase": "trace"}, "value": 0.5},
+    {"name": "compile.seconds",
+     "labels": {"program": "jit_glm_fit_lbfgs", "phase": "lower"},
+     "value": 0.75},
+    {"name": "compile.seconds",
+     "labels": {"program": "jit_glm_fit_lbfgs", "phase": "cache_load"},
+     "value": 0.25},
+    {"name": "compile.seconds",
+     "labels": {"program": "jit_metric_auc", "phase": "xla_compile"},
+     "value": 41.0},
+    {"name": "compile.requests",
+     "labels": {"program": "jit_glm_fit_lbfgs", "outcome": "hit"},
+     "value": 2.0},
+    {"name": "compile.requests",
+     "labels": {"program": "jit_metric_auc", "outcome": "miss"},
+     "value": 1.0},
+    {"name": "compile.requests",
+     "labels": {"program": "jit_iota", "outcome": "uncached"}, "value": 1.0},
+    {"name": "span.seconds", "labels": {"span": "kernels.probe"},
+     "value": 8.0},
+], "gauges": []}}
+
+
+@pytest.mark.parametrize("metric, wanted", [
+    ("setup.trace_s", 2.5),
+    ("setup.lower_s", 0.75),
+    ("setup.cache_load_s", 0.25),
+    ("setup.xla_compile_s", 41.0),
+    ("setup.programs", 4.0),
+])
+def test_compile_reader_on_a_hand_made_run(metric, wanted):
+    """The benchmark's five readers of the compile accounting: the value
+    where the rows are there, nothing where the program publishes none (the
+    parent commit), and 0 for a phase nothing ran in (a warm run's
+    ``xla_compile``) so long as the accounting is there at all."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks import run as harness
+
+    read = harness.load_module(
+        os.path.join(root, "benchmarks", "layer_metrics"), metric).read
+    assert read(_COMPILE_RUN) == wanted
+    others = {"counters": {"counters": [
+        row for row in _COMPILE_RUN["counters"]["counters"]
+        if not row["name"].startswith("compile.")], "gauges": []}}
+    assert read(others) is None
+    assert read({"counters": {"counters": [], "gauges": []}}) is None
+    if metric.endswith("_s"):
+        phase = metric[len("setup."):-len("_s")]
+        without = {"counters": {"counters": [
+            row for row in _COMPILE_RUN["counters"]["counters"]
+            if row["labels"].get("phase") != phase], "gauges": []}}
+        assert read(without) == 0.0
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == metric]
+    assert entry == {
+        "name": metric, "unit": "s" if metric.endswith("_s") else "count",
+        "better": "lower", "source": "program_counter",
+        "layer": "drivers + device policy", "moves": "setup_s",
+    }

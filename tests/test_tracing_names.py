@@ -23,7 +23,7 @@ import pytest
 
 from photon_tpu import telemetry
 from photon_tpu.telemetry import NULL_SESSION, MetricsRegistry, TelemetrySession
-from photon_tpu.utils import device
+from photon_tpu.utils import compilation_cache, device
 from photon_tpu.utils.device import named_jit
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -175,6 +175,209 @@ def test_jax_free_process_spans_stay_jax_free():
     )
 
 
+# -- compile accounting (utils/compilation_cache.py) ---------------------------
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_HIT = "/jax/compilation_cache/cache_hits"
+_MISS = "/jax/compilation_cache/cache_misses"
+
+
+def _play(sequence) -> None:
+    """Feed the listeners a recorded sequence through ``jax.monitoring`` as
+    jax 0.9 emits it (``dispatch.LogElapsedTimeContextManager``): a span is
+    a scalar when it opens, a duration and a time span when it closes; the
+    cache's verdict comes between, without a name."""
+    from jax import monitoring
+
+    for kind, event, *rest in sequence:
+        if kind == "open":
+            name, start = rest
+            monitoring.record_scalar(event, start, fun_name=name)
+        elif kind == "close":
+            name, start, end = rest
+            monitoring.record_event_duration_secs(
+                event, end - start, fun_name=name)
+            monitoring.record_event_time_span(
+                event, start, end, fun_name=name)
+        elif kind == "duration":
+            monitoring.record_event_duration_secs(event, *rest)
+        else:
+            monitoring.record_event(event)
+
+
+def _compile_rows() -> dict:
+    """``{(counter, program, phase or outcome): value}`` of the process
+    registry's compile accounting."""
+    return {
+        (name, dict(labels)["program"],
+         dict(labels).get("phase") or dict(labels)["outcome"]): value
+        for (name, labels), value in _counters(
+            telemetry.process_registry()).items()
+        if name.startswith("compile.")
+    }
+
+
+# Each case: the sequence, the rows it must leave (nothing else under
+# compile.*), and the seconds the outermost spans cover.
+_RECORDED = {
+    # Tracing `outer` traces `inner_a` (which traces `leaf`) and `inner_b`,
+    # inner first: each second once, the outer program its self time.
+    "nested_trace": ([
+        ("open", _TRACE, "outer", 100.0),
+        ("open", _TRACE, "inner_a", 100.25),
+        ("open", _TRACE, "leaf", 100.25),
+        ("close", _TRACE, "leaf", 100.25, 100.375),
+        ("close", _TRACE, "inner_a", 100.25, 100.5),
+        ("open", _TRACE, "inner_b", 100.5),
+        ("close", _TRACE, "inner_b", 100.5, 100.75),
+        ("close", _TRACE, "outer", 100.0, 101.0),
+    ], {
+        ("compile.seconds", "jit_outer", "trace"): 0.5,
+        ("compile.seconds", "jit_inner_a", "trace"): 0.125,
+        ("compile.seconds", "jit_leaf", "trace"): 0.125,
+        ("compile.seconds", "jit_inner_b", "trace"): 0.25,
+    }, 1.0),
+    # A hit: the whole request under cache_load, nothing under xla_compile.
+    "hit": ([
+        ("open", _BACKEND, "jit(served)", 10.0),
+        ("event", "/jax/compilation_cache/compile_requests_use_cache"),
+        ("event", _HIT),
+        ("duration", "/jax/compilation_cache/compile_time_saved_sec", 3.0),
+        ("duration", "/jax/compilation_cache/cache_retrieval_time_sec",
+         0.0625),
+        ("close", _BACKEND, "jit(served)", 10.0, 10.0625),
+    ], {
+        ("compile.requests", "jit_served", "hit"): 1,
+        ("compile.seconds", "jit_served", "cache_load"): 0.0625,
+    }, 0.0625),
+    # A miss: the reverse.
+    "miss": ([
+        ("open", _BACKEND, "jit(compiled)", 20.0),
+        ("event", "/jax/compilation_cache/compile_requests_use_cache"),
+        ("event", _MISS),
+        ("close", _BACKEND, "jit(compiled)", 20.0, 22.5),
+    ], {
+        ("compile.requests", "jit_compiled", "miss"): 1,
+        ("compile.seconds", "jit_compiled", "xla_compile"): 2.5,
+    }, 2.5),
+    # No cache event since the last request: uncached, and the verdict of
+    # the hit before it is not carried over.
+    "uncached_after_a_hit": ([
+        ("open", _BACKEND, "jit(served)", 10.0),
+        ("event", _HIT),
+        ("close", _BACKEND, "jit(served)", 10.0, 10.125),
+        ("open", _BACKEND, "jit(never_offered)", 11.0),
+        ("close", _BACKEND, "jit(never_offered)", 11.0, 11.5),
+    ], {
+        ("compile.requests", "jit_served", "hit"): 1,
+        ("compile.seconds", "jit_served", "cache_load"): 0.125,
+        ("compile.requests", "jit_never_offered", "uncached"): 1,
+        ("compile.seconds", "jit_never_offered", "xla_compile"): 0.5,
+    }, 0.625),
+    # One name, three shapes: one row, three requests.  The module's name
+    # and the function's come to one form, a lambda's as JAX sanitises it.
+    "one_label_a_name": ([
+        step for start in (30.0, 31.0, 32.0) for step in (
+            ("open", _TRACE, "entity_solve_newton", start),
+            ("close", _TRACE, "entity_solve_newton", start, start + 0.25),
+            ("open", _LOWER, "jit(entity_solve_newton)", start + 0.25),
+            ("close", _LOWER, "jit(entity_solve_newton)", start + 0.25,
+             start + 0.5),
+            ("open", _BACKEND, "jit(entity_solve_newton)", start + 0.5),
+            ("event", _HIT),
+            ("close", _BACKEND, "jit(entity_solve_newton)", start + 0.5,
+             start + 0.75),
+        )
+    ] + [
+        ("open", _TRACE, "<lambda>", 40.0),
+        ("close", _TRACE, "<lambda>", 40.0, 40.5),
+        ("open", _LOWER, "jit(<lambda>)", 40.5),
+        ("close", _LOWER, "jit(<lambda>)", 40.5, 41.0),
+    ], {
+        ("compile.requests", "jit_entity_solve_newton", "hit"): 3,
+        ("compile.seconds", "jit_entity_solve_newton", "trace"): 0.75,
+        ("compile.seconds", "jit_entity_solve_newton", "lower"): 0.75,
+        ("compile.seconds", "jit_entity_solve_newton", "cache_load"): 0.75,
+        ("compile.seconds", "jit__lambda", "trace"): 0.5,
+        ("compile.seconds", "jit__lambda", "lower"): 0.5,
+    }, 3.25),
+    # A probe inside a trace lowers and compiles: the phases nest across
+    # kinds too, and still no second is counted twice.
+    "compile_inside_a_trace": ([
+        ("open", _TRACE, "glm_fit_lbfgs", 60.0),
+        ("open", _TRACE, "candidate", 60.5),
+        ("close", _TRACE, "candidate", 60.5, 60.75),
+        ("open", _LOWER, "jit(candidate)", 60.75),
+        ("close", _LOWER, "jit(candidate)", 60.75, 61.0),
+        ("open", _BACKEND, "jit(candidate)", 61.0),
+        ("event", _MISS),
+        ("close", _BACKEND, "jit(candidate)", 61.0, 63.0),
+        ("close", _TRACE, "glm_fit_lbfgs", 60.0, 64.0),
+    ], {
+        ("compile.seconds", "jit_glm_fit_lbfgs", "trace"): 1.5,
+        ("compile.seconds", "jit_candidate", "trace"): 0.25,
+        ("compile.seconds", "jit_candidate", "lower"): 0.25,
+        ("compile.requests", "jit_candidate", "miss"): 1,
+        ("compile.seconds", "jit_candidate", "xla_compile"): 2.0,
+    }, 4.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RECORDED))
+def test_compile_listeners_on_a_recorded_sequence(case):
+    sequence, wanted, covered = _RECORDED[case]
+    compilation_cache.enable()
+    telemetry.process_registry().clear()
+    _play(sequence)
+    rows = _compile_rows()
+    assert rows == pytest.approx(wanted)
+    # The per-program rows sum to the totals: every second the outermost
+    # spans cover is under exactly one (program, phase).
+    assert sum(
+        v for (name, _, _), v in rows.items() if name == "compile.seconds"
+    ) == pytest.approx(covered)
+    assert compilation_cache.request_counts() == {
+        outcome: sum(v for (name, _, o), v in wanted.items()
+                     if name == "compile.requests" and o == outcome)
+        for outcome in ("hit", "miss", "uncached")
+    }
+
+
+def test_enable_twice_installs_one_set_of_listeners():
+    first = compilation_cache.enable()
+    assert compilation_cache.enable() == first
+    telemetry.process_registry().clear()
+    _play(_RECORDED["miss"][0])
+    assert _compile_rows() == pytest.approx(_RECORDED["miss"][1])
+
+
+def test_named_jit_leaves_its_compile_rows_once():
+    """Live on the host: the first call of a named program traces, lowers
+    and makes one backend request under ``jit_<name>``; the second call of
+    the same shape adds nothing."""
+    compilation_cache.enable()
+    telemetry.process_registry().clear()
+    probe = named_jit("probe_me", lambda x: jnp.cos(x) * 3.0 + x)
+    x = jnp.arange(7, dtype=jnp.float32)
+    probe(x).block_until_ready()
+    mine = {k: v for k, v in _compile_rows().items()
+            if k[1] == "jit_probe_me"}
+    assert {k[2] for k in mine if k[0] == "compile.seconds"} in (
+        {"trace", "lower", "cache_load"}, {"trace", "lower", "xla_compile"})
+    assert all(v > 0 for v in mine.values())
+    (request,) = [k for k in mine if k[0] == "compile.requests"]
+    assert mine[request] == 1 and request[2] in ("hit", "miss")
+    after_first = _compile_rows()
+    probe(x).block_until_ready()
+    assert _compile_rows() == after_first
+    # Another shape of the same name: the same row, a second request.
+    probe(jnp.ones((3, 2))).block_until_ready()
+    assert sum(v for k, v in _compile_rows().items()
+               if k[:2] == ("compile.requests", "jit_probe_me")) == 2
+
+
 # -- one process registry ------------------------------------------------------
 
 
@@ -207,6 +410,25 @@ def test_kernel_metrics_rows_keep_their_shape():
     report = TelemetrySession("t").build_report()
     assert {"name": "span.count", "labels": {"span": "kernels.probe"},
             "value": 1.0} in report["metrics"]["counters"]
+    # The compile accounting's rows, label for label (README "Telemetry";
+    # the benchmark's setup.* readers and the report's Compile table read
+    # these names).
+    compilation_cache.enable()
+    _play([("open", _BACKEND, "jit(score_fixed)", 50.0),
+           ("event", "/jax/compilation_cache/cache_hits"),
+           ("close", _BACKEND, "jit(score_fixed)", 50.0, 50.25)])
+    assert [row for row in device.kernel_metrics()
+            if row["name"].startswith("compile.")] == [
+        {"name": "compile.requests",
+         "labels": {"outcome": "hit", "program": "jit_score_fixed"},
+         "value": 1.0},
+        {"name": "compile.seconds",
+         "labels": {"phase": "cache_load", "program": "jit_score_fixed"},
+         "value": 0.25},
+    ]
+    assert compilation_cache.PHASES == (
+        "trace", "lower", "cache_load", "xla_compile")
+    assert compilation_cache.OUTCOMES == ("hit", "miss", "uncached")
     assert not any(
         isinstance(value, (dict, list, set))
         for name, value in vars(device).items() if not name.startswith("__")
@@ -570,6 +792,8 @@ def test_published_program_names():
     from photon_tpu.evaluation import metrics
     from photon_tpu.game import coordinate, model, residuals
 
+    compilation_cache.enable()
+
     programs = {
         metrics.area_under_roc_curve: "metric_auc",
         metrics.logistic_loss_metric: "metric_logloss",
@@ -596,6 +820,12 @@ def test_published_program_names():
     ))
     assert module == "HloModule jit_score_table_update"
     assert "residuals/update" in _scopes(ops, "score_table_update")
+    # The compile accounting files both under the module's own name.
+    rows = _compile_rows()
+    for program in ("jit_metric_auc", "jit_score_table_update"):
+        assert rows["compile.seconds", program, "lower"] > 0
+        assert sum(v for k, v in rows.items()
+                   if k[:2] == ("compile.requests", program)) >= 1
 
 
 def test_score_fixed_sparse_branch_carries_its_scopes_and_counts():
