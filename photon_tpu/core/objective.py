@@ -79,6 +79,37 @@ def _fm_segment_grad(per_row: Array, fm: FeatureMajorAux, dim: int) -> Array:
         return jnp.sum(jax.vmap(_block)(contrib, fm.ids), axis=0)
 
 
+def _lane_form(slab):
+    """``slab`` over one entity's operands (features ``[R, d]`` first), and
+    under ``vmap`` over ALL of them the same arithmetic once, with the
+    mapped axis moved behind every other: the entities on the lane axis, as
+    ``newton._spd_solve_lanes`` has them.  A caller that maps the features
+    with ``in_axes=-1`` (``batched_solve._entity_solve_newton``) gets the
+    inverse of ``vmap``'s own ``moveaxis`` here, and no transpose of them is
+    left.  Any other pattern of mapped operands is a plain ``vmap``."""
+    fn = jax.custom_batching.custom_vmap(slab)
+
+    @fn.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        if all(in_batched):
+            out = slab(*(jnp.moveaxis(a, 0, -1) for a in args))
+            return jnp.moveaxis(out, -1, 0), True
+        in_axes = [0 if b else None for b in in_batched]
+        return jax.vmap(slab, in_axes, axis_size=axis_size)(*args), True
+
+    return fn
+
+
+# The three dense products of a GLM as float32 elementwise products and sums
+# over non-lane axes, no ``dot_general``: features ``x [R, d, ...]``, the
+# trailing axes batch axes (none for one entity, the entities under vmap).
+_lane_xw = _lane_form(lambda x, v: jnp.sum(x * v[None], axis=1))
+_lane_xtu = _lane_form(lambda x, u: jnp.sum(x * u[:, None], axis=0))
+_lane_xtdx = _lane_form(
+    lambda x, u: jnp.sum(x[:, :, None] * (x * u[:, None])[:, None], axis=0)
+)
+
+
 @dataclasses.dataclass(frozen=True)
 class RegularizationContext:
     """L1/L2/elastic-net configuration.
@@ -449,10 +480,71 @@ class GlmObjective:
         normalized feature space (matching hessian_diagonal), expanded as
         ``F (A - B sᵀ - s Bᵀ + C s sᵀ) F`` with ``A = Xᵀ D X``,
         ``B = Xᵀ D 1``, ``C = Σ D`` so sparse batches stay sparse."""
-        z = self._margins(w, batch)
+        return self.hessian_at_margins(self._margins(w, batch), w, batch)
+
+    # -- functions of the margins ----------------------------------------------
+    # ``z = margins(w)`` is affine in ``w``, and value, gradient and Hessian
+    # at ``w`` are functions of ``z``: an optimizer that carries ``z``
+    # (``newton.MarginForm``) searches a line ``z + t X v`` with no pass
+    # over the features a trial.  ``lanes``: the dense products in the form
+    # that puts a mapped axis on the lanes (:func:`_lane_form`).
+    def _x_dot(self, v: Array, batch: Batch, lanes: bool) -> Array:
+        if lanes:
+            return _lane_xw(batch.x, v)
+        return margins(v, batch._replace(offset=jnp.zeros((), v.dtype)))
+
+    def _xt_dot(self, u: Array, batch: Batch, dim: int, lanes: bool) -> Array:
+        if lanes:
+            return _lane_xtu(batch.x, u)
+        if isinstance(batch, DenseBatch):
+            return batch.x.T @ u
+        return jnp.zeros(dim, u.dtype).at[batch.ids].add(u[:, None] * batch.vals)
+
+    def direction_margins(self, v: Array, batch: Batch, lanes: bool = False) -> Array:
+        """``margins(w + v) - margins(w)``: ``X v`` under the normalization,
+        without the offsets."""
+        norm = self.normalization
+        with jax.named_scope("valuegrad/margins"):
+            if norm is None:
+                return self._x_dot(v, batch, lanes)
+            v_eff = v if norm.factors is None else v * norm.factors
+            xv = self._x_dot(v_eff, batch, lanes)
+            if norm.shifts is None:
+                return xv
+            return xv - jnp.sum(norm.shifts * v_eff)
+
+    def margins(self, w: Array, batch: Batch, lanes: bool = False) -> Array:
+        return self.direction_margins(w, batch, lanes) + batch.offset
+
+    def value_at_margins(self, z: Array, w: Array, batch: Batch) -> Array:
+        with jax.named_scope("valuegrad/loss"):
+            v = jnp.sum(batch.weight * self.loss.value(z, batch.label))
+            return v + 0.5 * self.l2_weight * jnp.sum(w * w)
+
+    def grad_at_margins(self, z: Array, w: Array, batch: Batch,
+                        lanes: bool = False) -> Array:
+        """The gradient at ``w`` from ``z = margins(w)``:
+        :meth:`_fast_data_value_and_grad`'s algebra, one pass over the
+        features."""
+        with jax.named_scope("valuegrad/loss"):
+            dz = batch.weight * self.loss.d1(z, batch.label)
+        with jax.named_scope("valuegrad/grad"):
+            g = self._xt_dot(dz, batch, w.shape[0], lanes)
+            norm = self.normalization
+            if norm is not None:
+                if norm.shifts is not None:
+                    g = g - norm.shifts * jnp.sum(dz)
+                g = g * norm.factors_or_ones(w.shape[0])
+            return g + self.l2_weight * w
+
+    def hessian_at_margins(self, z: Array, w: Array, batch: Batch,
+                           lanes: bool = False) -> Array:
+        """:meth:`hessian_matrix` at ``w`` from ``z = margins(w)``."""
         d2w = batch.weight * self.loss.d2(z, batch.label)
         d = w.shape[0]
-        if isinstance(batch, DenseBatch):
+        if lanes:
+            a, b = _lane_xtdx(batch.x, d2w), self._xt_dot(d2w, batch, d, lanes)
+        elif isinstance(batch, DenseBatch):
             a = jnp.einsum("ni,n,nj->ij", batch.x, d2w, batch.x)
             b = batch.x.T @ d2w
         else:

@@ -49,20 +49,30 @@ class _LineSearchState(NamedTuple):
 
 def _backtracking_line_search(fun, w, d, f0, dir_deriv, t0, max_steps, active,
                               scope: str = "line_search"):
-    """Armijo backtracking from step ``t0``, halving on failure.  ``scope``
-    names the trials' operations in the device trace (``lbfgs/line_search``,
-    ``newton/gradient``: the trials are where a Newton step's gradient is
-    evaluated).
+    """:func:`_backtracking` along ``w + t d``: a trial is one (value, grad)
+    evaluation of ``fun``.  Returns (t, f_t, g_t, success, trials)."""
+    return _backtracking(
+        lambda t: fun(w + t * d), f0, dir_deriv, t0, max_steps, active, scope
+    )
 
-    Returns (t, f_t, g_t, success, trials).  The acceptance test lives in
-    the loop condition, so exactly one (value, grad) evaluation happens per
+
+def _backtracking(phi, f0, dir_deriv, t0, max_steps, active,
+                  scope: str = "line_search"):
+    """Armijo backtracking from step ``t0``, halving on failure, over
+    ``phi(t) -> (value, aux)`` (``aux`` any pytree: the gradient at the
+    trial point, or nothing).  ``scope`` names the trials' operations in
+    the device trace (``lbfgs/line_search``, ``newton/gradient``,
+    ``newton/line_search``).
+
+    Returns (t, f_t, aux_t, success, trials).  The acceptance test lives in
+    the loop condition, so exactly one evaluation of ``phi`` happens per
     trial — an accepted first step costs a single evaluation, and ``trials``
     (int32) is the number of evaluations this search ran.  Inert when
     ``active`` is False.
     """
 
     def trial(t):
-        f, g = fun(w + t * d)
+        f, g = phi(t)
         # NaN/Inf trial values (e.g. Poisson exp overflow) never pass Armijo.
         ok = (f <= f0 + _ARMIJO_C1 * t * dir_deriv) & jnp.isfinite(f)
         return f, g, ok

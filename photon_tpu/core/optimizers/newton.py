@@ -13,6 +13,15 @@ what an unbatched call runs.  On the v5e the custom call spends 1.7 us on
 each 16 x 16 matrix, 16 of 128 lanes carrying data; it was 60 % of a GAME
 fit (PERF.md, PR 28).
 
+A GLM objective goes in as a :class:`MarginForm`: its margins are affine
+in ``w``, so the solver carries them, searches the line ``z + t X step``
+with one pass over the features (``X step``) and a loss over ``[rows]`` a
+trial, and takes gradient and Hessian at the accepted point from the
+stepped margins — three passes an iteration.  Under ``vmap`` that is what
+counts: the line search runs in lockstep, so with a value and a gradient
+over the features a trial every entity of a bin paid two passes for each
+trial of the one entity that backtracked longest (PERF.md, PR 39).
+
 Same contract as :func:`~photon_tpu.core.optimizers.lbfgs.lbfgs`: a single
 ``lax.while_loop`` machine whose state updates are all masked on an
 ``active`` flag, so converged lanes FREEZE under vmap while heavy entities
@@ -50,7 +59,10 @@ from photon_tpu.core.optimizers.base import (
     record_history,
     tree_where,
 )
-from photon_tpu.core.optimizers.lbfgs import _backtracking_line_search
+from photon_tpu.core.optimizers.lbfgs import (
+    _backtracking,
+    _backtracking_line_search,
+)
 
 Array = jax.Array
 
@@ -66,6 +78,18 @@ _POLISH_STEPS = 2  # full steps after the loop, one evaluation each
 # batched custom call, and the d = 32 unroll compiles in 4 s; past it
 # nothing is measured, and the unroll's work and compile grow as d^3.
 LANES_MAX_DIM = 32
+# One vector register's lanes.  A dense bin's features sit on the v5e with
+# their ROWS on the lanes whatever their logical order (the compiler lays a
+# ``[B, 256, 16]`` array out rows-minor; an entity-minor slab only where the
+# entity count needs no padding), so a bin of at least this many rows an
+# entity fills the lanes as it is and its Hessian is an MXU product; under
+# it most lanes idle, and the bin is turned entity-minor instead
+# (:func:`reduction_kind`), its entities padded to a multiple of this.
+# Fixed from the v5e (PERF.md, PR 39; one bin program alone, margins
+# carried in both): ``[13,124, 32, 16]`` 19.6 ms a call rows-minor, 10.3 ms
+# entity-minor; ``[25,238, 256, 16]`` 44.6 against 87.9 ms; ``[1,567,
+# 1,024, 16]`` 6.7 against 11.7 ms.
+LANES = 128
 
 
 def _spd_solve_xla(h: Array, g: Array) -> Array:
@@ -130,6 +154,21 @@ def factorization_kind(dim: int) -> str:
     return "lanes" if dim <= LANES_MAX_DIM else "xla"
 
 
+def reduction_kind(dense: bool, dim: int, entities: int, rows: int) -> str:
+    """Which form the three dense products of a batched ``newton`` bin take
+    (``X v``, ``Xᵀ u``, ``Xᵀ D X``): ``lanes`` — the features turned
+    entity-minor once a call, float32 products and sums over the non-lane
+    axes (``objective._lane_form``) — for dense features at the
+    factorization's lane dims whose ``rows`` an entity leave lanes idle
+    (under ``LANES``) and whose ``entities`` a device fill them (at least
+    ``LANES``); else ``rows``, the products over ``[B, rows, dim]``.  What
+    ``solves.reductions{kind}`` counts."""
+    lanes = (
+        dense and dim <= LANES_MAX_DIM and rows < LANES and entities >= LANES
+    )
+    return "lanes" if lanes else "rows"
+
+
 @jax.custom_batching.custom_vmap
 def spd_solve(h: Array, g: Array) -> Array:
     """``h^-1 g`` for a symmetric positive definite ``h``.  Unbatched it is
@@ -149,10 +188,29 @@ def _spd_solve_vmap(axis_size, in_batched, h, g):
     return jax.vmap(_spd_solve_xla)(h, g), True
 
 
+class MarginForm(NamedTuple):
+    """An objective through its margins ``z = margins(w)``, affine in ``w``
+    (a GLM's ``X w + offset``): value, gradient and Hessian at ``w`` as
+    functions of ``(z, w)``.  :func:`newton` then carries ``z``: a Newton
+    iteration is three passes over the features (``direction``, ``grad``,
+    ``hess``) however many trials its line search runs, each trial the loss
+    over ``z + t X step``.  Without it a trial is a value AND a gradient,
+    two passes, and under ``vmap`` every entity of a bin pays for the trials
+    of the one that backtracks longest (on the v5e 0.55 s of a 1.42 s GAME
+    fit: PERF.md, PR 39)."""
+
+    margins: Callable[[Array], Array]  # w -> z
+    direction: Callable[[Array], Array]  # v -> margins(w + v) - margins(w)
+    value: Callable[[Array, Array], Array]  # (z, w) -> f
+    grad: Callable[[Array, Array], Array]  # (z, w) -> g
+    hess: Callable[[Array, Array], Array]  # (z, w) -> [d, d]
+
+
 class _State(NamedTuple):
     w: Array
     f: Array
     g: Array
+    z: Array  # margins(w) under a MarginForm, else a placeholder
     it: Array
     ls: Array  # line-search trials so far (one objective evaluation each)
     active: Array
@@ -163,31 +221,41 @@ class _State(NamedTuple):
 
 
 def newton(
-    fun: Callable[[Array], tuple[Array, Array]],
+    fun: Callable[[Array], tuple[Array, Array]] | None,
     w0: Array,
     config: OptimizerConfig = OptimizerConfig(),
     hess: Callable[[Array], Array] | None = None,
+    form: MarginForm | None = None,
 ) -> OptimizerResult:
     """Minimize ``fun`` (returning (value, grad)) with full Newton steps.
 
     ``hess(w) -> [d, d]`` supplies the dense Hessian (for GLM objectives,
     ``objective.hessian_matrix``); if None it is derived from ``fun`` by
     forward-mode differentiation of the gradient (exact, d jvp passes).
+    ``form``: the objective as a :class:`MarginForm`, in place of ``fun``
+    and ``hess``; the same iterates, the margins carried between them.
     Pure JAX: safe under jit and vmap (the GAME batched entity solves).
     """
-    if hess is None:
+    if form is None and hess is None:
         def hess(w):  # noqa: ANN001
             return jax.jacfwd(lambda u: fun(u)[1])(w)
 
+    def evaluate(w):
+        """``(z, f, g)`` at ``w``, the margins taken afresh."""
+        if form is None:
+            return (jnp.zeros((), w.dtype), *fun(w))
+        z = form.margins(w)
+        return z, form.value(z, w), form.grad(z, w)
+
     d = w0.shape[0]
     eye = jnp.eye(d, dtype=w0.dtype)
-    f0, g0 = fun(w0)
+    z0, f0, g0 = evaluate(w0)
     gnorm0 = jnp.linalg.norm(g0)
     conv0 = gnorm0 == 0.0
     hv, hg, hvalid = init_history(config.max_iterations, f0, gnorm0)
 
     init = _State(
-        w=w0, f=f0, g=g0,
+        w=w0, f=f0, g=g0, z=z0,
         it=jnp.asarray(0, jnp.int32),
         ls=jnp.asarray(0, jnp.int32),
         active=~conv0,
@@ -201,34 +269,49 @@ def newton(
     def cond(s: _State):
         return s.active
 
-    def solve(w, g):
+    def solve(w, g, z):
         """The Newton step ``-(H(w) + ridge I)^-1 g``."""
         with jax.named_scope("newton/hessian"):
-            h = hess(w)
+            h = hess(w) if form is None else form.hess(z, w)
             ridge = _RIDGE * (1.0 + jnp.max(jnp.abs(jnp.diagonal(h))))
             h = h + ridge * eye
         with jax.named_scope("newton/cholesky"):
             return -spd_solve(h, g)
 
     def body(s: _State):
-        step = solve(s.w, s.g)
+        step = solve(s.w, s.g, s.z)
         with jax.named_scope("newton/step"):
-            dir_deriv = jnp.dot(s.g, step)
+            # Sums of float32 products, not ``dot``: a batched dot is a
+            # ``dot_general`` at the backend's matmul precision.
+            dir_deriv = jnp.sum(s.g * step)
             # A failed factorization (non-PD curvature -> NaN) or a
             # non-descent step falls back to steepest descent for this
             # iteration.
             bad = ~jnp.all(jnp.isfinite(step)) | (dir_deriv >= 0.0)
             step = jnp.where(bad, -s.g, step)
-            dir_deriv = jnp.where(bad, -jnp.dot(s.g, s.g), dir_deriv)
+            dir_deriv = jnp.where(bad, -jnp.sum(s.g * s.g), dir_deriv)
             t0 = jnp.where(
                 bad, 1.0 / jnp.maximum(jnp.linalg.norm(s.g), 1.0), 1.0
             )
 
-        t, f_new, g_new, ls_ok, trials = _backtracking_line_search(
-            fun, s.w, step, s.f, dir_deriv, t0, config.max_line_search,
-            s.active, scope="newton/gradient",
-        )
-        w_new = s.w + t * step
+        if form is None:
+            t, f_new, g_new, ls_ok, trials = _backtracking_line_search(
+                fun, s.w, step, s.f, dir_deriv, t0, config.max_line_search,
+                s.active, scope="newton/gradient",
+            )
+            w_new, z_new = s.w + t * step, s.z
+        else:
+            with jax.named_scope("newton/direction"):
+                u = form.direction(step)
+            # A trial is the value along the carried margins, no gradient.
+            t, f_new, _, ls_ok, trials = _backtracking(
+                lambda t: (form.value(s.z + t * u, s.w + t * step), ()),
+                s.f, dir_deriv, t0, config.max_line_search, s.active,
+                scope="newton/line_search",
+            )
+            w_new, z_new = s.w + t * step, s.z + t * u
+            with jax.named_scope("newton/gradient"):
+                g_new = form.grad(z_new, w_new)
 
         gnorm_new = jnp.linalg.norm(g_new)
         converged, reason = check_convergence(
@@ -250,13 +333,14 @@ def newton(
         w_out = jnp.where(ls_ok, w_new, s.w)
         f_out = jnp.where(ls_ok, f_new, s.f)
         g_out = jnp.where(ls_ok, g_new, s.g)
+        z_out = jnp.where(ls_ok, z_new, s.z)
         hv, hg, hvalid = record_history(
             s.hv, s.hg, s.hvalid, it_new, f_out, jnp.linalg.norm(g_out),
             s.active & ls_ok,
         )
 
         new = _State(
-            w=w_out, f=f_out, g=g_out,
+            w=w_out, f=f_out, g=g_out, z=z_out,
             it=it_new, ls=s.ls + trials, active=still_active,
             reason=reason.astype(jnp.int32),
             hv=hv, hg=hg, hvalid=hvalid,
@@ -276,24 +360,32 @@ def newton(
     # iterate (a lane that stopped far from its optimum — max_iterations,
     # degenerate curvature — must not take an unsearched full step) and
     # the stepped point stays finite.
-    def polish(carry, _):
-        w, f, g = carry
-        step = solve(w, g)
+    def polish(carry):
+        k, w, f, g, z = carry
+        step = solve(w, g, z)
         near = jnp.all(jnp.isfinite(step)) & (
             jnp.linalg.norm(step)
             <= 1e-3 * jnp.maximum(jnp.linalg.norm(w), 1.0)
         )
         w_new = jnp.where(near, w + step, w)
-        f_new, g_new = fun(w_new)
+        # Under a MarginForm the margins are taken afresh here, not stepped
+        # along: the polish lands on the float32 gradient's own zero.
+        z_new, f_new, g_new = evaluate(w_new)
         keep = near & jnp.isfinite(f_new) & jnp.all(jnp.isfinite(g_new))
         return (
+            k + 1,
             jnp.where(keep, w_new, w),
             jnp.where(keep, f_new, f),
             jnp.where(keep, g_new, g),
-        ), None
+            jnp.where(keep, z_new, z),
+        )
 
-    (w_out, f_out, g_out), _ = lax.scan(
-        polish, (final.w, final.f, final.g), None, length=_POLISH_STEPS
+    # A ``while_loop`` on a counter, not a ``scan``: under ``vmap`` a scan
+    # moves every mapped constant's axis to the front, which would turn an
+    # entity-minor bin (``reduction_kind``) back inside each polish step.
+    _, w_out, f_out, g_out, _ = lax.while_loop(
+        lambda carry: carry[0] < _POLISH_STEPS, polish,
+        (jnp.asarray(0, jnp.int32), final.w, final.f, final.g, final.z),
     )
     return OptimizerResult(
         w=w_out,

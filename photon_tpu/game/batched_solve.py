@@ -21,6 +21,24 @@ it:
   for the common small-``solve_dim`` smooth case, and the existing vmapped
   L-BFGS/OWL-QN/TRON program for everything else (L1 bins, large dims,
   row-split placement) — so every existing ``problem`` config still solves.
+  The ``newton`` program takes the objective as a ``newton.MarginForm``:
+  the margins ``X w + offset`` ride in the solver's state, so an iteration
+  is three passes over a bin's features (``X step``, ``Xᵀ dz``,
+  ``Xᵀ D X``) however long the slowest entity's line search backtracks —
+  a trial is the loss along ``z + t X step``, not a value and a gradient
+  over the features for every entity of the bin.  One bin program of
+  ``game_fit`` alone on the v5e, ``[25,238, 256, 16]``: 118.9 ms a call
+  before, 44.6 ms after (PERF.md, PR 39).  The three products run over
+  ``[B, rows, dim]`` (the compiler puts the rows on the lanes and the
+  Hessian on the MXU) unless ``newton.reduction_kind`` says ``lanes``
+  (``solves.reductions{kind}``): a dense bin at the lane dims with fewer
+  rows an entity than a register has lanes and at least 128 entities a
+  device is padded to a multiple of 128 entities a device and its
+  features turned to ``[rows, dim, B]`` once a call
+  (:func:`_entity_solve_newton`, scope ``newton/to_lanes``), and the
+  products are float32 products and sums over the non-lane axes
+  (``objective._lane_form``): ``[13,124, 32, 16]`` 23.3 ms before, 19.6 ms
+  with the margins carried, 10.3 ms entity-minor.
 - **Solver cache** — :func:`cached_newton_solver` mirrors
   ``core.problem.cached_solver``: one traced program per static
   (optimizer-config, variance) pair, module-cached, the objective riding
@@ -56,11 +74,19 @@ import functools
 import os
 
 import jax
+import jax.numpy as jnp
 
 from photon_tpu.core.optimizers import OptimizerConfig
-from photon_tpu.core.optimizers.newton import factorization_kind, newton
+from photon_tpu.core.optimizers.newton import (
+    LANES,
+    MarginForm,
+    factorization_kind,
+    newton,
+    reduction_kind,
+)
 from photon_tpu.core.optimizers.newton_cg import newton_cg
 from photon_tpu.core.problem import ProblemConfig, _compute_variances, hvp_at_for
+from photon_tpu.data.batch import DenseBatch
 from photon_tpu.models.glm import Coefficients
 
 
@@ -140,21 +166,74 @@ def solver_route(problem: ProblemConfig, solve_dim: int,
 
 
 def _run_newton_fit(objective, batch, w0, *, cfg: OptimizerConfig,
-                    variance: str):
+                    variance: str, lanes: bool = False):
     """One damped-Newton GLM fit, pure in (objective, batch, w0) — the body
     :func:`cached_newton_solver` vmaps and compiles.  Mirrors
     ``core.problem._run_fit``: the objective is a pytree argument, and the
     variance computation is the SAME ``_compute_variances`` formula the
-    iterative path runs, so means AND variances agree at convergence."""
-    fun = lambda w: objective.value_and_grad(w, batch)  # noqa: E731
-    result = newton(
-        fun, w0, cfg, hess=lambda w: objective.hessian_matrix(w, batch)
+    iterative path runs, so means AND variances agree at convergence.  The
+    objective goes in as a ``newton.MarginForm`` (the solver carries the
+    margins: three passes over the features an iteration); ``lanes``: its
+    dense products in the form that puts a mapped axis on the lanes."""
+    form = MarginForm(
+        margins=lambda w: objective.margins(w, batch, lanes),
+        direction=lambda v: objective.direction_margins(v, batch, lanes),
+        value=lambda z, w: objective.value_at_margins(z, w, batch),
+        grad=lambda z, w: objective.grad_at_margins(z, w, batch, lanes),
+        hess=lambda z, w: objective.hessian_at_margins(z, w, batch, lanes),
     )
+    result = newton(None, w0, cfg, form=form)
     coefficients = Coefficients(
         means=result.w,
         variances=_compute_variances(objective, variance, result.w, batch),
     )
     return coefficients, result
+
+
+def _device_entities(leaf, shards: int, width: int):
+    """``[B, ...]`` with each of the ``shards`` contiguous entity blocks
+    padded with zeros or cut back to ``width`` entities: no entity changes
+    its block, so under a mesh nothing moves between devices."""
+    local = leaf.shape[0] // shards
+    if local == width:
+        return leaf
+    leaf = leaf.reshape(shards, local, *leaf.shape[1:])
+    if width < local:
+        leaf = leaf[:, :width]
+    else:
+        leaf = jnp.pad(
+            leaf, ((0, 0), (0, width - local)) + ((0, 0),) * (leaf.ndim - 2)
+        )
+    return leaf.reshape(shards * width, *leaf.shape[2:])
+
+
+def _entity_solve_newton(objective, batch, w0, *, run, entity_shards: int):
+    """``run`` over the entity axis of a bin.  Where
+    ``newton.reduction_kind`` says ``lanes`` the bin is first turned
+    entity-minor under the scope ``newton/to_lanes`` — once a call, outside
+    the solver's loops, which take the turned features as a loop-invariant
+    operand — each device's entities padded to a multiple of
+    ``newton.LANES`` with empty ones (zero rows, ``w0 = 0``: converged at
+    the start, cut off again at the end)."""
+    entities, dim = w0.shape
+    local = entities // entity_shards
+    dense = isinstance(batch, DenseBatch)
+    if reduction_kind(dense, dim, local, batch.label.shape[1]) != "lanes":
+        return jax.vmap(run, in_axes=(None, 0, 0))(objective, batch, w0)
+    width = -(-local // LANES) * LANES
+    with jax.named_scope("newton/to_lanes"):
+        batch, w0 = jax.tree.map(
+            lambda leaf: _device_entities(leaf, entity_shards, width),
+            (batch, w0),
+        )
+        batch = batch._replace(x=jnp.moveaxis(batch.x, 0, -1))
+    out = jax.vmap(
+        functools.partial(run, lanes=True),
+        in_axes=(None, DenseBatch(x=-1, label=0, offset=0, weight=0), 0),
+    )(objective, batch, w0)
+    return jax.tree.map(
+        lambda leaf: _device_entities(leaf, entity_shards, local), out
+    )
 
 
 def cached_newton_solver(problem: ProblemConfig):
@@ -163,7 +242,9 @@ def cached_newton_solver(problem: ProblemConfig):
     OptimizerResult)`` mapped over a leading entity axis.  Module-cached
     like ``core.problem.cached_solver`` — every coordinate and sweep config
     with the same static (optimizer config, variance) shares one traced
-    program, and jit's own cache keys on bin shapes."""
+    program, and jit's own cache keys on bin shapes.  ``entity_shards``
+    (static, by keyword; default 1) is the number of devices the entity
+    axis is split over, so that the lane form pads each device's block."""
     return _cached_newton_solver(
         problem.optimizer_config, problem.variance_computation
     )
@@ -174,8 +255,14 @@ def _cached_newton_solver(cfg: OptimizerConfig, variance: str):
     from photon_tpu.utils.device import named_jit
 
     run = functools.partial(_run_newton_fit, cfg=cfg, variance=variance)
+
+    def program(objective, batch, w0, entity_shards=1):
+        return _entity_solve_newton(
+            objective, batch, w0, run=run, entity_shards=entity_shards
+        )
+
     return named_jit(
-        "entity_solve_newton", jax.vmap(run, in_axes=(None, 0, 0))
+        "entity_solve_newton", program, static_argnames=("entity_shards",)
     )
 
 
@@ -224,7 +311,8 @@ def _cached_newton_cg_solver(cfg: OptimizerConfig, variance: str):
 
 
 def record_bin_telemetry(telemetry, coordinate: str, bin_stats: list,
-                         routes: list, solve_dims: list) -> None:
+                         routes: list, solve_dims: list, dense: list,
+                         entity_shards: int = 1) -> None:
     """Export the bin layout's padding economics as gauges — the ISSUE 8
     observability satellite: ``solves.bin_occupancy`` (LIVE entities per
     bin), ``solves.bin_entities_padded`` (mesh-padding slots), and
@@ -238,9 +326,13 @@ def record_bin_telemetry(telemetry, coordinate: str, bin_stats: list,
     instead of being inferred from timings.  ``solves.factorization{kind}``
     counts the LIVE entities of each ``newton`` bin by the form its
     factor-and-solve takes at the bin's static solve dim
-    (``newton.factorization_kind``: ``lanes`` or ``xla``)."""
-    for b, (stats, route, dim) in enumerate(
-        zip(bin_stats, routes, solve_dims)
+    (``newton.factorization_kind``: ``lanes`` or ``xla``), and
+    ``solves.reductions{kind}`` the same entities by the form of the bin's
+    three dense products (``newton.reduction_kind``: ``lanes`` or ``rows``,
+    from ``dense``, the solve dim, the bin's row capacity and its entities a
+    device)."""
+    for b, (stats, route, dim, is_dense) in enumerate(
+        zip(bin_stats, routes, solve_dims, dense)
     ):
         labels = dict(
             coordinate=coordinate, bin=str(b),
@@ -253,6 +345,13 @@ def record_bin_telemetry(telemetry, coordinate: str, bin_stats: list,
             telemetry.counter(
                 "solves.factorization", coordinate=coordinate,
                 kind=factorization_kind(dim),
+            ).inc(stats["live_entities"])
+            telemetry.counter(
+                "solves.reductions", coordinate=coordinate,
+                kind=reduction_kind(
+                    is_dense, dim, stats["total_entities"] // entity_shards,
+                    stats["capacity"],
+                ),
             ).inc(stats["live_entities"])
         telemetry.gauge("solves.bin_occupancy", **labels).set(
             stats["live_entities"]

@@ -1629,7 +1629,8 @@ class RandomEffectCoordinate:
             from photon_tpu.game.batched_solve import cached_newton_solver
 
             return cached_newton_solver(self.config.problem)(
-                self.problem.objective, batch, w0
+                self.problem.objective, batch, w0,
+                entity_shards=mesh_shards(self.mesh),
             )
         if route == "newton_cg":
             from photon_tpu.game.batched_solve import cached_newton_cg_solver
@@ -1717,6 +1718,8 @@ class RandomEffectCoordinate:
                 getattr(self, "fault_name", self.config.shard_name),
                 self.device_data.bin_stats, routes,
                 [dev["solve_dim"] for dev in self.device_data.device_buckets],
+                dense=[dev["dense"] for dev in self.device_data.device_buckets],
+                entity_shards=mesh_shards(self.mesh),
             )
             self._bins_recorded = len(routes)
         for i, bucket in enumerate(self.device_data.buckets):

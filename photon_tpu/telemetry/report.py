@@ -389,14 +389,17 @@ def _render_entity_solves_section(report: dict) -> list:
             f"| {_fmt(cells.get((coord, b)))} |"
         )
     # Which form the Newton bins' factor-and-solve took (by static solve
-    # dim: core.optimizers.newton.factorization_kind), in live entities.
-    forms = _counter_totals(
-        report, "solves.factorization", "coordinate", "kind")
-    if forms:
-        lines += ["", "| coordinate | factorization | live entities |",
-                  "|---|---|---|"]
-        for (coord, kind), n in sorted(forms.items()):
-            lines.append(f"| {coord} | {kind} | {_fmt(n)} |")
+    # dim: core.optimizers.newton.factorization_kind) and which their
+    # margin / gradient / Hessian sums (newton.reduction_kind), in live
+    # entities.
+    for counter, column in (("solves.factorization", "factorization"),
+                            ("solves.reductions", "reductions")):
+        forms = _counter_totals(report, counter, "coordinate", "kind")
+        if forms:
+            lines += ["", f"| coordinate | {column} | live entities |",
+                      "|---|---|---|"]
+            for (coord, kind), n in sorted(forms.items()):
+                lines.append(f"| {coord} | {kind} | {_fmt(n)} |")
     return lines
 
 

@@ -15,6 +15,7 @@ suite always used for cross-solver comparisons).
 import contextlib
 import functools
 import os
+import re
 import types
 
 import jax
@@ -837,6 +838,230 @@ def test_batched_newton_matches_unbatched_entity_by_entity(task, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# The lane form of a dense bin's sums (ISSUE 39): margins, gradient and
+# Hessian with the entities on the minor axis
+# ---------------------------------------------------------------------------
+
+_LANE_TASKS = {
+    "logistic": "logistic_regression", "poisson": "poisson_regression",
+    "linear": "linear_regression",
+}
+
+
+def _lane_bin(task, d, entities=130, rows=8, seed=0):
+    """A dense bin whose entity count is no multiple of 128, with 1-3
+    zero-weight pad rows an entity (zero features, as the bins pad)."""
+    from photon_tpu.data.batch import DenseBatch
+
+    rng = np.random.default_rng(seed)
+    live = (
+        np.arange(rows)[None, :] < rng.integers(rows - 3, rows, entities)[:, None]
+    ).astype(np.float32)
+    x = (0.5 * rng.normal(size=(entities, rows, d))).astype(np.float32)
+    x[:, :, 0] = 1.0  # the intercept a shift needs
+    x *= live[:, :, None]
+    z = np.einsum("brd,bd->br", x, 0.3 * rng.normal(size=(entities, d)))
+    if task == "logistic":
+        label = rng.random((entities, rows)) < 1 / (1 + np.exp(-z))
+    elif task == "poisson":
+        label = rng.poisson(np.exp(np.clip(z, -3, 2)))
+    else:
+        label = z + 0.1 * rng.normal(size=z.shape)
+    return DenseBatch(
+        x=jnp.asarray(x), label=jnp.asarray(label * live, jnp.float32),
+        offset=jnp.asarray(0.1 * rng.normal(size=z.shape) * live, jnp.float32),
+        weight=jnp.asarray(live),
+    )
+
+
+def _lane_objective(task, d, normalized):
+    from photon_tpu.core.normalization import NormalizationContext
+    from photon_tpu.core.objective import GlmObjective
+
+    rng = np.random.default_rng(7)
+    norm = None
+    if normalized:
+        norm = NormalizationContext(
+            factors=jnp.asarray(rng.uniform(0.5, 2.0, d), jnp.float32).at[0].set(1.0),
+            shifts=jnp.asarray(0.2 * rng.normal(size=d), jnp.float32).at[0].set(0.0),
+            intercept_id=0,
+        )
+    return GlmObjective.create(
+        _LANE_TASKS[task], RegularizationContext("l2", 1.0), normalization=norm
+    )
+
+
+def _at_margins(objective, lanes):
+    """Value, gradient and Hessian of one entity through the margins, as
+    ``newton.MarginForm`` evaluates them."""
+    def evaluate(w, batch):
+        z = objective.margins(w, batch, lanes)
+        return (
+            objective.value_at_margins(z, w, batch),
+            objective.grad_at_margins(z, w, batch, lanes),
+            objective.hessian_at_margins(z, w, batch, lanes),
+        )
+    return evaluate
+
+
+@pytest.mark.parametrize("normalized", [False, True], ids=["plain", "shift+factor"])
+@pytest.mark.parametrize("d", [8, 16, 32, 40])
+@pytest.mark.parametrize("task", ["logistic", "poisson", "linear"])
+def test_lane_form_matches_the_unbatched_expressions(task, d, normalized):
+    """Value, gradient and Hessian through the margins, in the lane form
+    under ``vmap`` (features mapped at their minor axis) and in the rows
+    form, against ``value_and_grad`` / ``hessian_matrix`` one entity at a
+    time; and the fit through ``cached_newton_solver`` (``lanes`` up to
+    ``LANES_MAX_DIM``, the ``rows`` form at 40) against ``newton`` over
+    those expressions, to the file's parity bound."""
+    from photon_tpu.core.optimizers.newton import newton, reduction_kind
+    from photon_tpu.data.batch import DenseBatch
+
+    batch = _lane_bin(task, d)
+    entities = batch.x.shape[0]
+    objective = _lane_objective(task, d, normalized)
+    w = jnp.asarray(
+        0.2 * np.random.default_rng(1).normal(size=(entities, d)), jnp.float32)
+    lanes = jax.jit(jax.vmap(
+        _at_margins(objective, True),
+        in_axes=(0, DenseBatch(x=-1, label=0, offset=0, weight=0)),
+    ))(w, batch._replace(x=jnp.moveaxis(batch.x, 0, -1)))
+    rows = jax.jit(jax.vmap(_at_margins(objective, False)))(w, batch)
+    one = jax.jit(lambda w, b: (
+        *objective.value_and_grad(w, b), objective.hessian_matrix(w, b)))
+    for e in (0, 57, entities - 1):
+        want = one(w[e], jax.tree.map(lambda leaf: leaf[e], batch))
+        for form in (lanes, rows):
+            for got, ref in zip(form, want):
+                scale = float(jnp.max(jnp.abs(ref)))
+                np.testing.assert_allclose(
+                    got[e], ref, rtol=1e-5, atol=1e-5 * scale)
+    # One entity, not under vmap: the same closed form, no batch axis.
+    alone = _at_margins(objective, True)(
+        w[3], jax.tree.map(lambda leaf: leaf[3], batch))
+    for got, ref in zip(alone, lanes):
+        np.testing.assert_allclose(got, ref[3], rtol=1e-5, atol=1e-5)
+
+    kind = reduction_kind(True, d, entities, batch.label.shape[1])
+    assert kind == ("lanes" if d <= 32 else "rows")
+    problem = _problem()
+    w0 = jnp.zeros((entities, d), jnp.float32)
+    solver = cached_newton_solver(problem)
+    # The program's own text says which form it took: the rows form's
+    # products are dot_generals, the lane form has none.
+    assert ("dot_general" in solver.lower(objective, batch, w0).as_text()) == (
+        kind == "rows")
+    coefficients, result = solver(objective, batch, w0)
+    assert coefficients.means.shape == (entities, d)
+    assert result.iterations.shape == (entities,)
+    assert bool(np.all(np.asarray(result.converged)))
+    # ``newton`` over value_and_grad / hessian_matrix, no margins carried.
+    single = jax.jit(lambda b, w0: newton(
+        lambda w: objective.value_and_grad(w, b), w0,
+        problem.optimizer_config,
+        hess=lambda w: objective.hessian_matrix(w, b),
+    ).w)
+    for e in (0, 57, entities - 1):
+        want = single(jax.tree.map(lambda leaf: leaf[e], batch), w0[e])
+        np.testing.assert_allclose(
+            np.asarray(coefficients.means[e]), np.asarray(want),
+            atol=1e-6 * max(1.0, float(jnp.max(jnp.abs(want)))), rtol=0,
+        )
+
+
+@pytest.mark.parametrize("storage", ["dense", "sparse"])
+@pytest.mark.parametrize("task", ["logistic", "poisson", "linear"])
+def test_newton_margin_form_lands_on_the_same_optimum(task, storage):
+    """``newton`` over a ``MarginForm`` (margins carried, a trial the value
+    along ``z + t X step``) against ``newton`` over value_and_grad /
+    hessian_matrix (a trial a value and a gradient at ``w + t step``): the
+    same optimum after as many iterations and trials, for dense and sparse
+    rows."""
+    from photon_tpu.core.optimizers.newton import MarginForm, newton
+    from photon_tpu.data.batch import SparseBatch
+
+    d = 8
+    batch = jax.tree.map(lambda leaf: leaf[5], _lane_bin(task, d, entities=8))
+    if storage == "sparse":
+        rows = batch.x.shape[0]
+        batch = SparseBatch(
+            ids=jnp.tile(jnp.arange(d, dtype=jnp.int32), (rows, 1)),
+            vals=batch.x, label=batch.label, offset=batch.offset,
+            weight=batch.weight,
+        )
+    objective = _lane_objective(task, d, normalized=(storage == "dense"))
+    cfg = _problem().optimizer_config
+    w0 = jnp.zeros(d, jnp.float32)
+    plain = jax.jit(lambda w0: newton(
+        lambda w: objective.value_and_grad(w, batch), w0, cfg,
+        hess=lambda w: objective.hessian_matrix(w, batch),
+    ))(w0)
+    carried = jax.jit(lambda w0: newton(None, w0, cfg, form=MarginForm(
+        margins=lambda w: objective.margins(w, batch),
+        direction=lambda v: objective.direction_margins(v, batch),
+        value=lambda z, w: objective.value_at_margins(z, w, batch),
+        grad=lambda z, w: objective.grad_at_margins(z, w, batch),
+        hess=lambda z, w: objective.hessian_at_margins(z, w, batch),
+    )))(w0)
+    # (The last iteration sits on float32's rounding of a 1e-8 gradient
+    # tolerance: one more or less.)
+    assert abs(int(carried.iterations) - int(plain.iterations)) <= 1
+    assert int(plain.iterations) > 1
+    assert bool(carried.converged) and bool(plain.converged)
+    assert abs(int(carried.evaluations) - int(plain.evaluations)) <= 3
+    np.testing.assert_allclose(carried.w, plain.w, atol=1e-6, rtol=0)
+    np.testing.assert_allclose(carried.value, plain.value, rtol=1e-6)
+
+
+@pytest.mark.parametrize("variance", ["simple", "full"])
+def test_lane_form_variances_match_the_rows_form(variance):
+    from photon_tpu.game.batched_solve import _run_newton_fit
+
+    batch = _lane_bin("logistic", 8)
+    objective = _lane_objective("logistic", 8, False)
+    problem = _problem(variance=variance)
+    w0 = jnp.zeros(batch.x.shape[::2], jnp.float32)
+    got, _ = cached_newton_solver(problem)(objective, batch, w0)
+    want, _ = jax.jit(jax.vmap(functools.partial(
+        _run_newton_fit, cfg=problem.optimizer_config, variance=variance,
+    ), in_axes=(None, 0, 0)))(objective, batch, w0)  # lanes=False: rows
+    np.testing.assert_allclose(got.variances, want.variances, rtol=1e-5)
+    np.testing.assert_allclose(got.means, want.means, atol=1e-6, rtol=0)
+
+
+def test_lane_form_on_a_mesh_pads_each_device_and_moves_nothing():
+    """Four CPU devices, the entity axis sharded: each device's 130
+    entities are padded to 256 in place, the fit is the one-device fit, and
+    the compiled program holds no collective."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
+    batch = _lane_bin("logistic", 16, entities=4 * 130)
+    objective = _lane_objective("logistic", 16, False)
+    w0 = jnp.zeros((4 * 130, 16), jnp.float32)
+    want, _ = cached_newton_solver(_problem())(objective, batch, w0)
+    placed, placed_w0 = jax.tree.map(
+        lambda leaf: jax.device_put(leaf, NamedSharding(
+            mesh, P("data", *([None] * (leaf.ndim - 1))))),
+        (batch, w0),
+    )
+    solver = cached_newton_solver(_problem())
+    got, result = solver(objective, placed, placed_w0, entity_shards=4)
+    assert got.means.sharding.is_equivalent_to(placed_w0.sharding, 2)
+    np.testing.assert_allclose(got.means, want.means, atol=1e-6, rtol=0)
+    assert bool(np.all(np.asarray(result.converged)))
+    text = solver.lower(
+        objective, placed, placed_w0, entity_shards=4).compile().as_text()
+    assert "f32[8,16,256]" in text  # a device's slab: 130 entities -> 256
+    for collective in ("all-gather", "all-to-all", "collective-permute",
+                       "reduce-scatter"):
+        assert f" {collective}(" not in text, collective
+    # What crosses devices is the lockstep loops' "any lane active?" alone.
+    reduced = set(re.findall(r"= (\S+) all-reduce\(", text))
+    assert reduced <= {"pred[]"}, reduced
+
+
+# ---------------------------------------------------------------------------
 # Telemetry + report
 # ---------------------------------------------------------------------------
 
@@ -860,6 +1085,47 @@ def test_bin_telemetry_gauges():
         if name == "solves.padded_fraction":
             assert 0.0 <= g["value"] < 1.0
         assert g["labels"]["route"] == "newton"
+
+
+def test_reductions_counter_follows_density_dim_and_entities(monkeypatch):
+    """``solves.reductions{coordinate,kind}`` counts each ``newton`` bin's
+    live entities once, under the form of its dense products: ``lanes``
+    for a dense bin under 128 rows an entity, of at least 128 entities a
+    device, at d <= 32, ``rows`` else; the run report's "Entity solves"
+    section shows it."""
+    from photon_tpu.telemetry.report import render_markdown
+
+    def kinds(session):
+        return {
+            c["labels"]["kind"]: c["value"]
+            for c in session.registry.snapshot()["counters"]
+            if c["name"] == "solves.reductions"
+        }
+
+    monkeypatch.setenv("PHOTON_SOLVE_MAX_BINS", "1")
+    monkeypatch.setenv("PHOTON_SOLVE_BIN_WASTE", "1000")
+    session = TelemetrySession("t-reductions")
+    data = _dataset(n_entities=150, dim=6)
+    coord, model, _ = _train(data, _config(), telemetry=session)
+    assert len(coord.device_data.buckets) == 1
+    assert kinds(session) == {"lanes": 150}
+    # The same fit with the bucket loop (bins under 128 entities): rows.
+    small = TelemetrySession("t-reductions-rows")
+    _, loop_model, _ = _train(data, _config(), telemetry=small, binning="off")
+    assert set(kinds(small)) <= {"lanes", "rows"} and kinds(small)["rows"] > 0
+    assert sum(kinds(small).values()) == 150
+    np.testing.assert_allclose(
+        np.asarray(model.table), np.asarray(loop_model.table), atol=1e-5)
+    wide = TelemetrySession("t-reductions-wide")
+    _train(_dataset(n_entities=150, dim=40), _config(), telemetry=wide)
+    assert kinds(wide) == {"rows": 150}
+    text = render_markdown({
+        "driver": "t", "run_id": "r", "status": "ok", "duration_s": 1.0,
+        "metrics": session.registry.snapshot(),
+    })
+    section = text[text.index("## Entity solves"):]
+    assert "| coordinate | reductions | live entities |" in section
+    assert "| per_entity | lanes | 150 |" in section
 
 
 def test_report_renders_entity_solves_section():

@@ -788,6 +788,108 @@ def test_entity_solve_newton_hlo_carries_program_and_scope_names():
     )
 
 
+def _lane_solver_lowered(entities=130, rows=8, d=16):
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import ProblemConfig
+    from photon_tpu.data.batch import DenseBatch
+    from photon_tpu.game.batched_solve import cached_newton_solver
+
+    reg = RegularizationContext("l2", 1.0)
+    config = ProblemConfig(
+        regularization=reg, optimizer_config=OptimizerConfig(max_iterations=4)
+    )
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    batch = DenseBatch(
+        shape(entities, rows, d), shape(entities, rows),
+        shape(entities, rows), shape(entities, rows),
+    )
+    return cached_newton_solver(config).lower(
+        GlmObjective.create("logistic_regression", reg), batch,
+        shape(entities, d),
+    )
+
+
+def test_entity_solve_newton_lane_form_scopes_and_loop_bodies():
+    """A dense d = 16 bin of 130 entities (ISSUE 39): the program carries
+    the solve's scopes and ``newton/to_lanes``; the turn to entity-minor
+    happens at the entry, so inside the loops no ``dot_general`` is left
+    and the only transposes of the features are the pair ``vmap`` and the
+    lane rule make of each other (identity, folded by XLA)."""
+    lowered = _lane_solver_lowered()
+    module, ops = _hlo(lowered)
+    assert module == "HloModule jit_entity_solve_newton"
+    assert _scopes(ops, "entity_solve_newton") >= {
+        "newton/hessian", "newton/cholesky", "newton/step",
+        "newton/direction", "newton/line_search", "newton/gradient",
+        "newton/to_lanes", "valuegrad/margins", "valuegrad/loss",
+        "valuegrad/grad",
+    }
+    text = lowered.as_text(dialect="hlo")
+    computations = re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ (?:\([^)]*\) -> |\{))", text)
+    entry = [c for c in computations if c.startswith("ENTRY")]
+    assert len(entry) == 1
+    features = 8 * 16 * 256  # rows x dim x 130 entities padded to 256
+    # The Newton loop, the line search's inside it, the polish steps'.
+    assert text.count(" while(") == 3
+    for computation in computations:
+        if computation.startswith("ENTRY"):
+            continue
+        assert " dot(" not in computation, computation[:200]
+        # name -> (operand, permutation) of each features-sized transpose
+        turns = {
+            m.group(1): (m.group(3), tuple(int(i) for i in m.group(4).split(",")))
+            for m in re.finditer(
+                r"(?m)^\s*(?:ROOT )?(\S+) = f32\[([\d,]+)\][^ ]* "
+                r"transpose\((?:[^ ]+ )?(\S+?)\), dimensions=\{([\d,]+)\}",
+                computation,
+            )
+            if np.prod([int(n) for n in m.group(2).split(",")]) == features
+        }
+        paired = set()
+        for name, (operand, second) in turns.items():
+            if operand in turns:
+                first = turns[operand][1]
+                assert tuple(first[i] for i in second) == (0, 1, 2)
+                paired |= {name, operand}
+        assert paired == set(turns), sorted(set(turns) - paired)
+    # The entry holds the one real turn (scope ``newton/to_lanes``, above).
+    assert re.search(r"= f32\[8,16,256\]\S* transpose\(", entry[0])
+
+
+def _text_hash(text: str) -> str:
+    import hashlib
+
+    # Source locations and line numbers are not the program.
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def test_rows_form_programs_are_the_parents():
+    """What ISSUE 39 leaves alone keeps its program, opcode for opcode: the
+    fixed effect's ``glm_fit_lbfgs`` over a dense d = 128 batch and an
+    unbatched ``hessian_matrix`` (the hashes are the parent commit's,
+    54fc018, over the HLO text without its source metadata)."""
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
+    from photon_tpu.data.batch import DenseBatch
+
+    reg = RegularizationContext("l2", 1.0)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    batch = DenseBatch(shape(64, 128), shape(64), shape(64), shape(64))
+    objective = GlmObjective.create("logistic_regression", reg)
+    problem = GlmOptimizationProblem(objective, ProblemConfig(
+        regularization=reg, optimizer_config=OptimizerConfig(max_iterations=3),
+    ))
+    fit = problem.solver().lower(objective, batch, shape(128))
+    assert _text_hash(fit.as_text(dialect="hlo")) == "68157ddbbfb91127"
+    small = DenseBatch(shape(8, 16), shape(8), shape(8), shape(8))
+    hessian = jax.jit(objective.hessian_matrix).lower(shape(16), small)
+    assert _text_hash(hessian.as_text(dialect="hlo")) == "11766d26094c5fb9"
+
+
 def test_published_program_names():
     from photon_tpu.evaluation import metrics
     from photon_tpu.game import coordinate, model, residuals
