@@ -85,9 +85,10 @@ class OptimizerResult(NamedTuple):
     history_value: Array  # [max_iter+1]
     history_grad_norm: Array  # [max_iter+1]
     history_valid: Array  # [max_iter+1] bool
-    # int32 total inner-CG iterations, set only by solvers with a CG inner
-    # loop (newton_cg); None elsewhere — a None leaf is an empty pytree
-    # subtree, so existing jit/vmap programs are unchanged.
+    # int32 total inner-CG iterations (one Hessian-vector product each),
+    # set by the solvers with a CG inner loop (tron, newton_cg); None
+    # elsewhere — a None leaf is an empty pytree subtree, so the other
+    # solvers' jit/vmap programs are unchanged.
     cg_iterations: Array | None = None
     # int32 objective (value+grad) evaluations the fit ran: the initial
     # point, every line-search trial, the final polish steps.  What the
@@ -99,6 +100,10 @@ class OptimizerResult(NamedTuple):
     # first step is accepted; each backtrack adds one).  None from solvers
     # with no line search (TRON).
     line_search_steps: Array | None = None
+    # int32 trust-region trials rejected (the step not taken, the radius
+    # shrunk); each is still an evaluation and an iteration.  Set by TRON
+    # alone.
+    trust_region_rejections: Array | None = None
 
 
 def _optional_int(count) -> int | None:

@@ -12,6 +12,15 @@ reference's ``HessianVectorAggregator`` treeAggregate collapsed into the same
 XLA program as the outer loop.  Both loops are masked ``lax.while_loop``s, so
 TRON vmaps for batched per-entity GAME solves.
 
+What it reports besides the iterate: ``evaluations`` (the initial point and
+one trial a trust-region iteration), ``cg_iterations`` (the CG steps that
+ran, one Hessian-vector product each; a finished, masked iteration adds
+none) and ``trust_region_rejections`` (trials whose reduction the model did
+not predict well enough, so ``w`` stayed).  Its phases carry named scopes:
+``tron/curvature`` (the operator's per-row curvature at ``w``), ``tron/cg``
+(the CG loop and its products) and ``tron/trial`` (the evaluation at
+``w + s``).
+
 Departure from liblinear noted for reviewers: rejected trust-region trials
 count against ``max_iterations`` here (the loop must be bounded for XLA);
 liblinear only counts accepted steps.  With the standard radius-shrink logic
@@ -56,7 +65,8 @@ class _CGState(NamedTuple):
 def _trcg(hvp, g, delta, max_cg, active, cg_tolerance=0.1):
     """LIBLINEAR trcg: approximately solve H s = -g with ||s|| <= delta.
 
-    Returns (s, r, at_boundary) where r = -g - H s is the residual."""
+    Returns (s, r, at_boundary, steps) where r = -g - H s is the residual
+    and ``steps`` the CG steps that ran (0 where ``active`` is false)."""
     cg_tol = cg_tolerance * jnp.linalg.norm(g)
 
     def cond(c: _CGState):
@@ -115,7 +125,7 @@ def _trcg(hvp, g, delta, max_cg, active, cg_tolerance=0.1):
         at_boundary=jnp.asarray(False),
     )
     final = lax.while_loop(cond, body, init)
-    return final.s, final.r, final.at_boundary
+    return final.s, final.r, final.at_boundary, final.it
 
 
 class _State(NamedTuple):
@@ -130,6 +140,8 @@ class _State(NamedTuple):
     hv: Array
     hg: Array
     hvalid: Array
+    cg_steps: Array
+    rejections: Array
 
 
 def tron(
@@ -181,6 +193,8 @@ def tron(
             conv0, ConvergenceReason.GRADIENT_TOLERANCE, ConvergenceReason.NOT_CONVERGED
         ).astype(jnp.int32),
         hv=hv0, hg=hg0, hvalid=hvalid0,
+        cg_steps=jnp.asarray(0, jnp.int32),
+        rejections=jnp.asarray(0, jnp.int32),
     )
 
     def cond(s: _State):
@@ -189,12 +203,16 @@ def tron(
     def body(s: _State):
         # ONE curvature operator per outer iteration: the precomputed-
         # curvature closure's margin pass runs here, not per CG product.
-        step, resid, _ = _trcg(
-            hvp_at(s.w), s.g, s.delta, max_cg, s.active,
-            cg_tolerance=config.cg_tolerance,
-        )
+        with jax.named_scope("tron/curvature"):
+            hv_op = hvp_at(s.w)
+        with jax.named_scope("tron/cg"):
+            step, resid, _, cg_steps = _trcg(
+                hv_op, s.g, s.delta, max_cg, s.active,
+                cg_tolerance=config.cg_tolerance,
+            )
         w_new = s.w + step
-        f_new, g_new = fun(w_new)
+        with jax.named_scope("tron/trial"):
+            f_new, g_new = fun(w_new)
 
         gs = jnp.dot(s.g, step)
         prered = -0.5 * (gs - jnp.dot(step, resid))
@@ -254,6 +272,8 @@ def tron(
             active=still_active,
             reason=reason.astype(jnp.int32),
             hv=hv, hg=hg, hvalid=hvalid,
+            cg_steps=s.cg_steps + cg_steps,
+            rejections=s.rejections + (~accept).astype(jnp.int32),
         )
         return tree_where(s.active, new, s)
 
@@ -271,4 +291,6 @@ def tron(
         # The initial point plus one trial point per trust-region iteration
         # (TRON has no line search: line_search_steps stays None).
         evaluations=final.it + 1,
+        cg_iterations=final.cg_steps,
+        trust_region_rejections=final.rejections,
     )

@@ -194,10 +194,11 @@ class GlmOptimizationProblem:
             registry.counter("optimizer.evaluations").inc_deferred(
                 result.evaluations
             )
-            if result.line_search_steps is not None:
-                registry.counter("optimizer.line_search_steps").inc_deferred(
-                    result.line_search_steps
-                )
+            for name in ("line_search_steps", "cg_iterations",
+                         "trust_region_rejections"):
+                count = getattr(result, name)
+                if count is not None:
+                    registry.counter(f"optimizer.{name}").inc_deferred(count)
         return coefficients, result
 
     def compute_variances(self, w: Array, batch: Batch) -> Optional[Array]:

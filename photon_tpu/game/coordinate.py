@@ -111,8 +111,8 @@ def _accumulate_solve_stats(
     masked out of every component, so they can never inflate ``entities``
     or ``converged``; a quarantined (non-finite) entity is not counted
     converged either — its "solution" was discarded.  ``cg_iterations``
-    (per-entity inner-CG totals, Newton-CG bins only — see
-    ``OptimizerResult.cg_iterations``) sums into the ``cg_iters`` slot,
+    (per-entity inner-CG totals, from the bins whose solver has a CG
+    inner loop, Newton-CG or TRON — see ``OptimizerResult.cg_iterations``) sums into the ``cg_iters`` slot,
     and the SAME bins' real entities into ``cg_entities`` — the correct
     per-entity-mean denominator when a coordinate mixes CG and non-CG
     bins (projected buckets can differ in solve_dim); other routes

@@ -694,7 +694,7 @@ def _scopes(op_names: set, program: str) -> set:
         if f"jit({program})" in name:
             found |= set(re.findall(
                 r"((?:valuegrad|lbfgs|newton|fm|pallas|residuals|validation"
-                r"|score_fixed)/[a-z_]+)", name,
+                r"|score_fixed|tron|blocked)/[a-z_]+)", name,
             ))
     return found
 
@@ -751,6 +751,139 @@ def test_glm_fit_lbfgs_hlo_carries_program_and_scope_names(monkeypatch):
         for name in ops["autodiff"]
     )
     assert problem.solver(vmapped=True).__name__ == "entity_fit_lbfgs"
+
+
+def test_glm_fit_tron_hlo_carries_its_phase_scopes(monkeypatch):
+    """ISSUE 40: TRON's three phases carry their scopes, and the
+    Hessian-vector product's two ``blocked`` directions run inside the CG
+    loop (``tron/cg``), the curvature's margins under ``tron/curvature``."""
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
+    from photon_tpu.data.batch import (
+        attach_feature_major,
+        sparse_batch_from_rows,
+    )
+
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "blocked")
+    jax.clear_caches()
+    rng = np.random.default_rng(0)
+    rows = [
+        (np.sort(rng.choice(64, 4, replace=False)), rng.standard_normal(4))
+        for _ in range(48)
+    ]
+    batch = attach_feature_major(sparse_batch_from_rows(
+        rows, rng.poisson(1.0, 48).astype(np.float32)
+    ), aligned_dim=64)
+    assert batch.bt is not None
+    reg = RegularizationContext("l2", 1.0)
+    problem = GlmOptimizationProblem(
+        GlmObjective.create("poisson_regression", reg),
+        ProblemConfig(optimizer="tron", regularization=reg,
+                      optimizer_config=OptimizerConfig(
+                          max_iterations=2, cg_max_iterations=3)),
+    )
+    module, ops = _hlo(problem.solver().lower(
+        problem.objective, batch, jnp.zeros(64, jnp.float32)))
+    assert module == "HloModule jit_glm_fit_tron"
+    assert _scopes(ops, "glm_fit_tron") >= {
+        "tron/curvature", "tron/cg", "tron/trial", "blocked/xw",
+        "blocked/xtdz", "valuegrad/margins", "valuegrad/grad",
+    }
+    for direction in ("blocked/xw", "blocked/xtdz"):
+        assert any(f"tron/cg/while/body/{direction}" in name
+                   for name in ops), direction
+    assert any("tron/curvature/blocked/xw" in name for name in ops)
+    assert any("tron/trial/valuegrad/grad/blocked/xtdz" in name
+               for name in ops)
+
+
+def _poisson_problem(rows: int, dim: int, seed: int):
+    """A sparse Poisson problem of ``rows`` x 8 entries over ``dim``."""
+    from photon_tpu.data.batch import SparseBatch
+
+    rng = np.random.default_rng(seed)
+    ids = np.sort(rng.integers(0, dim, (rows, 8)), axis=1).astype(np.int32)
+    vals = rng.standard_normal((rows, 8)).astype(np.float32)
+    label = rng.poisson(np.exp(vals.sum(1) / 8)).astype(np.float32)
+    return SparseBatch(
+        ids=jnp.asarray(ids), vals=jnp.asarray(vals),
+        label=jnp.asarray(label), offset=jnp.zeros(rows, jnp.float32),
+        weight=jnp.ones(rows, jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("dim", [16, 64])
+def test_tron_counts_equal_a_hand_count(dim):
+    """``cg_iterations`` is the number of Hessian-vector products that ran
+    (a finished, masked iteration adds none), ``evaluations`` the objective
+    calls, and ``trust_region_rejections`` the iterations that did not move
+    ``w`` (both problems make TRON reject trials: few rows a coefficient and
+    the exp link)."""
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.core.optimizers import OptimizerConfig, tron
+
+    batch = _poisson_problem(256, dim, seed=dim)
+    objective = GlmObjective.create(
+        "poisson_regression", RegularizationContext("l2", 1.0))
+    calls = {"hv": 0, "fun": 0}
+
+    def counted(key):
+        jax.debug.callback(lambda: calls.__setitem__(key, calls[key] + 1))
+
+    def fun(w):
+        counted("fun")
+        return objective.value_and_grad(w, batch)
+
+    def hvp_at(w):
+        op = objective.hvp_operator(w, batch)
+
+        def hv(v):
+            counted("hv")
+            return op(v)
+
+        return hv
+
+    result = tron(fun, jnp.zeros(dim, jnp.float32),
+                  OptimizerConfig(max_iterations=12, cg_max_iterations=6),
+                  hvp_at=hvp_at)
+    jax.block_until_ready(result.w)
+    jax.effects_barrier()
+    assert int(result.cg_iterations) == calls["hv"] > 0
+    assert int(result.evaluations) == calls["fun"]
+    accepted = int(np.asarray(result.history_valid).sum()) - 1
+    assert int(result.trust_region_rejections) == (
+        int(result.iterations) - accepted)
+    assert int(result.trust_region_rejections) > 0
+
+
+def test_problem_run_hands_tron_counts_to_the_process_registry():
+    """``optimizer.cg_iterations`` / ``optimizer.trust_region_rejections``
+    ride the same deferred path as ``optimizer.evaluations`` (ISSUE 40)."""
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
+
+    batch = _poisson_problem(256, 64, seed=64)
+    reg = RegularizationContext("l2", 1.0)
+    problem = GlmOptimizationProblem(
+        GlmObjective.create("poisson_regression", reg),
+        ProblemConfig(optimizer="tron", regularization=reg,
+                      optimizer_config=OptimizerConfig(
+                          max_iterations=12, cg_max_iterations=6)),
+    )
+    telemetry.process_registry().clear()
+    want = {"evaluations": 0, "cg_iterations": 0,
+            "trust_region_rejections": 0}
+    for _ in range(2):
+        _, result = problem.run(batch, dim=64)
+        for name in want:
+            want[name] += int(getattr(result, name))
+    counters = _counters(telemetry.process_registry())
+    for name, total in want.items():
+        assert counters[(f"optimizer.{name}", ())] == total, name
+    assert want["cg_iterations"] > 0 and want["trust_region_rejections"] > 0
+    assert ("optimizer.line_search_steps", ()) not in counters
 
 
 def test_entity_solve_newton_hlo_carries_program_and_scope_names():
