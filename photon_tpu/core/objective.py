@@ -28,6 +28,7 @@ from jax import tree_util
 
 from photon_tpu.core.losses import PointwiseLoss, get_loss
 from photon_tpu.core.normalization import NormalizationContext
+from photon_tpu.core.optimizers.tron import MarginForm
 from photon_tpu.data.batch import (
     LAYOUT_FIELDS,
     Batch,
@@ -222,8 +223,9 @@ class GlmObjective:
 
         return aligned_segment_grad(u, batch.al_t, batch.ids.shape[0])
 
-    def _margins_for_kernel(self, kernel: str, w: Array, batch: Batch) -> Array:
-        if not has_own_forward(kernel, batch):
+    def _margins_for_kernel(self, kernel: Optional[str], w: Array,
+                            batch: Batch) -> Array:
+        if kernel is None or not has_own_forward(kernel, batch):
             # Single home of the normalization algebra for the XLA forward.
             return self._margins(w, batch)
         if self.normalization is None:
@@ -301,17 +303,35 @@ class GlmObjective:
         never densifies, mirroring hessian_diagonal's algebra)."""
         with jax.named_scope("valuegrad/margins"):
             z = self._margins_for_kernel(kernel, w, batch)
+        dim = w.shape[0]
+        return self._data_value_and_grad_at(
+            z, batch, lambda dz: self._segment_grad(kernel, dz, batch, dim),
+            dim,
+        )
+
+    def _data_value_and_grad_at(self, z: Array, batch: Batch, xtu, dim: int
+                                ) -> tuple[Array, Array]:
+        """Data term of value+gradient from the margins ``z``: the loss over
+        the rows and one ``Xᵀ dz`` pass (``xtu``), no forward pass."""
         with jax.named_scope("valuegrad/loss"):
             v = jnp.sum(batch.weight * self.loss.value(z, batch.label))
             dz = batch.weight * self.loss.d1(z, batch.label)
         with jax.named_scope("valuegrad/grad"):
-            g = self._segment_grad(kernel, dz, batch, w.shape[0])
+            g = xtu(dz)
             norm = self.normalization
             if norm is not None:
                 if norm.shifts is not None:
                     g = g - norm.shifts * jnp.sum(dz)
-                g = g * norm.factors_or_ones(w.shape[0])
+                g = g * norm.factors_or_ones(dim)
         return v, g
+
+    def _with_l2(self, val: Array, g: Array, w: Array) -> tuple[Array, Array]:
+        if not _static_zero(self.l2_weight):
+            with jax.named_scope("valuegrad/loss"):
+                val = val + 0.5 * self.l2_weight * jnp.dot(w, w)
+            with jax.named_scope("valuegrad/grad"):
+                g = g + self.l2_weight * w
+        return val, g
 
     def _fast_data_hessian_vector(
         self, w: Array, v: Array, batch: Batch, kernel: str = "fm"
@@ -334,13 +354,8 @@ class GlmObjective:
     def value_and_grad(self, w: Array, batch: Batch) -> tuple[Array, Array]:
         kernel = self._sparse_kernel(batch, int(w.shape[0]))
         if kernel is not None:
-            val, g = self._fast_data_value_and_grad(w, batch, kernel)
-            if not _static_zero(self.l2_weight):
-                with jax.named_scope("valuegrad/loss"):
-                    val = val + 0.5 * self.l2_weight * jnp.dot(w, w)
-                with jax.named_scope("valuegrad/grad"):
-                    g = g + self.l2_weight * w
-            return val, g
+            return self._with_l2(
+                *self._fast_data_value_and_grad(w, batch, kernel), w)
         return jax.value_and_grad(self.value)(w, batch)
 
     def grad(self, w: Array, batch: Batch) -> Array:
@@ -393,48 +408,65 @@ class GlmObjective:
         λ₂ v`` — the matrix-free Newton-CG inner-loop workhorse (ISSUE 14:
         two sparse matvecs per CG iteration, never a ``[d, d]`` matrix,
         and no margin recomputation per product).  Exact for GLMs (margins
-        are linear in ``w``).  Static-layout batches route both matvecs
-        through the selected kernel (the gradient's layout trick);
-        normalized objectives and exotic batch shapes fall back to the
-        per-call jvp-of-gradient, still matrix-free."""
-        if self.normalization is not None:
+        are linear in ``w``).  Built from :meth:`tron_form`; normalized
+        objectives and exotic batch shapes fall back to the per-call
+        jvp-of-gradient, still matrix-free."""
+        form = self.tron_form(batch, int(w.shape[0]))
+        if form is None:
             return lambda v: self.hessian_vector(w, v, batch)
-        dim = int(w.shape[0])
+        op = form.curvature(form.margins(w))
+        return lambda v: op(v)[0]
+
+    def tron_form(self, batch: Batch, dim: int) -> Optional[MarginForm]:
+        """The objective as :func:`tron` carries it (``tron.MarginForm``):
+        the margins once at the start, value and gradient from margins (one
+        ``Xᵀ`` pass), and the curvature ``D = weight·d2(z)`` from margins,
+        each product ``Xᵀ(D·(X v)) + λ₂ v`` handing back its ``X v``.  Both
+        passes go through the selected kernel on a static-layout batch (the
+        gradient's layout trick), are matrix products on a dense one, gather
+        and scatter-add over 2-D ids.  ``None`` under normalization and for
+        other batch shapes, where TRON keeps ``value_and_grad`` and the
+        per-call Hessian-vector product."""
+        if self.normalization is not None:
+            return None
         kernel = self._sparse_kernel(batch, dim)
         if kernel is not None:
-            z = self._margins_for_kernel(kernel, w, batch)
-            d2w = batch.weight * self.loss.d2(z, batch.label)
-
-            def hv_kernel(v: Array) -> Array:
-                xv = self._xu_product(kernel, v, batch)
-                out = self._segment_grad(kernel, d2w * xv, batch, dim)
-                if not _static_zero(self.l2_weight):
-                    out = out + self.l2_weight * v
-                return out
-
-            return hv_kernel
-        if isinstance(batch, DenseBatch):
+            xu = lambda v: self._xu_product(kernel, v, batch)  # noqa: E731
+            xtu = lambda u: self._segment_grad(kernel, u, batch, dim)  # noqa: E731
+        elif isinstance(batch, DenseBatch):
             xu = lambda v: batch.x @ v  # noqa: E731
             xtu = lambda u: batch.x.T @ u  # noqa: E731
         elif batch.ids.ndim == 2:
             xu = lambda v: jnp.sum(  # noqa: E731
                 jnp.take(v, batch.ids, axis=0) * batch.vals, axis=-1
             )
-            xtu = lambda u: jnp.zeros(dim, w.dtype).at[batch.ids].add(  # noqa: E731
+            xtu = lambda u: jnp.zeros(dim, u.dtype).at[batch.ids].add(  # noqa: E731
                 u[:, None] * batch.vals
             )
         else:
-            return lambda v: self.hessian_vector(w, v, batch)
-        z = self._margins(w, batch)
-        d2w = batch.weight * self.loss.d2(z, batch.label)
+            return None
 
-        def hv(v: Array) -> Array:
-            out = xtu(d2w * xu(v))
-            if not _static_zero(self.l2_weight):
-                out = out + self.l2_weight * v
-            return out
+        def margins(w: Array) -> Array:
+            with jax.named_scope("valuegrad/margins"):
+                return self._margins_for_kernel(kernel, w, batch)
 
-        return hv
+        def value_and_grad(z: Array, w: Array) -> tuple[Array, Array]:
+            return self._with_l2(
+                *self._data_value_and_grad_at(z, batch, xtu, dim), w)
+
+        def curvature(z: Array):
+            d2w = batch.weight * self.loss.d2(z, batch.label)
+
+            def hv(v: Array) -> tuple[Array, Array]:
+                xv = xu(v)
+                out = xtu(d2w * xv)
+                if not _static_zero(self.l2_weight):
+                    out = out + self.l2_weight * v
+                return out, xv
+
+            return hv
+
+        return MarginForm(margins, value_and_grad, curvature)
 
     def hessian_vector_product(self, w: Array, v: Array, batch: Batch) -> Array:
         """One matrix-free ``H v`` (``Xᵀ(D(w)·(X v)) + λ₂ v``) — the

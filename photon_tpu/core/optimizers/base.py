@@ -104,6 +104,10 @@ class OptimizerResult(NamedTuple):
     # shrunk); each is still an evaluation and an iteration.  Set by TRON
     # alone.
     trust_region_rejections: Array | None = None
+    # int32 forward passes over the features (``X w``) that carried margins
+    # made unnecessary: TRON under a ``tron.MarginForm`` spares two an
+    # iteration, TRON on ``fun`` none.  None from the other solvers.
+    margin_passes_spared: Array | None = None
 
 
 def _optional_int(count) -> int | None:

@@ -81,22 +81,34 @@ def hvp_at_for(objective, batch: Batch):
 
 
 def _run_fit(objective, batch: Batch, w0: Array, *, optimizer: str,
-             cfg: OptimizerConfig, variance: str):
+             cfg: OptimizerConfig, variance: str, vmapped: bool = False):
     """One GLM fit, pure in (objective, batch, w0) — the body every cached
     solver compiles.  The objective is a PYTREE ARGUMENT (reg weights and
     normalization arrays are dynamic leaves), so one compiled program serves
     an entire lambda sweep / hyperparameter search; only shapes, the loss,
-    the optimizer, and its static config retrace."""
+    the optimizer, and its static config retrace.  ``vmapped``: the body of
+    the per-entity bucket solve."""
     fun = lambda w: objective.value_and_grad(w, batch)  # noqa: E731
     if optimizer in ("owlqn", "owl-qn"):
         result = owlqn(fun, w0, cfg, l1_weight=objective.l1_weight)
     elif optimizer == "tron":
-        # The precomputed-curvature operator (hvp_operator): margins/D(w)
-        # once per trust-region iteration, two matvecs per CG product —
-        # TRON stops recomputing margins per product (ROADMAP solver
-        # edge (e); objectives without hvp_operator fall back to per-call
-        # hessian_vector inside hvp_at_for, still matrix-free).
-        result = tron(fun, w0, cfg, hvp_at=hvp_at_for(objective, batch))
+        # The objective's margin form where it has one: TRON carries the
+        # margins, and an iteration makes no forward pass of its own.  Else
+        # the precomputed-curvature operator (hvp_operator): margins/D(w)
+        # once per trust-region iteration, two matvecs per CG product
+        # (objectives without hvp_operator fall back to per-call
+        # hessian_vector inside hvp_at_for, still matrix-free).  Not for
+        # the entity lanes: those small fits run on to the objective's
+        # float32 resolution, where carried and fresh margins round apart
+        # and a trial can go the other way, and the pass spared is one
+        # entity's [rows, d] matvec; they keep today's iterates bit for bit.
+        make_form = None if vmapped else getattr(objective, "tron_form", None)
+        form = None if make_form is None else make_form(
+            batch, int(w0.shape[0]))
+        if form is None:
+            result = tron(fun, w0, cfg, hvp_at=hvp_at_for(objective, batch))
+        else:
+            result = tron(None, w0, cfg, form=form)
     elif optimizer in ("newton_cg", "newton-cg"):
         result = newton_cg(
             fun, w0, cfg,
@@ -143,7 +155,7 @@ def _cached_solver(optimizer: str, cfg: OptimizerConfig, variance: str,
     from photon_tpu.utils.device import named_jit
 
     run = functools.partial(_run_fit, optimizer=optimizer, cfg=cfg,
-                            variance=variance)
+                            variance=variance, vmapped=vmapped)
     if vmapped:
         run = jax.vmap(run, in_axes=(None, 0, 0))
     # The device program's published name: jit_glm_fit_lbfgs, or
@@ -195,7 +207,7 @@ class GlmOptimizationProblem:
                 result.evaluations
             )
             for name in ("line_search_steps", "cg_iterations",
-                         "trust_region_rejections"):
+                         "trust_region_rejections", "margin_passes_spared"):
                 count = getattr(result, name)
                 if count is not None:
                     registry.counter(f"optimizer.{name}").inc_deferred(count)

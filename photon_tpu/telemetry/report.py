@@ -101,8 +101,10 @@ def _render_program_work_section(report: dict) -> list:
     layout build, the kernel probe), which no session keeps as ``Span``
     objects.  Optimizer work: objective evaluations and line-search trials
     beside the iterations they served (a backtracking fit shows as trials
-    above iterations; the row with no coordinate is the process registry's
-    total of the fits run through ``GlmOptimizationProblem.run``).  Layout
+    above iterations, and TRON's forward passes over the features that its
+    carried margins made unnecessary; the row with no coordinate is the
+    process registry's total of the fits run through
+    ``GlmOptimizationProblem.run``).  Layout
     bytes: what the layout build handed to the
     device (``layout.h2d_bytes{what}``) and moved through the layout cache
     (``layout.cache_bytes{op}``), and the layouts it did not build because
@@ -129,12 +131,13 @@ def _render_program_work_section(report: dict) -> list:
     work = {
         column: _counter_totals(report, f"optimizer.{column}", "coordinate")
         for column in ("solves", "iterations", "evaluations",
-                       "line_search_steps")
+                       "line_search_steps", "margin_passes_spared")
     }
     if work["evaluations"]:
         lines += ["", "## Optimizer work", "",
                   "| coordinate | solves | iterations | evaluations "
-                  "| line-search trials |", "|---|---|---|---|---|"]
+                  "| line-search trials | margin passes spared |",
+                  "|---|---|---|---|---|---|"]
         for key in sorted(set().union(*work.values())):
             lines.append(
                 f"| {key[0]} | " + " | ".join(

@@ -619,10 +619,10 @@ def test_tron_hvp_operator_route_matches_per_call_hvp():
 
 def test_tron_vmapped_entity_route_unchanged():
     """The vmapped per-entity TRON route (GAME random effects): the
-    operator wiring (`hvp_at`, what `_run_fit` now passes) produces the
-    same per-lane solutions as the legacy per-call `hvp` wiring under the
-    same vmap — the rewire changes where the curvature is built, not what
-    any entity converges to."""
+    operator wiring (`hvp_at`, what `_run_fit` passes on this route)
+    produces the same per-lane solutions as the legacy per-call `hvp`
+    wiring under the same vmap — the rewire changes where the curvature is
+    built, not what any entity converges to."""
     import jax
     import jax.numpy as jnp
 
@@ -661,7 +661,9 @@ def test_tron_vmapped_entity_route_unchanged():
     legacy = jax.jit(jax.vmap(legacy_lane))(stacked, w0)
     operator = jax.jit(jax.vmap(operator_lane))(stacked, w0)
     assert float(jnp.abs(legacy - operator).max()) <= 1e-6
-    # And the cached GAME solver route (the production wiring) matches.
+    # And the cached GAME solver route (the production wiring) matches: the
+    # entity lanes do not carry TRON's margins (ISSUE 41).
     solver = cached_solver("tron", cfg, "none", vmapped=True)
-    coeff, _ = solver(objective, stacked, w0)
+    coeff, result = solver(objective, stacked, w0)
     assert float(jnp.abs(coeff.means - operator).max()) <= 1e-6
+    assert int(jnp.sum(result.margin_passes_spared)) == 0

@@ -699,6 +699,8 @@ def test_report_program_work_sections_from_counters():
     session.counter("optimizer.iterations", coordinate="fixed").inc(13)
     session.counter("optimizer.evaluations", coordinate="fixed").inc(19)
     session.counter("optimizer.line_search_steps", coordinate="fixed").inc(13)
+    session.counter("optimizer.evaluations", coordinate="tron").inc(4)
+    session.counter("optimizer.margin_passes_spared", coordinate="tron").inc(6)
     session.counter("layout.h2d_bytes", what="aligned").inc(3 * 2**20)
     session.counter("layout.cache_bytes", op="write").inc(2**19)
     session.counter("layout.skipped", layout="fm").inc()
@@ -717,7 +719,8 @@ def test_report_program_work_sections_from_counters():
     ]
     total = sum(s["duration_s"] for s in report["spans"])
     assert row.split(" | ")[1:3] == ["2", f"{total:.3f}"]
-    assert "| fixed | 2 | 13 | 19 | 13 |" in text
+    assert "| fixed | 2 | 13 | 19 | 13 | — |" in text
+    assert "| tron | — | — | 4 | — | 6 |" in text
     assert "| to device: aligned | 3.0 |" in text
     assert "| layout cache write | 0.5 |" in text
     assert "| not built, another kernel won the probe: fm | none (x1) |" in text

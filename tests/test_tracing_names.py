@@ -756,7 +756,9 @@ def test_glm_fit_lbfgs_hlo_carries_program_and_scope_names(monkeypatch):
 def test_glm_fit_tron_hlo_carries_its_phase_scopes(monkeypatch):
     """ISSUE 40: TRON's three phases carry their scopes, and the
     Hessian-vector product's two ``blocked`` directions run inside the CG
-    loop (``tron/cg``), the curvature's margins under ``tron/curvature``."""
+    loop (``tron/cg``).  ISSUE 41: TRON carries the margins, so neither the
+    curvature nor the trial runs a ``blocked/xw`` of its own; the trial's
+    gradient pass stays."""
     from photon_tpu.core.objective import GlmObjective, RegularizationContext
     from photon_tpu.core.optimizers import OptimizerConfig
     from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
@@ -793,7 +795,9 @@ def test_glm_fit_tron_hlo_carries_its_phase_scopes(monkeypatch):
     for direction in ("blocked/xw", "blocked/xtdz"):
         assert any(f"tron/cg/while/body/{direction}" in name
                    for name in ops), direction
-    assert any("tron/curvature/blocked/xw" in name for name in ops)
+    assert not any(
+        f"{phase}/" in name and "blocked/xw" in name
+        for name in ops for phase in ("tron/curvature", "tron/trial"))
     assert any("tron/trial/valuegrad/grad/blocked/xtdz" in name
                for name in ops)
 
@@ -999,11 +1003,38 @@ def _text_hash(text: str) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-def test_rows_form_programs_are_the_parents():
-    """What ISSUE 39 leaves alone keeps its program, opcode for opcode: the
-    fixed effect's ``glm_fit_lbfgs`` over a dense d = 128 batch and an
-    unbatched ``hessian_matrix`` (the hashes are the parent commit's,
-    54fc018, over the HLO text without its source metadata)."""
+def _sparse_blocked_fit_lowered(monkeypatch):
+    """``glm_fit_lbfgs`` over a sparse batch that carries ``bt``, under
+    ``blocked``: what the fixed effect of ``glm_sparse_fit`` and
+    ``game_sparse_fit`` runs."""
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.core.optimizers import OptimizerConfig
+    from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
+    from photon_tpu.data.batch import (
+        attach_feature_major,
+        sparse_batch_from_rows,
+    )
+
+    monkeypatch.setenv("PHOTON_SPARSE_GRAD", "blocked")
+    jax.clear_caches()
+    rng = np.random.default_rng(0)
+    rows = [
+        (np.sort(rng.choice(64, 4, replace=False)), rng.standard_normal(4))
+        for _ in range(48)
+    ]
+    batch = attach_feature_major(sparse_batch_from_rows(
+        rows, rng.integers(0, 2, 48).astype(np.float32)
+    ), aligned_dim=64)
+    assert batch.bt is not None
+    reg = RegularizationContext("l2", 1.0)
+    objective = GlmObjective.create("logistic_regression", reg)
+    problem = GlmOptimizationProblem(objective, ProblemConfig(
+        regularization=reg, optimizer_config=OptimizerConfig(max_iterations=3),
+    ))
+    return problem.solver().lower(objective, batch, jnp.zeros(64, jnp.float32))
+
+
+def _dense_fit_lowered(monkeypatch):
     from photon_tpu.core.objective import GlmObjective, RegularizationContext
     from photon_tpu.core.optimizers import OptimizerConfig
     from photon_tpu.core.problem import GlmOptimizationProblem, ProblemConfig
@@ -1016,11 +1047,35 @@ def test_rows_form_programs_are_the_parents():
     problem = GlmOptimizationProblem(objective, ProblemConfig(
         regularization=reg, optimizer_config=OptimizerConfig(max_iterations=3),
     ))
-    fit = problem.solver().lower(objective, batch, shape(128))
-    assert _text_hash(fit.as_text(dialect="hlo")) == "68157ddbbfb91127"
+    return problem.solver().lower(objective, batch, shape(128))
+
+
+def _dense_hessian_lowered(monkeypatch):
+    from photon_tpu.core.objective import GlmObjective, RegularizationContext
+    from photon_tpu.data.batch import DenseBatch
+
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    objective = GlmObjective.create(
+        "logistic_regression", RegularizationContext("l2", 1.0))
     small = DenseBatch(shape(8, 16), shape(8), shape(8), shape(8))
-    hessian = jax.jit(objective.hessian_matrix).lower(shape(16), small)
-    assert _text_hash(hessian.as_text(dialect="hlo")) == "11766d26094c5fb9"
+    return jax.jit(objective.hessian_matrix).lower(shape(16), small)
+
+
+@pytest.mark.parametrize("lowered, digest", [
+    # ISSUE 39 (parent 54fc018): the fixed effect's fit over a dense
+    # d = 128 batch, and an unbatched ``hessian_matrix``.
+    ((_dense_fit_lowered, _dense_hessian_lowered),
+     ("68157ddbbfb91127", "11766d26094c5fb9")),
+    # ISSUE 41 (parent c8be8ef): L-BFGS over ``blocked`` tiles, through
+    # the value and gradient that TRON's margin form shares.
+    ((_sparse_blocked_fit_lowered,), ("5e7a46fc0f078865",)),
+], ids=["dense", "sparse_blocked"])
+def test_rows_form_programs_are_the_parents(monkeypatch, lowered, digest):
+    """What ISSUE 39 and ISSUE 41 leave alone keeps its program, opcode for
+    opcode (each hash is its parent commit's, over the HLO text without its
+    source metadata)."""
+    for lower, want in zip(lowered, digest):
+        assert _text_hash(lower(monkeypatch).as_text(dialect="hlo")) == want
 
 
 def test_published_program_names():
